@@ -12,7 +12,10 @@ instead of taking it:
   budget, independent of the admission governors' foreground slots);
 - before taking a token a heal YIELDS while foreground pressure is
   high — pressure is (a) queue depth on either admission governor or
-  (b) span-measured foreground disk p99 over a sliding window;
+  (b) span-measured foreground disk p99 over a sliding window of the
+  last ``max_wait_s`` seconds (a sample older than the longest a heal
+  may wait says nothing about what a heal waiting now would relieve:
+  once the foreground goes quiet, so does the pressure);
 - a heal never waits longer than ``max_wait_s``: at the deadline it is
   granted anyway (counted separately).  Starvation therefore slows the
   MRF drain but can never deadlock it — the backlog always reaches dry.
@@ -106,11 +109,12 @@ class HealPacer:
 
     def note_foreground_disk(self, seconds: float) -> None:
         with self._lat_mu:
-            self._lat.append(seconds)
+            self._lat.append((time.monotonic(), seconds))
 
     def disk_p99_s(self) -> float:
+        horizon = time.monotonic() - self.cfg.max_wait_s
         with self._lat_mu:
-            samples = sorted(self._lat)
+            samples = sorted(s for t, s in self._lat if t >= horizon)
         if len(samples) < _MIN_P99_SAMPLES:
             return 0.0
         idx = min(len(samples) - 1, int(0.99 * (len(samples) - 1) + 0.5))

@@ -60,6 +60,10 @@ def main(argv: list[str] | None = None) -> int:
             print(f"minio-tpu {server.mode} mode")
             print(f"S3 endpoint: {scheme}://{server.endpoint}")
             print(f"RootUser: {server.root_user}")
+            if server.engine_backend is not None:
+                b = server.engine_backend
+                print(f"Erasure backend: platform={b.platform} "
+                      f"device_kind={b.device_kind!r} devices={b.count}")
         try:
             action = server.wait()
         finally:

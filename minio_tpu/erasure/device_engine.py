@@ -1,14 +1,12 @@
 """Fused single-dispatch device codec for the erasure hot path.
 
-BENCH_r05 measured the device streaming PUT at 0.016 GB/s against a
-0.66 GB/s sustained H2D bound: the chip encodes at 1973 GB/s (einsum)
-but the per-batch orchestration — 70 ms null dispatch, serial
-h2d -> compute -> d2h, fresh device allocations every batch — threw
-away 97% of even the transfer ceiling. Same lesson as the XOR-coding
-optimization literature (arXiv:2108.02692): once the kernel is fast,
-throughput is decided by data movement and invocation overhead.
+Once the GF kernel is fast, throughput is decided by data movement and
+invocation overhead (the lesson of the XOR-coding optimization
+literature, arXiv:2108.02692): a per-batch orchestration of serial
+h2d -> compute -> d2h with fresh device allocations every batch throws
+away most of even the transfer ceiling.
 
-This module is the answer, structured so each [B, k, S] batch costs:
+This module is structured so each [B, k, S] batch costs:
 
 - ONE dispatch: GF parity matmul (ops/rs.py einsum path) and the
   HighwayHash-256 bitrot digests of all k+m shards
@@ -109,11 +107,8 @@ def _d2h_async(arr) -> None:
     np.asarray finds the bytes already (or nearly) landed."""
     if arr is None:
         return
-    try:
-        arr.copy_to_host_async()
-        _stat("async_d2h")
-    except Exception:  # noqa: BLE001 - platform without async copy
-        pass
+    arr.copy_to_host_async()
+    _stat("async_d2h")
 
 
 class DeviceCodec:
@@ -261,9 +256,12 @@ class DeviceCodec:
 
             return impl
 
+        from . import registry
+
         fn = self._get_fn(key, make)
         bitmat = self._dev_mat(key[:3], self._recon_bits(present, targets))
         dev = self._stage(src)
+        registry.note_dispatch(self.codec_id, "device")
         _stat("dispatches")
         _stat("donated_batches")
         if with_hashes:

@@ -11,10 +11,9 @@
   worker-shm / numpy);
 - **geometry** — a predicate over (k, m);
 - **measured throughput** — a tiny min-of-N encode probe per host
-  engine (device/mesh carry declared host-feed rate bounds: the r03
-  measurement showed every available TPU attachment feeds host bytes
-  at well under 1 GB/s, which bounds host-sourced service regardless
-  of MXU rate).
+  engine (device/mesh carry declared host-feed rate bounds: two
+  constants, unmeasured on this attachment and ROADMAP S2's to
+  replace).
 
 Engine selection (`select_engine`) replaces the four-way if-chain that
 used to live in erasure/codec.py: candidates are gated by availability
@@ -77,6 +76,9 @@ CODEC_DESCRIPTORS: list[tuple[str, str, str]] = [
      "Erasure batch dispatches, labeled codec + engine substrate"),
     ("mtpu_codec_probe_gbps", "gauge",
      "Measured codec probe throughput (GB/s), labeled codec + engine"),
+    ("backend_info", "gauge",
+     "JAX backend the device/mesh engines dispatch to, labeled "
+     "platform + device_kind + devices"),
 ]
 
 _metrics = None  # guarded-by: _metrics_mu
@@ -268,17 +270,25 @@ def supports(codec_id: str, substrate: str) -> bool:
 _PROBE_SHARD = 16384
 _PROBE_GEOMETRY = (4, 2)
 _PROBE_RUNS = 3
+# A fast call is sampled at least this long: three readings of a 25 µs
+# native call rank how cold the caches were for whichever codec was
+# probed first (1.6x apart in fresh processes), not the codecs.
+_PROBE_MIN_S = 0.005
 
 
 def _measure(fn, nbytes: int, runs: int = _PROBE_RUNS) -> float:
     """Best-of-N wall-clock GB/s for one probe callable (min time, the
-    same dispersion-resistant protocol bench.py uses)."""
+    same dispersion-resistant protocol bench.py uses); N is at least
+    `runs` and as many as fit in _PROBE_MIN_S."""
     fn()  # warm caches (matrix derivations, kernel tables)
     best = float("inf")
-    for _ in range(runs):
+    done = 0
+    until = time.perf_counter() + _PROBE_MIN_S
+    while done < runs or time.perf_counter() < until:
         t0 = time.perf_counter()
         fn()
         best = min(best, time.perf_counter() - t0)
+        done += 1
     if best <= 0:
         return 0.0
     return nbytes / best / 1e9
@@ -370,14 +380,18 @@ def select_engine(shard_len: int, total_shards: int | None = None,
     host ladder (native, then numpy) exactly as the pre-registry policy
     did. 'auto' ranks the available candidates by throughput: measured
     probes for the host engines, the codec's declared host-feed bounds
-    for device/mesh (see module docstring for the r03 measurement that
-    justifies feed-bounded ranking on host-sourced streams).
+    for device/mesh.
 
     The mesh candidate exists only when the caller names the geometry
     (`total_shards`) and placement.mesh_fit accepts it — forced mesh
     admits virtual CPU meshes (the CI path), auto only real multi-device
     accelerator backends. The env/mesh probes are re-read per call
     (tests flip them); the resolution itself is memoized.
+
+    THE one place the device and mesh engines are resolved: a 'device'
+    or 'mesh' answer has read jax.devices() (utils/jaxenv.backend), so
+    it names its backend on the metrics endpoint and raises instead of
+    serving from a CPU that JAX fell back to.
     """
     import os
 
@@ -390,13 +404,16 @@ def select_engine(shard_len: int, total_shards: int | None = None,
         mesh_fit = placement.mesh_fit(total_shards, explicit=eng == "mesh")
     else:
         mesh_fit = False
-    return _resolve_engine(
+    engine = _resolve_engine(
         eng,
         shard_len >= DEVICE_SHARD_THRESHOLD,
         gf_native.available(),
         mesh_fit,
         codec_id,
     )
+    if engine in ("device", "mesh"):
+        _announce_backend()
+    return engine
 
 
 @functools.lru_cache(maxsize=64)
@@ -420,6 +437,34 @@ def _resolve_engine(eng: str, device_sized: bool, native_ok: bool,
         reverse=True,
     )
     return ranked[0] if ranked else "numpy"
+
+
+def forced_engine_backend():
+    """The backend behind a forced device/mesh engine
+    (MTPU_ENCODE_ENGINE), read NOW, or None when no accelerator engine
+    is forced. The server calls this at boot: its banner names the
+    backend, and a missing chip stops it before it accepts a request
+    instead of at the first large PUT."""
+    import os
+
+    if os.environ.get("MTPU_ENCODE_ENGINE", "auto") in ("device", "mesh"):
+        return _announce_backend()
+    return None
+
+
+def _announce_backend():
+    """Read the backend the device/mesh engines dispatch to (cached per
+    process; raises on a CPU nobody asked for) and publish its identity
+    on the metrics endpoint."""
+    from ..utils import jaxenv
+
+    found = jaxenv.backend()
+    reg = _reg()
+    if reg is not None:
+        reg.set_gauge("backend_info", 1, platform=found.platform,
+                      device_kind=found.device_kind,
+                      devices=str(found.count))
+    return found
 
 
 def _engine_rank(codec_id: str, engine: str) -> tuple:

@@ -656,8 +656,8 @@ def _encode_stream_native_pipelined(erasure: Erasure, src,
               → frame-write (strided frame digests + writev scatter-
                 gather straight from the strip buffer, zero data copies)
 
-    so the md5/encode/frame/write stages that BENCH_r05 measured
-    back-to-back (md5_overlap_speedup 0.978) proceed concurrently;
+    so the md5/encode/frame/write stages, which once ran back-to-back,
+    proceed concurrently;
     bounded queues give backpressure against a slow disk, and a write
     failure past quorum cancels the read/encode stages promptly.
 

@@ -2534,7 +2534,8 @@ def crash_restart_put(root: str, seed: int = 7, payload_mib: int = 6,
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # One process per chip: the parent may hold it, the child never may.
+    env["JAX_PLATFORMS"] = "cpu"
     env["MTPU_INLINE_THRESHOLD"] = "0"
     proc = subprocess.Popen(
         [sys.executable, "-m", "minio_tpu.faults.scenarios", "serve",

@@ -6,53 +6,78 @@ every entry point; set MTPU_NO_NATIVE=1 to force them.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_DIR = os.path.join(_DIR, "_build")
 _SOURCES = ["highwayhash.c", "gfapply.c", "snappy.c"]
-_LIB_NAME = "libmtpu_native.so"
+# -march=native unlocks GFNI/pshufb/AVX2 for the kernels; the ladder
+# retries without it (scalar fallback paths in the C) on exotic
+# toolchains.
+_FLAG_LADDER = (["-march=native", "-fopenmp"], ["-fopenmp"], [])
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _needs_rebuild(so_path: str) -> bool:
-    if not os.path.exists(so_path):
-        return True
-    so_mtime = os.path.getmtime(so_path)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, src)) > so_mtime
-        for src in _SOURCES
-    )
+def _cpu_flags() -> str:
+    """The host CPU's feature list — what -march=native compiles for."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.machine()
+
+
+def _build_key() -> str:
+    """Hash of the C sources, the flag ladder and the host's CPU flags.
+    The library's file name carries it, so a build made from other
+    sources or carried over from another machine (the ISA dispatch is
+    decided at compile time: loading it there is SIGILL, not an
+    exception) is never loaded — it is simply not this build."""
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        with open(os.path.join(_DIR, src), "rb") as f:
+            h.update(f.read())
+    h.update(repr(_FLAG_LADDER).encode())
+    h.update(_cpu_flags().encode())
+    return h.hexdigest()[:16]
 
 
 def _build() -> str | None:
-    so_path = os.path.join(_BUILD_DIR, _LIB_NAME)
-    if not _needs_rebuild(so_path):
+    so_path = os.path.join(_BUILD_DIR, f"libmtpu_native-{_build_key()}.so")
+    if os.path.exists(so_path):
         return so_path
     os.makedirs(_BUILD_DIR, exist_ok=True)
     srcs = [os.path.join(_DIR, s) for s in _SOURCES]
     tmp = so_path + f".tmp{os.getpid()}"
-    # -march=native unlocks pshufb/AVX2 for the GF kernel; retry without
-    # it (scalar fallback paths in the C) on exotic toolchains.
-    for extra in (["-march=native", "-fopenmp"], ["-fopenmp"], []):
+    for extra in _FLAG_LADDER:
         cmd = ["cc", "-O3", *extra, "-shared", "-fPIC", "-o", tmp, *srcs]
         try:
             subprocess.run(
                 cmd, check=True, capture_output=True, timeout=120
             )
             os.replace(tmp, so_path)
-            return so_path
         except (subprocess.SubprocessError, OSError):
-            try:
+            with contextlib.suppress(OSError):
                 os.unlink(tmp)
-            except OSError:
-                pass
+            continue
+        for stale in glob.glob(os.path.join(_BUILD_DIR, "libmtpu_native*.so")):
+            if stale != so_path:
+                with contextlib.suppress(OSError):
+                    os.unlink(stale)
+        return so_path
     return None
 
 
@@ -70,6 +95,12 @@ def load() -> ctypes.CDLL | None:
         so_path = _build()
         if so_path is None:
             return None
+        # GOMP's default active wait keeps every team member spinning
+        # after a parallel region. Each request thread that calls in
+        # owns a team, so on a busy or shared-core host the spinners
+        # starve the workers and a sub-millisecond call takes tens of
+        # milliseconds. libgomp reads the policy once, when it loads.
+        os.environ.setdefault("OMP_WAIT_POLICY", "passive")
         try:
             lib = ctypes.CDLL(so_path)
         except OSError:

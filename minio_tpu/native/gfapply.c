@@ -44,6 +44,20 @@ int gf_engine_kind(void) {
 #endif
 }
 
+/* Team size for a batch of nblocks blocks of block_bytes input each:
+ * never more threads than blocks (the loop is parallel across blocks,
+ * and an idle team member still has to be woken and joined), and one
+ * thread per 256 KiB of input — the floor gf_apply_affine uses — since
+ * below that waking a thread costs more than the work it takes over. */
+static int gf_batch_team(int nthreads, size_t nblocks, size_t block_bytes) {
+    size_t team = nblocks * block_bytes / (size_t)(256 << 10);
+    if (team > nblocks)
+        team = nblocks;
+    if (nthreads < 1 || team < 1)
+        return 1;
+    return team < (size_t)nthreads ? (int)team : nthreads;
+}
+
 #ifdef GF_HAVE_GFNI512
 /* GFNI path: each coding coefficient c is an 8x8 GF(2) bit matrix (the
  * same expansion ops/gf.py bit_matrix feeds the MXU); vgf2p8affineqb
@@ -116,8 +130,7 @@ void gf_apply_affine(const uint64_t *qwords, int r, int k, const uint8_t *in,
 void gf_apply_affine_batch(const uint64_t *qwords, int r, int k,
                            const uint8_t *in, uint8_t *out, size_t nblocks,
                            size_t s, int nthreads) {
-    if (nthreads < 1)
-        nthreads = 1;
+    nthreads = gf_batch_team(nthreads, nblocks, (size_t)k * s);
 #if defined(_OPENMP)
 #pragma omp parallel for num_threads(nthreads) schedule(dynamic, 1)
 #endif
@@ -205,8 +218,7 @@ void gf_apply(const uint8_t *tables, int r, int k, const uint8_t *in,
 /* Batched variant: in[b][k][s], out[b][r][s]; parallel across blocks. */
 void gf_apply_batch(const uint8_t *tables, int r, int k, const uint8_t *in,
                     uint8_t *out, size_t nblocks, size_t s, int nthreads) {
-    if (nthreads < 1)
-        nthreads = 1;
+    nthreads = gf_batch_team(nthreads, nblocks, (size_t)k * s);
 #if defined(_OPENMP)
 #pragma omp parallel for num_threads(nthreads) schedule(dynamic, 1)
 #endif

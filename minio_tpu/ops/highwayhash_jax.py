@@ -19,7 +19,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.jaxenv import place_compile_cache
 from .highwayhash import MAGIC_KEY, _INIT0, _INIT1
+
+place_compile_cache()
 
 _U32 = jnp.uint32
 _MASK16 = np.uint32(0xFFFF)

@@ -24,6 +24,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.jaxenv import place_compile_cache
+
+place_compile_cache()
+
 
 @functools.partial(jax.jit, donate_argnums=())
 def _apply_bits(bitmat: jax.Array, shards: jax.Array) -> jax.Array:
@@ -58,13 +62,12 @@ def apply_gf_matrix(bitmat, shards) -> jax.Array:
     """Public entry: bitmat int8 [8R,8K] (from gf.bit_matrix), shards
     uint8 [..., K, S]. Leading dims are batch.
 
-    Kernel policy (round-3 measurement on the real chip, 1 GiB
-    device-resident dispatches): XLA's einsum formulation 28.3 GB/s,
-    plane-major Pallas 27.5 GB/s, the earlier interleaved Pallas kernel
-    13.5 GB/s — XLA already fuses unpack/matmul/pack into one kernel, so
-    hand-fusing buys nothing and its fixed tiling loses slightly. The
-    shipping path is therefore the einsum; set MTPU_RS_KERNEL=pallas to
-    opt in to the Pallas kernel (kept bit-exact for experimentation).
+    Kernel policy: the shipping path is the einsum, which XLA fuses
+    into one unpack/matmul/pack kernel. MTPU_RS_KERNEL=pallas opts in
+    to the Pallas kernel (ops/rs_pallas.py, kept bit-exact) on a TPU
+    backend; a kernel that does not compile there raises rather than
+    quietly running the einsum. Neither has a timing on record from
+    this attachment (ROADMAP D3).
     """
     import os
 

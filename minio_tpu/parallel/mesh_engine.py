@@ -282,6 +282,8 @@ class MeshCodec:
         split over the lane axis inside the program (padded to the lane
         dim when S doesn't divide), so a dp=1 mesh still reconstructs
         on every device, then all-gathers the rebuilt shards."""
+        from ..erasure import registry
+
         present = tuple(present[: self.k])
         targets = tuple(targets)
         dev, n_rows = self._stage(src)
@@ -329,6 +331,7 @@ class MeshCodec:
         bitmat = self._dev_mat(("rec", present, targets),
                                self._recon_bits(present, targets))
         b_padded = dev.shape[0]
+        registry.note_dispatch(self.codec_id, "mesh")
         self._record_batch(
             blocks=n_rows,
             collective=b_padded * len(targets) * s
@@ -357,7 +360,10 @@ class MeshCodec:
         what keeps the dispatches-per-batch == 1.0 guards falsifiable
         if a future change splits one batch into several collectives."""
         mesh_metrics.record("mesh_dispatches_total")
-        return fn(*args)
+        out = fn(*args)
+        first = out[0] if isinstance(out, tuple) else out
+        mesh_metrics.record_output_devices(len(first.sharding.device_set))
+        return out
 
     def _record_batch(self, blocks: int, collective: int,
                       stripe_bytes: int) -> None:

@@ -46,6 +46,8 @@ MESH_DESCRIPTORS: list[tuple[str, str, str]] = [
     ("mesh_dp", "gauge", "dp dim of the active mesh shape"),
     ("mesh_lane_utilization", "gauge",
      "Shard balance across lanes: 1.0 when k+m divides evenly"),
+    ("mesh_output_devices", "gauge",
+     "Devices holding the last mesh dispatch's output array"),
 ]
 
 STATS = {
@@ -90,6 +92,14 @@ def record_shape(dp: int, lanes: int, n_shards: int) -> None:
         per_lane = -(-n_shards // lanes)  # ceil
         _metrics.set_gauge("mesh_lane_utilization",
                            n_shards / (lanes * per_lane))
+
+
+def record_output_devices(n: int) -> None:
+    """Devices holding the last dispatch's output, read from the array's
+    own sharding: where the program ran, which the lane-byte counters
+    (host arithmetic over shapes) cannot say."""
+    if _metrics is not None:
+        _metrics.set_gauge("mesh_output_devices", n)
 
 
 def stats_snapshot() -> dict:

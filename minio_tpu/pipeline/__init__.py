@@ -6,13 +6,11 @@ per-stage telemetry exported through the observability metrics registry.
 This is the structural backbone of the erasure hot paths: PUT
 (source-read ∥ md5 ∥ encode ∥ bitrot-frame ∥ shard-write), GET's
 prefetching decode/bitrot-verify path, heal reconstruction, and the
-device engine's double-buffered host feed (ops/rs_pallas.HostFeed). The
-motivating measurement (BENCH_r05): encode runs at 11 GB/s but e2e PUT
-models at 0.45 GB/s because the stages run back-to-back —
-md5_overlap_speedup 0.978 means ZERO overlap. Once the GF kernel is
-fast, pipeline structure, not the codec, dominates throughput
-(arXiv:2108.02692); the same staged overlap discipline feeds the TPU
-path.
+device engine's double-buffered host feed (ops/rs_pallas.HostFeed).
+Stages that run back-to-back cap e2e PUT far below the encoder: once
+the GF kernel is fast, pipeline structure, not the codec, dominates
+throughput (arXiv:2108.02692); the same staged overlap discipline feeds
+the TPU path.
 """
 
 from .admission import AdmissionGovernor, client_context, governor

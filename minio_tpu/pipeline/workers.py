@@ -467,13 +467,22 @@ def _worker_cli() -> None:  # pragma: no cover - child process
     multiprocessing spawn): spawn re-executes the parent's __main__,
     which breaks under pytest/stdin drivers, while stdin EOF here is a
     natural orphan guard — the child exits the moment its parent dies.
-    Imports stay jax-free (numpy + the native lib); one native thread
-    per worker so W workers never oversubscribe the cores the parent
-    still needs."""
+    Imports stay jax-free (numpy + the native lib) — checked below,
+    since the pool is armed at boot beside a server that may own the
+    chip, and a child that imported jax could reach for it; one native
+    thread per worker so W workers never oversubscribe the cores the
+    parent still needs."""
     import pickle
     import sys
 
     os.environ.setdefault("MTPU_NATIVE_THREADS", "1")
+    # Everything the ops below import, up front, so the check covers it.
+    from .. import native  # noqa: F401
+    from ..erasure import bitrot, registry  # noqa: F401
+    from ..ops import gf_native, highwayhash  # noqa: F401
+
+    if "jax" in sys.modules:
+        raise RuntimeError("worker child imported jax")
     inp = sys.stdin.buffer
     out = sys.stdout.buffer
     mats: dict = {}

@@ -168,6 +168,10 @@ class Server:
         from .erasure import registry as _codec_registry
 
         _codec_registry.set_metrics(self.metrics)
+        # A forced device/mesh engine reads its backend now, not at the
+        # first large PUT: the banner names it, and a missing chip
+        # stops the server here.
+        self.engine_backend = _codec_registry.forced_engine_backend()
         # Request-span tracing plane (ISSUE 12): per-kind latency
         # histograms (mtpu_span_seconds) and slow-request capture
         # counts flow through the same registry; pub/sub buses count

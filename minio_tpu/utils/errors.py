@@ -1,6 +1,6 @@
 """Canonical error types for the TPU-native object store.
 
-Mirrors the error taxonomy of the reference implementation
+Mirrors the error classes of the reference implementation
 (/root/reference/cmd/typed-errors.go, cmd/storage-errors.go) so that quorum
 reduction and heal-trigger semantics can be expressed identically, while
 remaining idiomatic Python exceptions.

@@ -166,6 +166,22 @@ def test_default_pressure_trips_on_queue_depth_and_p99():
     assert p.pressured()
 
 
+def test_pressure_fades_when_the_foreground_goes_quiet():
+    """Slow foreground ops followed by silence must not throttle heals
+    for ever: samples older than max_wait_s no longer count. (A ring of
+    the last N samples, whatever their age, held a 64-client burst's
+    tail over a whole MRF drain: every heal waited out its deadline.)"""
+    import time
+
+    p = HealPacer(PaceConfig(enabled=True, disk_p99_ms=50.0,
+                             max_wait_s=0.05))
+    for _ in range(40):
+        p.note_foreground_disk(0.2)
+    assert p.pressured()
+    time.sleep(0.1)
+    assert not p.pressured() and p.disk_p99_s() == 0.0
+
+
 def test_note_disk_op_filters_background_ops():
     """Latencies measured under a background ioflow tag (heal/scan/
     replication) must NOT count as foreground pressure — the pacer

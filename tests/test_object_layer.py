@@ -293,7 +293,7 @@ def test_tee_md5_pipelined_matches_inline():
 
 
 def test_tee_md5_overlap_speedup_on_multicore():
-    """VERDICT r5 #9 — prove or retire the pipelined tee. The
+    """Prove or retire the pipelined tee (ROADMAP D7). The
     worker-thread hasher's reason to exist is REAL md5/encode overlap:
     hashing batch N on the worker while the caller's thread runs the
     GIL-releasing native encode. On >=2 cores that must measure

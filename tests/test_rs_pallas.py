@@ -9,11 +9,7 @@ import pytest
 from minio_tpu.ops import gf
 from minio_tpu.ops.gf import gf_matmul_shards_ref
 from minio_tpu.ops.rs import apply_gf_matrix
-from minio_tpu.ops.rs_pallas import apply_gf_matrix_pallas, pallas_available
-
-pytestmark = pytest.mark.skipif(
-    not pallas_available(), reason="pallas import unavailable"
-)
+from minio_tpu.ops.rs_pallas import apply_gf_matrix_pallas
 
 
 @pytest.mark.parametrize("k,m", [(2, 2), (4, 2), (8, 4), (12, 4), (8, 8),

@@ -15,11 +15,16 @@ owns the chip — the server — can have it. It talks to the child only over
 signed HTTP and reads the drive directories it gave the child. Without
 --tiny a child whose platform is not `tpu` is a failure, never a fallback.
 
-Exit code 0 and, as the last line of stdout, one JSON object
-{"ok": true, "device": {"platform", "kind", "count"}, ...} when every
-phase passed; non-zero and no result line otherwise (diagnostics go to
-stderr and chiprun_out/). Wall seconds in the result are smoke
-observations, not benchmark metrics.
+Exit code 0 and two lines of stdout when every phase passed: the run's
+record (phases, dispatch counts, start times, host facts; also written to
+chiprun_out/chip_smoke.json), then, as the LAST line, the verdict with
+these keys and no others, the device as the child's JAX reports it:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Non-zero and nothing on stdout otherwise (diagnostics go to stderr and
+chiprun_out/). Wall seconds in the record are smoke observations, not
+benchmark metrics.
 """
 
 from __future__ import annotations
@@ -708,6 +713,8 @@ def main(argv: list[str] | None = None) -> int:
               file=sys.stderr)
         return 1
     print(json.dumps(res))
+    # The last line is the verdict alone: these keys and no others.
+    print(json.dumps({"ok": True, "device": res["device"]}), flush=True)
     return 0
 
 

@@ -137,7 +137,12 @@ def test_chip_smoke_tiny_passes():
 
     r = _smoke()
     assert r.returncode == 0, r.stderr[-4000:]
-    res = json.loads(r.stdout.strip().splitlines()[-1])
+    record, verdict = r.stdout.strip().splitlines()[-2:]
+    res = json.loads(record)
+    # the last line is the verdict alone: these keys and no others
+    assert json.loads(verdict) == {"ok": True, "device": res["device"]}
+    assert set(res["device"]) == {"platform", "kind", "count"}
+    assert isinstance(res["device"]["count"], int)
     assert res["ok"] and all(p["ok"] for p in res["phases"].values())
     assert list(res["phases"]) == [
         "start", "load", "healthy_get", "reference", "degraded_get",

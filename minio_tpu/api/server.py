@@ -45,6 +45,7 @@ import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ..iam import IAMSys
+from ..observability import spans as _spans
 from . import sign
 from .admin import ADMIN_PREFIX, AdminHandlers
 from .auth import AUTH_STREAMING, authenticate, authorize
@@ -145,7 +146,10 @@ class LimitedReader:
             return b""
         if n is None or n < 0 or n > self._left:
             n = self._left
-        buf = self._raw.read(n)
+        # Blocks until the client has sent n bytes: the request's time
+        # on the wire, on whichever thread pulls the body.
+        with _spans.span("body-read"):
+            buf = self._raw.read(n)
         self._left -= len(buf)
         return buf
 
@@ -701,8 +705,6 @@ class S3Server:
             # already finished it (deferred flips False).
             rt = getattr(ctx, "deferred_trace", None)
             if rt is not None and rt.deferred:
-                from ..observability import spans as _spans
-
                 with _spans.resume(rt):
                     pass
             # The throttle slot covers everything from admission through
@@ -1027,7 +1029,6 @@ class S3Server:
         # sketch). GETs that hit a missing/corrupt shard are promoted
         # to get-degraded by the shard readers mid-stream.
         from ..observability import ioflow as _ioflow
-        from ..observability import spans as _spans
         from ..pipeline.admission import client_context
 
         client = auth_result.access_key or "anonymous"

@@ -18,6 +18,7 @@ import functools
 
 import numpy as np
 
+from ..observability import spans as _spans
 from ..ops import gf, rs
 from ..utils import ceil_frac
 from ..utils.errors import (
@@ -192,7 +193,13 @@ class Erasure:
             bits = dev_bitmat
             if bits is None:
                 bits = bits_np if bits_np is not None else gf.bit_matrix_for(mat_gf)
-            out = np.asarray(rs.apply_gf_matrix(bits, shards))
+            from . import device_engine
+
+            # The unfused device path (tail blocks, degraded GETs): one
+            # dispatch, so one device-call on the request's span tree.
+            with _spans.span("device-call", "apply"):
+                dev_out = rs.apply_gf_matrix(bits, shards)
+            out = device_engine.to_host(dev_out)
         else:
             # Host fallback: the codec's own numpy realization (dense
             # GF(2) bit-matmul, or the Cauchy XOR schedule).

@@ -47,6 +47,8 @@ import warnings
 
 import numpy as np
 
+from ..observability import spans as _spans
+
 # Module counters — the dispatch/trace regression guard read by
 # test_bench_smoke and reported by bench.py's device section.
 #   dispatches      one per fused call actually sent to the device
@@ -100,6 +102,18 @@ def reset_stats() -> None:
 
 def _is_device_array(x) -> bool:
     return not isinstance(x, np.ndarray) and hasattr(x, "block_until_ready")
+
+
+def to_host(*handles):
+    """Materialise device results on the host, one ndarray per handle
+    (None stays None). The D2H started at dispatch, so the time in here
+    is the host blocked until the device has finished: the `device-wait`
+    span, the one place the streaming drivers wait for the chip."""
+    # a host engine's ndarrays come through here too: nothing to wait for
+    waits = any(_is_device_array(h) for h in handles)
+    with _spans.span("device-wait") if waits else _spans.NULL:
+        out = tuple(None if h is None else np.asarray(h) for h in handles)
+    return out[0] if len(out) == 1 else out
 
 
 def _d2h_async(arr) -> None:
@@ -169,6 +183,12 @@ class DeviceCodec:
             self._fns.setdefault(key, fn)
             return self._fns[key]
 
+    def _note_trace(self) -> None:
+        from . import registry
+
+        _stat("traces")
+        registry.note_trace(self.codec_id, "device")
+
     def _fused_fn(self, key, with_hashes: bool):
         def make():
             import jax.numpy as jnp
@@ -177,7 +197,7 @@ class DeviceCodec:
             from ..ops.rs import apply_gf_matrix
 
             def impl(bitmat, blocks):
-                _stat("traces")  # runs at trace time only
+                self._note_trace()  # runs at trace time only
                 out = apply_gf_matrix(bitmat, blocks)
                 if not with_hashes:
                     return out
@@ -198,8 +218,9 @@ class DeviceCodec:
         # real host-side fixup copy is counted before the H2D.
         from ..pipeline.buffers import ascontig_counted
 
-        return jax.device_put(ascontig_counted(blocks,
-                                               "put.device_stage"))
+        with _spans.span("device-h2d", "device"):
+            return jax.device_put(ascontig_counted(blocks,
+                                                   "put.device_stage"))
 
     # --- encode ---
 
@@ -213,10 +234,10 @@ class DeviceCodec:
         bitmat = self._dev_mat("parity", self._parity_bits_np)
         _stat("dispatches")
         _stat("donated_batches")
-        if with_hashes:
-            parity, digests = fn(bitmat, dev)
-        else:
-            parity, digests = fn(bitmat, dev), None
+        # trace + lower + cache lookup + enqueue: where a re-trace lands
+        with _spans.span("device-call", "enc"):
+            out = fn(bitmat, dev)
+        parity, digests = out if with_hashes else (out, None)
         _d2h_async(parity)
         _d2h_async(digests)
         return parity, digests
@@ -248,7 +269,7 @@ class DeviceCodec:
             from ..ops.rs import apply_gf_matrix
 
             def impl(bitmat, blocks):
-                _stat("traces")
+                self._note_trace()
                 out = apply_gf_matrix(bitmat, blocks)
                 if not with_hashes:
                     return out
@@ -264,10 +285,9 @@ class DeviceCodec:
         registry.note_dispatch(self.codec_id, "device")
         _stat("dispatches")
         _stat("donated_batches")
-        if with_hashes:
-            rebuilt, digests = fn(bitmat, dev)
-        else:
-            rebuilt, digests = fn(bitmat, dev), None
+        with _spans.span("device-call", "rec"):
+            out = fn(bitmat, dev)
+        rebuilt, digests = out if with_hashes else (out, None)
         _d2h_async(rebuilt)
         _d2h_async(digests)
         return rebuilt, digests

@@ -74,6 +74,10 @@ CODEC_DESCRIPTORS: list[tuple[str, str, str]] = [
      "Codec selections at write time, labeled codec + geometry (k+m)"),
     ("mtpu_codec_dispatch_total", "counter",
      "Erasure batch dispatches, labeled codec + engine substrate"),
+    ("codec_trace_total", "counter",
+     "Traces of a fused device function (jax.jit building a new one), "
+     "labeled codec + engine; the mesh engine counts its own under "
+     "mesh_retraces_total"),
     ("mtpu_codec_probe_gbps", "gauge",
      "Measured codec probe throughput (GB/s), labeled codec + engine"),
     ("backend_info", "gauge",
@@ -580,6 +584,14 @@ def _auto_codec_heal_heavy(data_blocks: int, parity_blocks: int) -> str:
         ):
             best, best_frac = cid, frac
     return best
+
+
+def note_trace(codec_id: str, engine: str) -> None:
+    """One trace of a fused device function: runs inside the traced
+    Python, so once per function jax.jit builds and never per call."""
+    reg = _reg()
+    if reg is not None:
+        reg.inc("codec_trace_total", codec=codec_id, engine=engine)
 
 
 def note_dispatch(codec_id: str, engine: str) -> None:

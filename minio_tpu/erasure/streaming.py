@@ -42,6 +42,8 @@ from .codec import Erasure
 _io_pool = ThreadPoolExecutor(max_workers=64, thread_name_prefix="mtpu-io")
 
 from ..observability import ioflow as _ioflow
+from ..observability import spans as _spans
+from .device_engine import to_host as _to_host
 from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 from ..utils.fanout import QuorumFanout, StragglerCompensator
 from ..utils.fanout import is_local_sink as _is_local_sink
@@ -309,33 +311,44 @@ def encode_stream(erasure: Erasure, src, writers: list, quorum: int,
     )
     engine = _select_engine(shard, erasure.total_shards,
                             codec=erasure.codec_id)
-    if engine == "native":
-        # Host-native engine: the batched strip path (one GFNI encode +
-        # one framing call per shard per batch).
-        if _SINGLE_CORE:
-            return _encode_stream_native(erasure, src, writer, batch_blocks)
-        from ..pipeline import workers as _workers
+    # One span over the whole stream, labelled by the driver that ran.
+    with _spans.span("stream") as sp:
+        if engine == "native":
+            # Host-native engine: the batched strip path (one GFNI encode
+            # + one framing call per shard per batch).
+            if _SINGLE_CORE:
+                sp.relabel("native_serial")
+                return _encode_stream_native(erasure, src, writer,
+                                             batch_blocks)
+            from ..pipeline import workers as _workers
 
-        wpool = (_workers.armed()
-                 if registry.supports(erasure.codec_id, "worker") else None)
-        if wpool is not None:
-            # Worker-pool path: the per-batch GF encode + strided
-            # digests run in a child process over a shared-memory strip
-            # — the main interpreter's GIL stays free for fill/writev/
-            # commit, which is what lets N concurrent clients scale.
-            return _encode_stream_native_workers(
-                erasure, src, writer, batch_blocks, telemetry, wpool
+            wpool = (_workers.armed()
+                     if registry.supports(erasure.codec_id, "worker")
+                     else None)
+            if wpool is not None:
+                # Worker-pool path: the per-batch GF encode + strided
+                # digests run in a child process over a shared-memory
+                # strip — the main interpreter's GIL stays free for
+                # fill/writev/commit, which is what lets N concurrent
+                # clients scale.
+                sp.relabel("native_workers")
+                return _encode_stream_native_workers(
+                    erasure, src, writer, batch_blocks, telemetry, wpool
+                )
+            sp.relabel("native_pipelined")
+            return _encode_stream_native_pipelined(
+                erasure, src, writer, batch_blocks, telemetry
             )
-        return _encode_stream_native_pipelined(
-            erasure, src, writer, batch_blocks, telemetry
+        if _SINGLE_CORE:
+            sp.relabel("batched_serial")
+            return _encode_stream_batched(
+                erasure, src, writer, batch_blocks, want_digests
+            )
+        sp.relabel("batched_pipelined")
+        return _encode_stream_batched_pipelined(
+            erasure, src, writer, batch_blocks, want_digests, engine,
+            telemetry, sp
         )
-    if _SINGLE_CORE:
-        return _encode_stream_batched(
-            erasure, src, writer, batch_blocks, want_digests
-        )
-    return _encode_stream_batched_pipelined(
-        erasure, src, writer, batch_blocks, want_digests, engine, telemetry
-    )
 
 
 _HOST_FEED = None
@@ -397,8 +410,8 @@ def _encode_stream_batched(erasure: Erasure, src, writer: ParallelWriter,
     def flush(p) -> None:
         nonlocal total
         data, parity_f, hashes_f, n = p
-        parity = np.asarray(parity_f)  # blocks until the dispatch finishes
-        hashes = np.asarray(hashes_f) if hashes_f is not None else None
+        # blocks until the dispatch finishes
+        parity, hashes = _to_host(parity_f, hashes_f)
         for bi in range(n):
             blocks = [data[bi, j] for j in range(erasure.data_blocks)] + [
                 parity[bi, j] for j in range(erasure.parity_blocks)
@@ -440,7 +453,8 @@ def _encode_stream_batched(erasure: Erasure, src, writer: ParallelWriter,
 def _encode_stream_batched_pipelined(erasure: Erasure, src,
                                      writer: ParallelWriter,
                                      batch_blocks: int, want_digests: bool,
-                                     engine: str, telemetry: str) -> int:
+                                     engine: str, telemetry: str,
+                                     sp=_spans.NULL) -> int:
     """Pipelined driver for the device/numpy engines: read → pack →
     host-feed (double-buffered H2D staging, ops/rs_pallas.HostFeed) →
     fused dispatch → flush+write as overlapped stages. The H2D transfer
@@ -535,9 +549,7 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
         if data is not None:
             # D2H only the parity/hashes; the data shards are still
             # host-resident in the pooled buffer.
-            parity = np.asarray(parity_f)
-            hashes = (np.asarray(hashes_f) if hashes_f is not None
-                      else None)
+            parity, hashes = _to_host(parity_f, hashes_f)
             n = parity.shape[0]
             host = buf[:n].reshape(n, k, shard)
             for bi in range(n):
@@ -588,6 +600,7 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
     except StopIteration:
         return 0
     if len(first[0]) < batch_blocks or first[1] is not None:
+        sp.relabel("inline")
         run_inline(first)
         return totals["bytes"]
 
@@ -597,10 +610,10 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
 
     stages = []
     if md5_update is not None:
-        stages.append(Stage("md5", md5_stage,
+        stages.append(Stage("md5", md5_stage, leaf=True,
                             bytes_of=lambda it: sum(len(b)
                                                     for b in it[0])))
-    stages.append(Stage("pack", pack))
+    stages.append(Stage("pack", pack, leaf=True))
     if feed is not None:
         stages.append(Stage(feed.name, h2d,
                             bytes_of=lambda it: it[1].nbytes))
@@ -796,9 +809,9 @@ def _encode_stream_native_pipelined(erasure: Erasure, src,
 
     stages = []
     if md5_update is not None:
-        stages.append(Stage("md5", md5_stage,
+        stages.append(Stage("md5", md5_stage, leaf=True,
                             bytes_of=lambda it: it[1] * block_size))
-    stages += [Stage("encode", encode),
+    stages += [Stage("encode", encode, leaf=True),
                Stage("frame-write", frame_write, bytes_of=int)]
     Pipeline(telemetry, stages, queue_depth=1, pools=[pool],
              drop=drop).run(source_from_first())
@@ -962,7 +975,7 @@ def _encode_stream_native_workers(erasure: Erasure, src,
 
     stages = []
     if md5_update is not None:
-        stages.append(Stage("md5", md5_stage,
+        stages.append(Stage("md5", md5_stage, leaf=True,
                             bytes_of=lambda it: it[1] * block_size))
     stages += [Stage("worker-encode", encode),
                Stage("frame-write", frame_write, bytes_of=int)]
@@ -1180,7 +1193,6 @@ class ParallelReader:
                     run(i)
         else:
             from ..observability import carry as _obs_carry
-            from ..observability import spans as _spans
 
             # Reader threads carry the caller's trace (disk-op and
             # worker-verify spans) and byte-flow op tag (shard-read
@@ -1358,82 +1370,88 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
             from ..pipeline import workers as _workers
 
             wpool = _workers.armed()
-    try:
-        if engine == "mesh":
-            # Mesh serving path: degraded blocks reconstruct in fused
-            # collective dispatches batched per failure pattern; healthy
-            # blocks stream straight through on the host — written before
-            # the next fetch, so the recycled readinto ring is safe here
-            # too (batched degraded rows are copied out at append time).
-            for r in readers:
-                if hasattr(r, "reuse_buffers"):
-                    r.reuse_buffers()
-            bytes_written = _decode_stream_mesh(
-                erasure, writer, reader, geoms, note_heal
-            )
-        elif (wpool is not None and len(geoms) > 2
-              and _worker_read_profitable(erasure, readers)):
-            # Worker serving path (ISSUE 11): bitrot verification runs
-            # in the pool via the readers' shm rings, and degraded
-            # blocks batch per failure pattern into worker reconstruct
-            # dispatches over pooled shm strips — the main
-            # interpreter's GIL stays free for shard reads and client
-            # writes, which is what lets N concurrent GETs coexist
-            # with the PUT load. Serial batch consumption (write
-            # before next fetch) makes the recycled rings safe. The
-            # profitability gate keeps small-shard streams on the
-            # pipelined branch below: there the verify offload never
-            # engages, so serializing would trade the stage-thread
-            # read/write overlap for nothing.
-            for r in readers:
-                if hasattr(r, "reuse_buffers"):
-                    r.reuse_buffers()
-            bytes_written = _decode_stream_workers(
-                erasure, writer, reader, geoms, note_heal, wpool
-            )
-        elif _SINGLE_CORE or len(geoms) <= 2:
-            # Serial consumption drains every batch's views before the
-            # next reader fan-out, so the bitrot readers may recycle
-            # their read buffers (readinto a private ring, no fresh
-            # bytes per fetch). The pipelined branch below keeps
-            # several batches in flight and must NOT enable this.
-            for r in readers:
-                if hasattr(r, "reuse_buffers"):
-                    r.reuse_buffers()
-            for block_offset, block_length in geoms:
-                bufs = reader.read()
-                note_heal()
-                erasure.decode_data_blocks(bufs)
-                bytes_written += _write_data_blocks(
-                    writer, bufs, erasure.data_blocks, block_offset,
-                    block_length
+    with _spans.span("stream") as sp:
+        try:
+            if engine == "mesh":
+                # Mesh serving path: degraded blocks reconstruct in fused
+                # collective dispatches batched per failure pattern; healthy
+                # blocks stream straight through on the host — written before
+                # the next fetch, so the recycled readinto ring is safe here
+                # too (batched degraded rows are copied out at append time).
+                for r in readers:
+                    if hasattr(r, "reuse_buffers"):
+                        r.reuse_buffers()
+                sp.relabel("mesh")
+                bytes_written = _decode_stream_mesh(
+                    erasure, writer, reader, geoms, note_heal
                 )
-        else:
-            from ..pipeline import Pipeline, Stage
-
-            def decode(gb):
-                geom, bufs = gb
-                erasure.decode_data_blocks(bufs)
-                return gb
-
-            pipe = Pipeline(telemetry, [
-                Stage("shard-read", lambda geom: (geom, reader.read())),
-                Stage("decode", decode, bytes_of=lambda gb: gb[0][1]),
-            ], queue_depth=2)
-            # The client write stays on the CALLER's thread — response
-            # framing and socket state must not move across threads.
-            for (block_offset, block_length), bufs in pipe.results(geoms):
-                note_heal()
-                bytes_written += _write_data_blocks(
-                    writer, bufs, erasure.data_blocks, block_offset,
-                    block_length
+            elif (wpool is not None and len(geoms) > 2
+                  and _worker_read_profitable(erasure, readers)):
+                # Worker serving path (ISSUE 11): bitrot verification runs
+                # in the pool via the readers' shm rings, and degraded
+                # blocks batch per failure pattern into worker reconstruct
+                # dispatches over pooled shm strips — the main
+                # interpreter's GIL stays free for shard reads and client
+                # writes, which is what lets N concurrent GETs coexist
+                # with the PUT load. Serial batch consumption (write
+                # before next fetch) makes the recycled rings safe. The
+                # profitability gate keeps small-shard streams on the
+                # pipelined branch below: there the verify offload never
+                # engages, so serializing would trade the stage-thread
+                # read/write overlap for nothing.
+                for r in readers:
+                    if hasattr(r, "reuse_buffers"):
+                        r.reuse_buffers()
+                sp.relabel("workers")
+                bytes_written = _decode_stream_workers(
+                    erasure, writer, reader, geoms, note_heal, wpool
                 )
-    finally:
-        # Pooled shm ring slots go back to their pool when the stream
-        # ends (parked fan-out threads defer their own slot's release).
-        for r in readers:
-            if hasattr(r, "release_buffers"):
-                r.release_buffers()
+            elif _SINGLE_CORE or len(geoms) <= 2:
+                # Serial consumption drains every batch's views before the
+                # next reader fan-out, so the bitrot readers may recycle
+                # their read buffers (readinto a private ring, no fresh
+                # bytes per fetch). The pipelined branch below keeps
+                # several batches in flight and must NOT enable this.
+                for r in readers:
+                    if hasattr(r, "reuse_buffers"):
+                        r.reuse_buffers()
+                sp.relabel("serial")
+                for block_offset, block_length in geoms:
+                    bufs = reader.read()
+                    note_heal()
+                    erasure.decode_data_blocks(bufs)
+                    bytes_written += _write_data_blocks(
+                        writer, bufs, erasure.data_blocks, block_offset,
+                        block_length
+                    )
+            else:
+                from ..pipeline import Pipeline, Stage
+
+                sp.relabel("pipelined")
+
+                def decode(gb):
+                    geom, bufs = gb
+                    erasure.decode_data_blocks(bufs)
+                    return gb
+
+                pipe = Pipeline(telemetry, [
+                    Stage("shard-read", lambda geom: (geom, reader.read())),
+                    Stage("decode", decode, bytes_of=lambda gb: gb[0][1]),
+                ], queue_depth=2)
+                # The client write stays on the CALLER's thread — response
+                # framing and socket state must not move across threads.
+                for (block_offset, block_length), bufs in pipe.results(geoms):
+                    note_heal()
+                    bytes_written += _write_data_blocks(
+                        writer, bufs, erasure.data_blocks, block_offset,
+                        block_length
+                    )
+        finally:
+            # Pooled shm ring slots go back to their pool when the stream
+            # ends (parked fan-out threads defer their own slot's release).
+            for r in readers:
+                if hasattr(r, "release_buffers"):
+                    r.release_buffers()
 
     if bytes_written != length:
         raise ErrLessData(f"wrote {bytes_written}, want {length}")
@@ -1465,7 +1483,7 @@ def _decode_stream_mesh(erasure: Erasure, writer, reader, geoms: list,
     def flush(p) -> None:
         nonlocal bytes_written
         bufs_list, geom_list, targets, fut = p
-        rebuilt = np.asarray(fut)  # D2H started at dispatch
+        rebuilt = _to_host(fut)  # D2H started at dispatch
         for bi, (bufs, (off, ln)) in enumerate(zip(bufs_list, geom_list)):
             for t_i, t in enumerate(targets):
                 bufs[t] = rebuilt[bi, t_i]
@@ -1775,62 +1793,67 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
 
     engine = _select_engine(erasure.shard_size(), erasure.total_shards,
                             codec=erasure.codec_id)
-    try:
-        if engine in ("device", "mesh") and total_blocks:
-            # Same fused reconstruct+digest driver for both accelerator
-            # engines; only the codec differs (one chip vs the mesh).
-            if engine == "mesh":
-                from ..parallel.mesh_engine import for_geometry
-            else:
-                from .device_engine import for_geometry
+    with _spans.span("stream") as sp:
+        try:
+            if engine in ("device", "mesh") and total_blocks:
+                # Same fused reconstruct+digest driver for both accelerator
+                # engines; only the codec differs (one chip vs the mesh).
+                if engine == "mesh":
+                    from ..parallel.mesh_engine import for_geometry
+                else:
+                    from .device_engine import for_geometry
 
-            codec = for_geometry(erasure.data_blocks,
-                                 erasure.parity_blocks,
-                                 erasure.codec_id)
-            return _heal_stream_fused(erasure, writers, reader, targets,
-                                      total_blocks, codec)
+                codec = for_geometry(erasure.data_blocks,
+                                     erasure.parity_blocks,
+                                     erasure.codec_id)
+                sp.relabel("heal_fused")
+                return _heal_stream_fused(erasure, writers, reader, targets,
+                                          total_blocks, codec)
 
-        if (engine == "native" and not _SINGLE_CORE and total_blocks > 2
-                and len(targets) <= erasure.parity_blocks):
-            from . import registry as _registry
-            from ..pipeline import workers as _workers
+            if (engine == "native" and not _SINGLE_CORE and total_blocks > 2
+                    and len(targets) <= erasure.parity_blocks):
+                from . import registry as _registry
+                from ..pipeline import workers as _workers
 
-            wpool = (_workers.armed()
-                     if _registry.supports(erasure.codec_id, "worker")
-                     else None)
-            if wpool is not None:
-                # Worker heal driver (ISSUE 11): per-failure-pattern
-                # batch reconstruct + re-digest in a child interpreter
-                # over pooled shm strips, bitrot verification of the
-                # survivor reads in the pool too — the native-engine
-                # counterpart of the fused device/mesh heal.
-                return _heal_stream_workers(erasure, writers, reader,
-                                            targets, total_blocks, wpool)
+                wpool = (_workers.armed()
+                         if _registry.supports(erasure.codec_id, "worker")
+                         else None)
+                if wpool is not None:
+                    # Worker heal driver (ISSUE 11): per-failure-pattern
+                    # batch reconstruct + re-digest in a child interpreter
+                    # over pooled shm strips, bitrot verification of the
+                    # survivor reads in the pool too — the native-engine
+                    # counterpart of the fused device/mesh heal.
+                    sp.relabel("heal_workers")
+                    return _heal_stream_workers(erasure, writers, reader,
+                                                targets, total_blocks, wpool)
 
-        if _SINGLE_CORE or total_blocks <= 2:
-            # Serial heal consumes (reconstructs + copies) each batch
-            # before the next fan-out: safe to recycle the readers'
-            # buffers.
+            if _SINGLE_CORE or total_blocks <= 2:
+                # Serial heal consumes (reconstructs + copies) each batch
+                # before the next fan-out: safe to recycle the readers'
+                # buffers.
+                for r in readers:
+                    if hasattr(r, "reuse_buffers"):
+                        r.reuse_buffers()
+                sp.relabel("heal_serial")
+                for _ in range(total_blocks):
+                    bufs = reader.read()
+                    write_targets(erasure.reconstruct_targets(bufs, targets))
+                return
+            from ..pipeline import Pipeline, Stage
+
+            sp.relabel("heal_pipelined")
+            pipe = Pipeline(telemetry, [
+                Stage("shard-read", lambda _i: reader.read()),
+                Stage("reconstruct",
+                      lambda bufs: erasure.reconstruct_targets(bufs, targets)),
+            ], queue_depth=2)
+            for shards in pipe.results(range(total_blocks)):
+                write_targets(shards)
+        finally:
             for r in readers:
-                if hasattr(r, "reuse_buffers"):
-                    r.reuse_buffers()
-            for _ in range(total_blocks):
-                bufs = reader.read()
-                write_targets(erasure.reconstruct_targets(bufs, targets))
-            return
-        from ..pipeline import Pipeline, Stage
-
-        pipe = Pipeline(telemetry, [
-            Stage("shard-read", lambda _i: reader.read()),
-            Stage("reconstruct",
-                  lambda bufs: erasure.reconstruct_targets(bufs, targets)),
-        ], queue_depth=2)
-        for shards in pipe.results(range(total_blocks)):
-            write_targets(shards)
-    finally:
-        for r in readers:
-            if hasattr(r, "release_buffers"):
-                r.release_buffers()
+                if hasattr(r, "release_buffers"):
+                    r.release_buffers()
 
 
 # Blocks per fused heal-reconstruction dispatch; matches the read-side
@@ -1871,8 +1894,7 @@ def _heal_stream_fused(erasure: Erasure, writers: list, reader,
     def flush(p) -> None:
         from ..pipeline.buffers import copy_add
 
-        rebuilt = np.asarray(p[0])  # D2H already started at dispatch
-        digs = np.asarray(p[1]) if p[1] is not None else None
+        rebuilt, digs = _to_host(*p)  # D2H already started at dispatch
         for bi in range(rebuilt.shape[0]):
             for t_i, t in enumerate(targets):
                 w = writers[t]

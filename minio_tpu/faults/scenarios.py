@@ -1478,16 +1478,19 @@ def _span_p99s(metrics) -> dict:
     import re
 
     pat = re.compile(
-        r'^mtpu_span_seconds_bucket\{kind="([^"]+)",le="([^"]+)"\} (\d+)$',
+        r'^mtpu_span_seconds_bucket\{kind="([^"]+)"(?:,op="[^"]*")?,'
+        r'le="([^"]+)"\} (\d+)$',
         re.M,
     )
-    buckets: dict[str, list[tuple[float, int]]] = {}
+    # one series per (kind, op): a kind's buckets are summed over its ops
+    sums: dict[str, dict[float, int]] = {}
     for kind, le, cum in pat.findall(metrics.render_prometheus()):
         bound = float("inf") if le == "+Inf" else float(le)
-        buckets.setdefault(kind, []).append((bound, int(cum)))
+        by_bound = sums.setdefault(kind, {})
+        by_bound[bound] = by_bound.get(bound, 0) + int(cum)
     out: dict[str, float] = {}
-    for kind, bs in sorted(buckets.items()):
-        bs.sort(key=lambda t: t[0])
+    for kind, by_bound in sorted(sums.items()):
+        bs = sorted(by_bound.items())
         total = bs[-1][1]
         if not total:
             continue

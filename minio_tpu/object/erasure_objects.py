@@ -66,6 +66,7 @@ _obj_pool = ThreadPoolExecutor(max_workers=64, thread_name_prefix="mtpu-obj")
 
 from ..observability import carry as _obs_carry
 from ..observability import ioflow as _ioflow
+from ..observability import spans as _spans
 from . import readtier as _readtier
 from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 from ..utils.fanout import StragglerCompensator
@@ -394,16 +395,21 @@ class ErasureObjects(MultipartMixin):
 
     def _put_object(self, bucket: str, object_: str, reader, size: int,
                     opts: ObjectOptions) -> ObjectInfo:
-        if _SINGLE_CORE:
-            # One core: admit ONE whole PUT at a time. Leaving setup and
-            # commit outside the slot lets queued PUTs steal the GIL
-            # between the encoder's native calls — measured 20% aggregate
-            # loss vs serial. Multicore hosts keep the narrower
-            # encode-only slot (overlapping commit IO there is a win).
-            with _encode_slot():
-                return self._put_object_inner(bucket, object_, reader,
-                                              size, opts)
-        return self._put_object_inner(bucket, object_, reader, size, opts)
+        # The object layer's span: admission, stream and commit are its
+        # children, the rest (set-up, sinks, md5, xl.meta) its self time.
+        with _spans.span("object", "put"):
+            if _SINGLE_CORE:
+                # One core: admit ONE whole PUT at a time. Leaving setup
+                # and commit outside the slot lets queued PUTs steal the
+                # GIL between the encoder's native calls — measured 20%
+                # aggregate loss vs serial. Multicore hosts keep the
+                # narrower encode-only slot (overlapping commit IO there
+                # is a win).
+                with _encode_slot():
+                    return self._put_object_inner(bucket, object_, reader,
+                                                  size, opts)
+            return self._put_object_inner(bucket, object_, reader, size,
+                                          opts)
 
     def _put_object_inner(self, bucket: str, object_: str, reader, size: int,
                           opts: ObjectOptions) -> ObjectInfo:
@@ -569,7 +575,10 @@ class ErasureObjects(MultipartMixin):
         # for every disk: a drive hung in rename_data is detached (its
         # errs slot becomes a timeout) and the missed commit heals via
         # the MRF queue below.
-        _quorum_fanout(commit, n, disks_by_shard, errs, write_quorum)
+        # The disk ops run on the fan-out pool (inline on one core, where
+        # they are this thread's mirrored leaves instead of the commit).
+        with _spans.span("commit", mirror=not _SINGLE_CORE):
+            _quorum_fanout(commit, n, disks_by_shard, errs, write_quorum)
         err = reduce_write_quorum_errs(errs, OBJECT_OP_IGNORED_ERRS, write_quorum)
         if err is not None:
             # Undo the renames that DID land (ref undoRename /
@@ -1120,10 +1129,16 @@ class ErasureObjects(MultipartMixin):
         # Pace slot BEFORE the object lock: a heal yielding to
         # foreground pressure must not do so while holding the write
         # lock a foreground PUT of the same object needs.
-        with _ioflow.tag("heal", bucket=bucket), _heal_slot(), \
+        # A heal sequence's thread carries no request: the heal is its
+        # own root (background: it stays out of the S3 requests' p99).
+        # Under an S3 trace the outer root is kept.
+        with _spans.request_trace("heal_object", background=True,
+                                  path=f"/{bucket}/{object_}"), \
+                _ioflow.tag("heal", bucket=bucket), _heal_slot(), \
                 self._locked_write(bucket, object_):
-            out = self._heal_object(bucket, object_, version_id,
-                                    remove_dangling)
+            with _spans.span("object", "heal"):
+                out = self._heal_object(bucket, object_, version_id,
+                                        remove_dangling)
             _readtier.invalidate(bucket, object_)
             return out
 
@@ -1311,27 +1326,29 @@ class ErasureObjects(MultipartMixin):
                     else:
                         sinks[s].close()
 
-        # Commit healed shards + metadata on stale disks.
+        # Commit healed shards + metadata on stale disks, one after the
+        # other on this thread: its disk spans are the mirrored leaves.
         healed = []
-        for s in stale_shards:
-            disk = disks_by_shard[s]
-            fi = FileInfo.from_dict(ref_fi.to_dict())
-            fi.volume, fi.name = bucket, object_
-            fi.erasure.index = s + 1
-            if inline:
-                fi.data = healed_inline[s]
-            try:
-                if inline or ref_fi.deleted:
-                    disk.write_metadata(bucket, object_, fi)
-                else:
-                    fi.data = {}
-                    disk.rename_data(
-                        SYSTEM_META_BUCKET, self._tmp_path(tmp_id), fi,
-                        bucket, object_,
-                    )
-                healed.append(disk.endpoint())
-            except Exception:  # noqa: BLE001 - heal is best-effort per disk
-                continue
+        with _spans.span("commit"):
+            for s in stale_shards:
+                disk = disks_by_shard[s]
+                fi = FileInfo.from_dict(ref_fi.to_dict())
+                fi.volume, fi.name = bucket, object_
+                fi.erasure.index = s + 1
+                if inline:
+                    fi.data = healed_inline[s]
+                try:
+                    if inline or ref_fi.deleted:
+                        disk.write_metadata(bucket, object_, fi)
+                    else:
+                        fi.data = {}
+                        disk.rename_data(
+                            SYSTEM_META_BUCKET, self._tmp_path(tmp_id), fi,
+                            bucket, object_,
+                        )
+                    healed.append(disk.endpoint())
+                except Exception:  # noqa: BLE001 - best-effort per disk
+                    continue
         return {"healed": healed, "dangling": False}
 
     def heal_bucket(self, bucket: str) -> dict:

@@ -27,13 +27,25 @@ Design (ISSUE 12):
   queryable via the admin `slow-requests` endpoint. Capture is the
   SLOW path — fast requests never pay more than the ring appends.
 
-- **Export** — every span observes `mtpu_span_seconds{kind=...}` (the
-  registry's log-spaced latency buckets) when a registry is installed;
-  finished trees also stream to `mc admin trace`-style consumers that
-  subscribed with `?spans=true` (TraceHub.publish_spans), and the
-  exemplar store answers the admin query. Device/mesh dispatch deltas
-  from the engines' existing STATS counters ride along on each tree so
-  a slow PUT shows how many fused dispatches it overlapped.
+- **Export** — every span observes `mtpu_span_seconds{kind=...,op=...}`
+  (the registry's log-spaced latency buckets; `op` is the root's API
+  name, so a cell that mixes operations reads each apart) when a
+  registry is installed; finished trees also stream to `mc admin
+  trace`-style consumers that subscribed with `?spans=true`
+  (TraceHub.publish_spans), and the exemplar store answers the admin
+  query. The tree's own `device-call` spans say how many fused
+  dispatches the request made.
+
+- **The profiler's clock** — leaf kinds (`MIRRORED`; through `twin()`
+  the sites that time themselves: `disk`, `stage-wait`, a `stage` that
+  holds no mirrored child; a `commit` whose caller says the same) are
+  mirrored as `jax.profiler.TraceAnnotation("mtpu:<kind>[ <tag>]")`,
+  so a `jax.profiler` trace names the host phase under each device idle
+  gap. The class is looked up through `sys.modules` (this module never
+  imports jax) and costs one flag test while no session is active. On
+  one thread mirrored annotations never nest: the reader that labels a
+  gap takes the event with the longest overlap, and an outer one would
+  swallow every gap under it.
 
 Always-on: `MTPU_TRACE=0` (or off/false/no) disarms the whole plane —
 `request_trace` then yields no context and every instrumentation site
@@ -45,6 +57,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -52,14 +65,16 @@ from collections import deque
 # Span series contributed to the metrics_v2 descriptor catalog.
 SPAN_DESCRIPTORS: list[tuple[str, str, str]] = [
     ("span_seconds", "histogram",
-     "Request-span latency by span kind (admission/stage/worker/"
-     "fanout/disk/request)"),
+     "Request-span latency by span kind (request/body-read/admission/"
+     "object/commit/stream/stage/device-h2d/device-call/device-wait/"
+     "worker/fanout/disk) and by op, the root's API name"),
     ("trace_slow_captures_total", "counter",
      "Slow-request span trees captured into the exemplar store"),
 ]
 
 RING_RECORDS = 1024        # per-thread ring slots (fixed-size records)
 SLOW_STORE_CAP = 64        # retained slow-request exemplars
+SLOW_BACKGROUND_CAP = 8    # and, apart from them, of background roots
 P99_WINDOW = 512           # request durations feeding the auto threshold
 P99_RECALC_EVERY = 32      # recompute cadence (finishes per recompute)
 MAX_TREE_SPANS = 2048      # exemplar size bound (ring scan result cap)
@@ -166,19 +181,23 @@ _trace_ids = itertools.count(1)
 class TraceCtx:
     """One request's trace: the id, a process-unique span-id allocator
     (itertools.count — safe under concurrent stage threads), and the
-    request-entry metadata the exemplar/stream entry carries."""
+    request-entry metadata the exemplar/stream entry carries. The label
+    is the root's API name: every span of the trace observes under it
+    (`op`). A `background` root (a heal) stays out of the running p99
+    that decides which S3 requests are slow."""
 
     __slots__ = ("trace_id", "label", "meta", "start_ns", "root_id",
-                 "_ids", "stats0", "error")
+                 "_ids", "error", "background")
 
-    def __init__(self, label: str, meta: dict | None = None):
+    def __init__(self, label: str, meta: dict | None = None,
+                 background: bool = False):
         self.trace_id = next(_trace_ids)
         self.label = label
         self.meta = meta or {}
+        self.background = background
         self.start_ns = time.monotonic_ns()
         self._ids = itertools.count(1)
         self.root_id = next(self._ids)
-        self.stats0 = _engine_stats()
         self.error = ""
 
     def alloc(self) -> int:
@@ -255,10 +274,10 @@ def bound(carrier, fn):
 # ---------------------------------------------------------------------------
 # recording
 
-def _observe(kind: str, dur_ns: int) -> None:
+def _observe(ctx: TraceCtx, kind: str, dur_ns: int) -> None:
     reg = _reg()
     if reg is not None:
-        reg.observe("span_seconds", dur_ns / 1e9, kind=kind)
+        reg.observe("span_seconds", dur_ns / 1e9, kind=kind, op=ctx.label)
 
 
 def record(kind: str, label: str, dur_ns: int,
@@ -266,7 +285,9 @@ def record(kind: str, label: str, dur_ns: int,
     """Record one finished leaf span under the current parent (the
     shape for sites that already measured their own duration: executor
     stage timings, disk-op wrappers, worker child exec-ns, and
-    zero-duration event marks like hedge/straggler-detach)."""
+    zero-duration event marks like hedge/straggler-detach). An
+    annotation has to be open while the time passes: such a site opens
+    its `twin()` itself."""
     ctx = _trace_var.get()
     if ctx is None:
         return
@@ -277,7 +298,51 @@ def record(kind: str, label: str, dur_ns: int,
         ctx.trace_id, ctx.alloc(), _parent_var.get(), kind, label,
         start_ns, dur_ns, threading.current_thread().name,
     ))
-    _observe(kind, dur_ns)
+    _observe(ctx, kind, dur_ns)
+
+
+# Kinds that hold no other span on their thread, and so go onto the
+# profiler's clock wherever `span()` opens them (`stage`, `stage-wait`
+# and `disk` go there through `twin()`). Kinds that are always shorter
+# than 100 us are left out: the trace's reader drops such events.
+MIRRORED = frozenset((
+    "body-read", "admission", "device-h2d", "device-call", "device-wait",
+))
+
+
+class _Twin:
+    """An open annotation on the profiler's clock; closing it frees the
+    thread for the next one."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        _tls.mirrored = False
+        self._ann.__exit__(*exc)
+        return False
+
+
+def _annotation(kind: str, tag: str) -> _Twin | None:
+    """An entered `jax.profiler.TraceAnnotation("mtpu:<kind>[ <tag>]")`,
+    or None: jax is not loaded, no profiler session is active, or this
+    thread already has one open (mirrored annotations never nest on a
+    thread)."""
+    jax = sys.modules.get("jax")
+    if jax is None or getattr(_tls, "mirrored", False):
+        return None
+    cls = jax.profiler.TraceAnnotation
+    if not cls.is_enabled():
+        return None
+    ann = cls(f"mtpu:{kind} {tag}" if tag else f"mtpu:{kind}")
+    ann.__enter__()
+    _tls.mirrored = True
+    return _Twin(ann)
 
 
 class _NullSpan:
@@ -293,16 +358,18 @@ class _NullSpan:
         pass
 
 
-_NULL = _NullSpan()
+NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_ctx", "kind", "label", "_sid", "_token", "_t0")
+    __slots__ = ("_ctx", "kind", "label", "_mirror", "_sid", "_token",
+                 "_t0", "_twin")
 
-    def __init__(self, ctx: TraceCtx, kind: str, label: str):
+    def __init__(self, ctx: TraceCtx, kind: str, label: str, mirror: bool):
         self._ctx = ctx
         self.kind = kind
         self.label = label
+        self._mirror = mirror
 
     def relabel(self, label: str) -> None:
         self.label = label
@@ -310,27 +377,45 @@ class _Span:
     def __enter__(self):
         self._sid = self._ctx.alloc()
         self._token = _parent_var.set(self._sid)
+        self._twin = (_annotation(self.kind, self.label) if self._mirror
+                      else None)
         self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *exc):
         end = time.monotonic_ns()
+        if self._twin is not None:
+            self._twin.__exit__(*exc)
         _parent_var.reset(self._token)
         _ring().append((
             self._ctx.trace_id, self._sid, _parent_var.get(), self.kind,
             self.label, self._t0, end - self._t0,
             threading.current_thread().name,
         ))
-        _observe(self.kind, end - self._t0)
+        _observe(self._ctx, self.kind, end - self._t0)
         return False
 
 
-def span(kind: str, label: str = ""):
-    """Nested span context manager; cheap no-op outside a trace."""
+def span(kind: str, label: str = "", *, mirror: bool | None = None):
+    """Nested span context manager; cheap no-op outside a trace.
+    `mirror` is for the caller of a `commit` that knows the span holds
+    no mirrored child on its thread."""
     ctx = _trace_var.get()
     if ctx is None:
-        return _NULL
-    return _Span(ctx, kind, label)
+        return NULL
+    if mirror is None:
+        mirror = kind in MIRRORED
+    return _Span(ctx, kind, label, mirror)
+
+
+def twin(kind: str, tag: str = ""):
+    """The twin on the profiler's clock alone, for a site that times
+    itself and hands the duration to `record()` (`stage`, `stage-wait`,
+    `disk`): NULL outside a trace and while no profiler session is
+    active, so such a site pays the plane one record and no more."""
+    if _trace_var.get() is None:
+        return NULL
+    return _annotation(kind, tag) or NULL
 
 
 # ---------------------------------------------------------------------------
@@ -338,6 +423,9 @@ def span(kind: str, label: str = ""):
 
 _slow_mu = threading.Lock()
 _slow_store: deque = deque(maxlen=SLOW_STORE_CAP)  # guarded-by: _slow_mu
+# A heal outlasts any threshold S3 requests set: its trees get a few
+# slots of their own, so that a heal sequence evicts no S3 exemplar.
+_slow_bg: deque = deque(maxlen=SLOW_BACKGROUND_CAP)  # guarded-by: _slow_mu
 _durations_ms: deque = deque(maxlen=P99_WINDOW)    # guarded-by: _slow_mu
 _finish_count = 0                                  # guarded-by: _slow_mu
 _auto_threshold_ms = float("inf")                  # guarded-by: _slow_mu
@@ -398,27 +486,6 @@ def _collect_tree(ctx: TraceCtx) -> list[dict]:
     return spans
 
 
-def _engine_stats() -> dict:
-    """Dispatch/robustness counters from the engines' existing STATS —
-    read only from modules ALREADY imported (never trigger a jax
-    import from the request path)."""
-    import sys
-
-    out: dict = {}
-    st = sys.modules.get("minio_tpu.erasure.streaming")
-    if st is not None:
-        out["hedged_reads"] = st.STATS.get("hedged_reads_total", 0)
-        out["fanout_stragglers"] = st.STATS.get(
-            "fanout_stragglers_total", 0)
-    de = sys.modules.get("minio_tpu.erasure.device_engine")
-    if de is not None:
-        out["device_dispatches"] = de.STATS.get("dispatches", 0)
-    pm = sys.modules.get("minio_tpu.parallel.metrics")
-    if pm is not None:
-        out["mesh_dispatches"] = pm.STATS.get("mesh_dispatches_total", 0)
-    return out
-
-
 def _finish(ctx: TraceCtx) -> None:
     end = time.monotonic_ns()
     dur_ns = end - ctx.start_ns
@@ -427,30 +494,28 @@ def _finish(ctx: TraceCtx) -> None:
         ctx.trace_id, ctx.root_id, 0, "request", ctx.label,
         ctx.start_ns, dur_ns, threading.current_thread().name,
     ))
-    _observe("request", dur_ns)
+    _observe(ctx, "request", dur_ns)
     dur_ms = dur_ns / 1e6
     threshold = slow_threshold_ms()
-    _note_duration(dur_ms)
+    if not ctx.background:
+        _note_duration(dur_ms)
     hub = _hub
     want_stream = hub is not None and getattr(hub, "any_spans", False)
     if dur_ms < threshold and not want_stream:
         return
-    stats1 = _engine_stats()
     entry = {
         "trace_id": ctx.hex_id,
         "api": ctx.label,
         "duration_ms": round(dur_ms, 3),
         "time_ns": time.time_ns(),
         "error": ctx.error,
-        "stats": {
-            k: stats1.get(k, 0) - ctx.stats0.get(k, 0) for k in stats1
-        },
         "spans": _collect_tree(ctx),
     }
     entry.update(ctx.meta)
     if dur_ms >= threshold:
         with _slow_mu:
-            _slow_store.append(entry)
+            (_slow_bg if ctx.background
+             else _slow_store).append(entry)
         reg = _reg()
         if reg is not None:
             reg.inc("trace_slow_captures_total")
@@ -459,9 +524,11 @@ def _finish(ctx: TraceCtx) -> None:
 
 
 class request_trace:
-    """Root span for one request, entered at S3 handler dispatch. Not
-    reentrant by design: a request already carrying a trace (internal
-    self-calls) keeps the OUTER trace.
+    """Root span for one request, entered at S3 handler dispatch, and
+    for one object's heal (`background=True`: the heal sequence's thread
+    carries no request). Not reentrant by design: a request already
+    carrying a trace (internal self-calls, a heal an S3 request asked
+    for) keeps the OUTER trace.
 
     Streaming responses: the handler RETURNS before the body streams
     (decode runs inside the response writer), so the API layer calls
@@ -469,12 +536,13 @@ class request_trace:
     trace with `resume(rt)` around the body-stream callable — the root
     span then covers the whole request, dispatch through last byte."""
 
-    __slots__ = ("_label", "_meta", "_tok_t", "_tok_p", "_ctx", "_tid",
-                 "deferred", "_io_holder", "_identity")
+    __slots__ = ("_label", "_meta", "_background", "_tok_t", "_tok_p",
+                 "_ctx", "_tid", "deferred", "_io_holder", "_identity")
 
-    def __init__(self, label: str, **meta):
+    def __init__(self, label: str, *, background: bool = False, **meta):
         self._label = label
         self._meta = meta
+        self._background = background
         self._ctx = None
         self.deferred = False
         self._io_holder = None
@@ -505,7 +573,7 @@ class request_trace:
     def __enter__(self) -> TraceCtx | None:
         if not enabled() or _trace_var.get() is not None:
             return None
-        ctx = TraceCtx(self._label, self._meta)
+        ctx = TraceCtx(self._label, self._meta, self._background)
         self._ctx = ctx
         self._tok_t = _trace_var.set(ctx)
         self._tok_p = _parent_var.set(ctx.root_id)
@@ -602,15 +670,19 @@ class resume:
 # introspection (admin endpoint, tests, bench)
 
 def slow_requests(n: int = SLOW_STORE_CAP) -> list[dict]:
-    """Most recent slow-request exemplars, newest last."""
+    """Most recent slow-request exemplars, background roots among
+    them, newest last."""
     with _slow_mu:
-        return list(_slow_store)[-n:]
+        both = [*_slow_store, *_slow_bg]
+    both.sort(key=lambda e: e["time_ns"])
+    return both[-n:]
 
 
 def clear_slow_requests() -> int:
     with _slow_mu:
-        n = len(_slow_store)
+        n = len(_slow_store) + len(_slow_bg)
         _slow_store.clear()
+        _slow_bg.clear()
         return n
 
 
@@ -626,6 +698,7 @@ def reset() -> None:
             ring.n = 0
     with _slow_mu:
         _slow_store.clear()
+        _slow_bg.clear()
         _durations_ms.clear()
         _finish_count = 0
         _auto_threshold_ms = float("inf")

@@ -34,6 +34,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import spans as _spans
 from ..utils.jaxenv import place_compile_cache
 
 place_compile_cache()
@@ -162,11 +163,13 @@ class HostFeed:
 
         if self._accept is not None and not self._accept(batch):
             return batch
-        if self._sharding is not None:
-            dev = jax.device_put(batch, self._sharding)
-        else:
-            dev = jax.device_put(batch)
-        dev.block_until_ready()
+        with _spans.span("device-h2d",
+                         "mesh" if self._sharding is not None else "device"):
+            if self._sharding is not None:
+                dev = jax.device_put(batch, self._sharding)
+            else:
+                dev = jax.device_put(batch)
+            dev.block_until_ready()
         return dev
 
 

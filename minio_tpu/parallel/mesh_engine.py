@@ -49,6 +49,7 @@ from ..erasure.device_engine import (
     _is_device_array,
     _quiet_cpu_donation_warning,
 )
+from ..observability import spans as _spans
 from . import metrics as mesh_metrics
 from . import placement
 
@@ -167,7 +168,8 @@ class MeshCodec:
             b = np.concatenate(
                 [b, np.zeros((pad,) + b.shape[1:], dtype=np.uint8)]
             )
-        return jax.device_put(b, self.data_spec), n
+        with _spans.span("device-h2d", "mesh"):
+            return jax.device_put(b, self.data_spec), n
 
     def host_feed(self):
         """The pipelined driver's H2D stage for this mesh: dp-shards the
@@ -244,10 +246,9 @@ class MeshCodec:
             + (b_padded * self.n * 32 if with_hashes else 0),
             stripe_bytes=b_padded * s,
         )
-        if with_hashes:
-            parity, digests = self._dispatch(fn, bitmat, dev)
-        else:
-            parity, digests = self._dispatch(fn, bitmat, dev), None
+        with _spans.span("device-call", "enc"):
+            out = self._dispatch(fn, bitmat, dev)
+        parity, digests = out if with_hashes else (out, None)
         if n_rows != b_padded:
             parity = parity[:n_rows]
             digests = digests[:n_rows] if digests is not None else None
@@ -338,10 +339,9 @@ class MeshCodec:
             + (b_padded * len(targets) * 32 if with_hashes else 0),
             stripe_bytes=0,
         )
-        if with_hashes:
-            rebuilt, digests = self._dispatch(fn, bitmat, dev)
-        else:
-            rebuilt, digests = self._dispatch(fn, bitmat, dev), None
+        with _spans.span("device-call", "rec"):
+            out = self._dispatch(fn, bitmat, dev)
+        rebuilt, digests = out if with_hashes else (out, None)
         if n_rows != b_padded:
             rebuilt = rebuilt[:n_rows]
             digests = digests[:n_rows] if digests is not None else None

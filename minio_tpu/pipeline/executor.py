@@ -145,7 +145,8 @@ class Pipeline:
         traced = _spans.current() is not None
         while True:
             t0 = time.perf_counter()
-            item = self._get(in_q)
+            with _spans.twin("stage-wait", span_label):
+                item = self._get(in_q)
             wait = time.perf_counter() - t0
             stats.wait_s += wait
             if item is CANCELLED:
@@ -161,7 +162,11 @@ class Pipeline:
                 _spans.record("stage-wait", span_label, int(wait * 1e9))
             try:
                 t0 = time.perf_counter()
-                out = stage.fn(item)
+                if stage.leaf:
+                    with _spans.twin("stage", span_label):
+                        out = stage.fn(item)
+                else:
+                    out = stage.fn(item)
                 busy = time.perf_counter() - t0
                 stats.busy_s += busy
                 if traced:

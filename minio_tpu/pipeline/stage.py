@@ -45,11 +45,15 @@ class Stage:
     bytes_of  -- optional item -> int used for the stage's byte counter
                  (measured on the stage's OUTPUT so expansion stages
                  like bitrot framing report what they produced).
+    leaf      -- the stage opens no mirrored span on its thread (no
+                 device-*, disk, admission, body-read), so its own span
+                 goes onto the profiler's clock (observability/spans).
     """
 
     name: str
     fn: Callable
     bytes_of: Callable | None = None
+    leaf: bool = False
     # Filled by the executor per run; kept on the stage so callers can
     # read a finished pipeline's per-stage numbers without the registry.
     stats: "StageStats" = field(default_factory=lambda: StageStats())

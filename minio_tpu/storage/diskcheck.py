@@ -308,10 +308,13 @@ class MetricsDisk:
                     return self._call_guarded(op, fn, args, kwargs)
                 # Per-disk op latency on the request's span timeline —
                 # the leaf level of the attribution tree (which DISK a
-                # stalled fan-out was actually waiting on).
+                # stalled fan-out was actually waiting on); on the
+                # profiler's clock under the op alone, so that sixteen
+                # drives sum to one row.
                 t0s = time.monotonic_ns()
                 try:
-                    return self._call_guarded(op, fn, args, kwargs)
+                    with _spans.twin("disk", op):
+                        return self._call_guarded(op, fn, args, kwargs)
                 finally:
                     _spans.record(
                         "disk", f"{op}:{self._disk.endpoint()}",
@@ -329,7 +332,8 @@ class MetricsDisk:
                 )
             t0 = time.perf_counter()
             try:
-                out = fn(*args, **kwargs)
+                with _spans.twin("disk", op):
+                    out = fn(*args, **kwargs)
             except Exception:
                 if guarded:
                     # A SLOW failure (stall that eventually errored) is

@@ -128,7 +128,7 @@ def main(tmp: str) -> None:
         "pool": pool.snapshot(),
         "trees": [
             {"api": t["api"], "duration_ms": t["duration_ms"],
-             "stats": t["stats"], "spans": t["spans"]}
+             "spans": t["spans"]}
             for t in trees
         ],
         "admin": json.loads(admin_body),

@@ -555,8 +555,8 @@ def test_span_p99_extraction_from_histogram():
     m = Metrics()
     for _ in range(10):
         m.observe("span_seconds", 0.003, kind="disk")
-    for _ in range(90):
-        m.observe("span_seconds", 0.7, kind="disk")
+    for _ in range(90):     # a kind's ops are summed
+        m.observe("span_seconds", 0.7, kind="disk", op="put_object")
     for _ in range(50):
         m.observe("span_seconds", 0.002, kind="fanout")
     p = scenarios._span_p99s(m)

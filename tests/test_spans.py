@@ -130,8 +130,9 @@ def test_slow_store_is_bounded():
 
 
 def test_exposition_has_span_kind_histograms():
-    """mtpu_span_seconds{kind=...} appears for admission/stage/fanout
-    after real (1-core-safe) traffic through the instrumented seams."""
+    """mtpu_span_seconds{kind=...,op=...} appears for admission/stage/
+    fanout after real (1-core-safe) traffic through the instrumented
+    seams; op is the root's API name."""
     import threading as _th
 
     from minio_tpu.pipeline import Pipeline, Stage
@@ -153,7 +154,8 @@ def test_exposition_has_span_kind_histograms():
         quorum_wait(cv, set(), lambda: 0, 0, 0.01, 0.0)
     text = reg.render_prometheus()
     for kind in ("admission", "stage", "fanout", "request"):
-        assert f'mtpu_span_seconds_count{{kind="{kind}"}}' in text, kind
+        assert (f'mtpu_span_seconds_count{{kind="{kind}",'
+                f'op="put_object"}}') in text, kind
     assert reg.counter_value("trace_slow_captures_total") >= 1
 
 
@@ -193,13 +195,34 @@ def test_admin_slow_requests_endpoint_shape():
     assert spans.slow_requests() == []
 
 
-def test_engine_stats_deltas_ride_on_trees():
-    from minio_tpu.erasure import streaming
+def test_put_tree_holds_one_device_call_per_dispatch(tmp_path, monkeypatch):
+    """The tree's own device-call spans say how many fused dispatches
+    the request made (what the process-wide counters' delta could not,
+    under concurrency): as many as the dispatch counter rose."""
+    import io
 
-    with spans.request_trace("get_object"):
-        streaming.record_stat("hedged_reads_total", 2)
+    from minio_tpu.erasure import registry
+    from minio_tpu.object.erasure_objects import ErasureObjects
+    from minio_tpu.storage.local import LocalStorage
+
+    monkeypatch.setenv("MTPU_ENCODE_ENGINE", "device")
+    monkeypatch.setenv("MTPU_CODEC", "dense-gf8")    # `auto` ranks by a probe
+    reg = Metrics()
+    monkeypatch.setattr(registry, "_metrics", reg)
+    es = ErasureObjects(
+        [LocalStorage(str(tmp_path / f"d{i}"), endpoint=f"d{i}")
+         for i in range(4)], default_parity=2)
+    es.make_bucket("b")
+    payload = os.urandom(9 << 20)        # two batches: 8 blocks and 1
+    with spans.request_trace("put_object"):
+        es.put_object("b", "k", io.BytesIO(payload), len(payload))
     tree = spans.slow_requests()[-1]
-    assert tree["stats"]["hedged_reads"] == 2
+    assert "stats" not in tree
+    calls = [s for s in tree["spans"] if s["kind"] == "device-call"]
+    rose = reg.counter_value("mtpu_codec_dispatch_total",
+                             codec="dense-gf8", engine="device")
+    assert len(calls) == rose == 2, (calls, rose)
+    assert {s["label"] for s in calls} == {"enc"}
 
 
 def test_defer_resume_reenters_ioflow_tag_and_admission_identity():

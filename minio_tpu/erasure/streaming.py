@@ -551,21 +551,17 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
             # host-resident in the pooled buffer.
             parity, hashes = _to_host(parity_f, hashes_f)
             n = parity.shape[0]
-            host = buf[:n].reshape(n, k, shard)
-            for bi in range(n):
-                blocks = (
-                    [host[bi, j] for j in range(erasure.data_blocks)]
-                    + [parity[bi, j]
-                       for j in range(erasure.parity_blocks)]
-                )
-                digests = (
-                    # copy-ok: meta (32-byte digests, not payload)
-                    [hashes[bi, j].tobytes()
-                     for j in range(erasure.total_shards)]
-                    if hashes is not None else None
-                )
-                writer.write(blocks, digests)
-                out += block_size
+            # One fan-out a batch, not one a block: each drive's task
+            # ships its shard's n frames ([digest||chunk] pairs out of
+            # the strip buffer, under the device's digests where it made
+            # them) in one writev. A fan-out's cost is its sixteen
+            # thread hand-overs, whatever they carry (PERF.md §6, PR 28).
+            writer.write_frame_batches(
+                buf, parity, n, k, erasure.parity_blocks, shard,
+                digests=(None if hashes is None
+                         else hashes.transpose(1, 0, 2)),
+            )
+            out += n * block_size
         if buf is not None:
             pool.release(buf)
             item[0] = None

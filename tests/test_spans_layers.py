@@ -167,6 +167,26 @@ def test_put_10mib_12p4_tree_holds_every_layer(plane):
     assert [s["kind"] for s in phases] == ["admission", "stream", "commit"]
 
 
+def test_put_10mib_fans_its_shard_writes_out_once_a_batch(plane):
+    """The pipelined device driver hands each drive its shard's frames of
+    a whole batch in one task (ISSUE 28): a 10 MiB PUT waits for three
+    quorums (the 8-block batch, the 2-block batch, the commit), not for
+    eleven, and what it wrote reads back bitrot-verified."""
+    if SINGLE_CORE:
+        pytest.skip("the serial driver writes block by block, inline")
+    n16 = plane["n16"]
+    body = os.urandom(10 * MIB)
+    with spans.request_trace("put_object"):
+        n16.es.put_object("b", "batched", LimitedReader(io.BytesIO(body),
+                                                        len(body)), len(body))
+    tree = spans.slow_requests()[-1]
+    waits = [s for s in _kinds(tree)["fanout"] if s["label"] == "quorum-wait"]
+    assert len(waits) == 3, [s["label"] for s in _kinds(tree)["fanout"]]
+    sink = io.BytesIO()
+    n16.es.get_object("b", "batched", sink)
+    assert sink.getvalue() == body
+
+
 def test_put_1mib_2p2_runs_inline_and_is_not_dark(plane):
     n4, reg = plane["n4"], plane["reg"]
     n4.put("warm", MIB)

@@ -1,15 +1,15 @@
 """Mesh serving engine: the multi-chip erasure plane as a production
 PUT/GET/heal path.
 
-`parallel/sharded.ShardedErasure` proved the SPMD data plane correct on
-3 mesh shapes (MULTICHIP_r05) but was reachable only from the
-`dryrun_multichip` demo. This module packages the same lane-sharded
-GF encode / reconstruct / device bitrot digests behind EXACTLY the
-async-codec seams the fused device engine already serves
-(`erasure/device_engine.DeviceCodec`), so the streaming drivers in
-`erasure/streaming.py` — HostFeed-staged, double-buffered, quorum-
-fan-out on the write side — run on a mesh without a line of driver
-duplication:
+The lane-sharded GF encode / reconstruct / device bitrot digests of
+`parallel/sharded.ShardedErasure` behind EXACTLY the async-codec seams
+the fused device engine serves (`erasure/device_engine.DeviceCodec`),
+so the streaming drivers in `erasure/streaming.py` — HostFeed-staged,
+double-buffered, quorum-fan-out on the write side — run on a mesh
+without a line of driver duplication. Its proofs: byte-exactness on
+virtual CPU devices (`tests/test_mesh_engine.py`), `chip_smoke.py
+--chips 4`, and the benchmark's cell `n16mesh4-put10m` on a four-chip
+host (dp=1 x lane=4; PERF.md):
 
 - ``encode_async(blocks, with_hashes)`` — ONE pjit dispatch per
   [B, k, S] batch computes the lane-sharded stripe's parity AND the
@@ -102,6 +102,7 @@ class MeshCodec:
         self._lock = threading.Lock()
         self._dev_mats: dict = {}
         self._fns: dict = {}
+        self._gauged: set = set()
         mesh_metrics.record_shape(self.dp, self.lanes, self.n)
 
     # --- cached device operands / compiled functions (one protocol for
@@ -164,6 +165,7 @@ class MeshCodec:
         b = ascontig_counted(blocks, "put.device_stage")
         n = b.shape[0]
         pad = (-n) % self._pad_rows
+        mesh_metrics.record("mesh_padded_blocks_total", pad)
         if pad:
             b = np.concatenate(
                 [b, np.zeros((pad,) + b.shape[1:], dtype=np.uint8)]
@@ -351,8 +353,7 @@ class MeshCodec:
 
     # --- telemetry ---
 
-    @staticmethod
-    def _dispatch(fn, *args):
+    def _dispatch(self, fn, *args):
         """THE collective-call chokepoint: every invocation of a
         compiled mesh program must come through here so
         mesh_dispatches_total counts actual pjit calls — batches are
@@ -361,8 +362,12 @@ class MeshCodec:
         if a future change splits one batch into several collectives."""
         mesh_metrics.record("mesh_dispatches_total")
         out = fn(*args)
-        first = out[0] if isinstance(out, tuple) else out
-        mesh_metrics.record_output_devices(len(first.sharding.device_set))
+        if fn not in self._gauged:
+            # a compiled function's output sharding never changes
+            self._gauged.add(fn)
+            first = out[0] if isinstance(out, tuple) else out
+            mesh_metrics.record_output_devices(
+                len(first.sharding.device_set))
         return out
 
     def _record_batch(self, blocks: int, collective: int,

@@ -36,6 +36,8 @@ MESH_DESCRIPTORS: list[tuple[str, str, str]] = [
      "dp-group batches shipped through the mesh engine"),
     ("mesh_blocks_total", "counter",
      "Erasure blocks encoded/reconstructed on the mesh"),
+    ("mesh_padded_blocks_total", "counter",
+     "Rows of zero padding staged beside them (ragged host batches)"),
     ("mesh_retraces_total", "counter",
      "XLA (re)traces of mesh programs — flat in steady state"),
     ("mesh_collective_bytes_total", "counter",
@@ -54,6 +56,7 @@ STATS = {
     "mesh_dispatches_total": 0,
     "mesh_batches_total": 0,
     "mesh_blocks_total": 0,
+    "mesh_padded_blocks_total": 0,
     "mesh_retraces_total": 0,
     "mesh_collective_bytes_total": 0,
 }

@@ -135,6 +135,46 @@ def test_mesh_reconstruct_matches_host(monkeypatch):
     np.testing.assert_array_equal(digs, _host_digests(rebuilt))
 
 
+@pytest.mark.parametrize("rows,padded", [(8, 0), (2, 6)])
+def test_mesh_at_the_benchmark_cells_geometry(monkeypatch, rows, padded):
+    """`n16mesh4-put10m`'s own geometry and shape (12+4, dp=1 x lane=4:
+    4 of the 16 shards a chip) at the two batches a 10 MiB PUT makes, the
+    full one of 8 rows and the tail of 2 that is padded to 8: parity and
+    all 16 digests are the benchmark's reference's and the one-chip
+    engine's, byte for byte."""
+    from benchmark.harness import reference
+
+    from minio_tpu.erasure import device_engine
+
+    monkeypatch.setenv("MTPU_MESH_SHAPE", "1x4")
+    s = 2731 + 19           # whole 32-byte packets and a remainder
+    codec = mesh_engine.for_geometry(12, 4)
+    assert (codec.dp, codec.lanes, codec._pad_rows) == (1, 4, 8)
+    blocks = np.random.default_rng(rows).integers(
+        0, 256, size=(rows, 12, s), dtype=np.uint8
+    )
+    before = mesh_metrics.stats_snapshot()
+    parity, digests = codec.encode_async(blocks.copy(), with_hashes=True)
+    parity, digests = np.asarray(parity), np.asarray(digests)
+    after = mesh_metrics.stats_snapshot()
+    assert parity.shape == (rows, 4, s) and digests.shape == (rows, 16, 32)
+    assert (after["mesh_padded_blocks_total"]
+            - before["mesh_padded_blocks_total"]) == padded
+    assert after["mesh_blocks_total"] - before["mesh_blocks_total"] == rows
+    assert (after["mesh_collective_bytes_total"]
+            - before["mesh_collective_bytes_total"]) == 8 * (4 * s + 16 * 32)
+    exp = reference.apply_matrix(
+        reference.parity_matrix("dense-gf8", 12, 4), blocks)
+    np.testing.assert_array_equal(parity, exp)
+    np.testing.assert_array_equal(
+        digests,
+        reference.highwayhash256(np.concatenate([blocks, exp], axis=1)))
+    one = device_engine.for_geometry(12, 4)
+    parity1, digests1 = one.encode_async(blocks.copy(), with_hashes=True)
+    np.testing.assert_array_equal(parity, np.asarray(parity1))
+    np.testing.assert_array_equal(digests, np.asarray(digests1))
+
+
 # ---------------------------------------------------------------------------
 # streaming drivers on the mesh engine
 

@@ -111,16 +111,19 @@ def test_encode_stream_device_matches_numpy_engine(monkeypatch, k, m):
         assert a == b, f"shard {i} differs between device and numpy engines"
 
 
-def test_reconstruct_async_matches_oracle():
-    k, m, s = 8, 4, 500
+@pytest.mark.parametrize("k,m", GEOMETRIES)
+def test_reconstruct_async_matches_oracle(k, m):
+    """Fused reconstruct + digests of the rebuilt shards, at the cells'
+    geometries too (12+4, 2+2), over whole packets and a remainder."""
+    s = 500
     codec = device_engine.for_geometry(k, m)
     rng = np.random.default_rng(5)
     data = rng.integers(0, 256, size=(k, s), dtype=np.uint8)
     full = gf.rs_matrix(k, m)
     all_shards = gf_matmul_shards_ref(full, data)  # [k+m, s]
-    dead = (0, 5, 9)  # two data + one parity lane lost
-    present = tuple(i for i in range(k + m) if i not in dead)
-    targets = (0, 5, 9)
+    # data and parity shards lost, as many as the geometry rebuilds
+    targets = (0, 5, k + 1) if m > 2 else (0, k + 1)
+    present = tuple(i for i in range(k + m) if i not in targets)
     src = np.stack([all_shards[list(present[:k])]] * 2)  # batch of 2
     rebuilt_f, digests_f = codec.reconstruct_async(
         src, present, targets, with_hashes=True

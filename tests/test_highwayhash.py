@@ -48,13 +48,70 @@ def test_streaming_matches_oneshot():
     assert h.digest() == hash256(data)
 
 
-@pytest.mark.parametrize("length", [0, 1, 3, 4, 15, 16, 17, 31, 32, 33, 63, 64, 100, 1024, 4096 + 21])
-def test_jax_matches_numpy(length):
-    rng = np.random.default_rng(length)
-    data = rng.integers(0, 256, size=(3, length), dtype=np.uint8)
+# Every remainder 0..31 with no whole packet and with one, the two shard
+# lengths the benchmark's cells run (87,382 = 2,730 packets + 22 bytes at
+# 12+4, 524,288 = 16,384 packets at 2+2), each at a small batch; then the
+# batch shapes the cells run, and one unbatched [L].
+_LENGTHS = [((3,), n) for n in range(64)] + [((2,), 87382), ((1, 2), 524288)]
+_BATCHES = [((8, 16), 1000), ((2, 16), 1000), ((1, 4), 2048), ((8, 2), 1000),
+            ((2, 2), 1000), ((), 333), ((3,), 100), ((3,), 1024),
+            ((3,), 4096 + 21)]
+
+
+@pytest.mark.parametrize(
+    "batch,length", _LENGTHS + _BATCHES,
+    ids=lambda v: "x".join(map(str, v)) or "unbatched"
+    if isinstance(v, tuple) else str(v))
+def test_jax_matches_numpy(batch, length):
+    rng = np.random.default_rng(length + len(batch))
+    data = rng.integers(0, 256, size=batch + (length,), dtype=np.uint8)
     want = hash256_batch(data)
     got = np.asarray(hash256_batch_jax(data))
     np.testing.assert_array_equal(want, got)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs (scan and loop
+    bodies, inner jits) after the equation that holds them."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _eqns(inner)
+
+
+def test_packet_step_is_elementwise_and_the_program_small():
+    """What PR 30's gain rests on, where tier-1 sees it: at the 12+4 cells'
+    shape the scan's body (one packet) holds arithmetic alone, nothing the
+    chip's compiler cannot fuse across, and the whole program is a quarter
+    of the 5,172 equations it was (429 in the body, ten unrolled
+    finalisation rounds outside). The one exception: the step reads its
+    eight words off the leading axis of the packet's [8, B, n] slab, eight
+    static picks that cost the chip an address each (one stacked input
+    measured faster there than eight arrays, each sliced per loop trip)."""
+    import collections
+
+    import jax
+    import jax.numpy as jnp
+
+    from minio_tpu.ops.highwayhash_jax import _build_hash_fn
+
+    length = 87382
+    jaxpr = jax.make_jaxpr(_build_hash_fn(length, MAGIC_KEY))(
+        jax.ShapeDtypeStruct((8, 16, length), jnp.uint8)).jaxpr
+    eqns = list(_eqns(jaxpr))
+    scans = [e for e in eqns if e.primitive.name == "scan"
+             and e.params["length"] == length // 32]
+    assert len(scans) == 1
+    body = collections.Counter(
+        e.primitive.name for e in _eqns(scans[0].params["jaxpr"].jaxpr))
+    assert (body.pop("slice"), body.pop("squeeze")) == (8, 8)
+    assert not set(body) & {
+        "gather", "concatenate", "reshape", "transpose", "dynamic_slice",
+        "convert_element_type", "broadcast_in_dim", "iota",
+    }, sorted(body)
+    assert len(eqns) < 1500, len(eqns)
 
 
 def test_batch_consistent_with_single():

@@ -175,6 +175,36 @@ def test_mesh_at_the_benchmark_cells_geometry(monkeypatch, rows, padded):
     np.testing.assert_array_equal(digests, np.asarray(digests1))
 
 
+def test_mesh_digests_stay_lane_local(monkeypatch):
+    """The compiled 12+4 encode of the 1x4 mesh holds the collectives it
+    held before the digest scan was rewritten (PR 30) and no more: parity
+    from lane 3 to the others and back (collective-permute, all-gather)
+    and one all-gather of the 32-byte digests. The stripe is never
+    gathered: each chip hashes its own 4 of the 16 shards."""
+    import re
+    from collections import Counter
+
+    monkeypatch.setenv("MTPU_MESH_SHAPE", "1x4")
+    s = 2731 + 19
+    codec = mesh_engine.for_geometry(12, 4)
+    blocks = np.random.default_rng(3).integers(
+        0, 256, size=(8, 12, s), dtype=np.uint8)
+    _, digests = codec.encode_async(blocks.copy(), with_hashes=True)
+    np.asarray(digests)
+    fn = codec._fns[("enc", True, blocks.shape)]
+    dev, _ = codec._stage(blocks.copy())
+    hlo = fn.lower(codec._dev_mat("parity", codec._parity_bits_np),
+                   dev).compile().as_text()
+    found = re.findall(
+        r"= (\S+) (all-gather|all-reduce|collective-permute|all-to-all"
+        r"|reduce-scatter)(?:-start)?\(", hlo)
+    assert Counter(op for _, op in found) == {
+        "all-gather": 3, "collective-permute": 3}
+    shapes = sorted(shape.split("{")[0] for shape, _ in found)
+    assert shapes == ["u8[8,1,2750]"] * 3 + ["u8[8,16,32]"] + [
+        "u8[8,4,2750]"] * 2
+
+
 # ---------------------------------------------------------------------------
 # streaming drivers on the mesh engine
 

@@ -48,6 +48,14 @@ def _on_signal(signum, frame):
     raise Stop(f"signal {signal.Signals(signum).name}")
 
 
+def trace_cue_at(traffic: dict, t0: float, seconds: float) -> float:
+    """When the traced slice is cued: at the mix's `trace_cue_share` of
+    the window, the middle where the mix names none. A mix whose work may
+    end before the close (a heal's backlog) cues it early, where a
+    sequence several times faster still holds the device."""
+    return t0 + seconds * float(traffic.get("trace_cue_share", 0.5))
+
+
 def _wait_file(path: str, timeout: float, what: str) -> dict:
     t0 = time.monotonic()
     while time.monotonic() - t0 < timeout:
@@ -165,12 +173,13 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
     load.setup()
     before = cl.counters(s3.metrics())
 
-    # The traced slice: cued from here at the middle of the window, timed
+    # The traced slice: cued from here at the mix's share of the window
+    # (`trace_cue_at`: the middle, unless the mix says otherwise), timed
     # and traced in the child, which takes a slice again where the device
     # did nothing in it, as long as the slice ends before the close. The
     # tracer then writes the one that holds the device (a minute for half
     # a million events) in the server's process while the window goes on:
-    # a traced run's latencies are those of the first half.
+    # a traced run's latencies are those answered before the cue.
     slices = [m["reader"] for m in cell.per_layer
               if "trace_slice_s" in m["reader"]]
     # Every chip writes its own events, so the slice is cut by their number.
@@ -181,7 +190,8 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
 
     def cue_trace(t0: float):
         def work():
-            time.sleep(max(0.0, t0 + seconds / 2 - time.monotonic()))
+            time.sleep(max(0.0, trace_cue_at(cell.traffic, t0, seconds)
+                           - time.monotonic()))
             until = t0 + seconds - 0.5 + time.time() - time.monotonic()
             child.cue(f"trace {trace_dir} {slice_s} {until} {done_path}")
         if slice_s > 0:
@@ -195,6 +205,8 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
     failed = [o for o in win.ops if not o.ok]
     say(f"window closed: {len(win.ops)} requests, {len(failed)} failed"
         + (f"; heal polls {win.heal_polls[-1]}" if win.heal_polls else ""))
+    if win.heal_polls:
+        _say_heal_sequence(win, seconds)
     for o in failed[:5]:
         say(f"  {o.kind} {o.key}: {o.error or o.wrong}")
     if win.generator_late_s:
@@ -211,7 +223,8 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
         # the child's wall clock, on this process's monotonic one
         off = time.time() - time.monotonic()
         span = (done["start"] - off, done["stop"] - off)
-        say(f"traced {span[1] - span[0]:.3f}s ending "
+        say(f"traced {span[1] - span[0]:.3f}s starting "
+            f"{span[0] - win.t0:+.2f}s after the window opened and ending "
             f"{win.t0 + seconds - span[1]:+.2f}s before the close, attempt "
             f"{done['attempts']}; written in "
             f"{done['written'] - done['stop']:.1f}s")
@@ -231,7 +244,9 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
     ev = readers.Evidence(cell=cell, window=win, setup_s=setup_s,
                           before=before, after=after,
                           device_kind=device["kind"],
-                          traced_from=win.t0 + seconds / 2 if span else None)
+                          traced_from=trace_cue_at(cell.traffic, win.t0,
+                                                   seconds)
+                          if span else None)
     dev_line = {k: device[k] for k in ("platform", "kind", "count",
                                        "memory_peak_bytes")}
     breakdown = None
@@ -254,6 +269,23 @@ def _measure(cell: Cell, child: Child, root: str, seed: int, seconds: float,
         result["breakdown"] = breakdown
     result["checks"] = checks.rows             # comes last
     return result, checks
+
+
+def _say_heal_sequence(win: Window, seconds: float) -> None:
+    """How long the backlog lasted, and whether a slow run was slow all
+    along or stood still somewhere: the polls, by steps of 5 s."""
+    polls = [p for p in win.heal_polls if p[0] <= win.end + 1e-6]
+    say(f"heal sequence: {polls[-1][1]} objects reported healed in "
+        f"{win.end - win.t0:.2f}s of the window's {seconds:.0f}s ("
+        + ("the backlog ran dry" if win.end < win.t0 + seconds - 1e-3
+           else "still running at the close") + ")")
+    steps = [max((n for t, n, _ in polls if t <= win.t0 + s), default=0)
+             for s in range(0, int(seconds) + 5, 5)]
+    rises = [t for (t, n, _), (_, m, _) in zip(polls[1:], polls) if n > m]
+    waits = [b - a for a, b in zip([win.t0] + rises, rises)]
+    say("heal sequence: objects healed in each 5 s "
+        f"{[b - a for a, b in zip(steps, steps[1:])]}; the longest wait "
+        f"for a result {max(waits, default=0):.2f}s")
 
 
 def _attempted(win: Window, failed: list) -> dict:

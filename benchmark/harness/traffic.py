@@ -10,9 +10,14 @@ Loop kinds (`kind`):
   seed; at most `clients` are in flight, and a request's latency counts
   from when it was due, so a stall shows in the requests behind it; how
   late the generator ran is reported;
-- `heal`: set-up preloads objects and wipes the bucket on `wipe_drives`
-  drives under the running server; the window starts one heal sequence
-  through the admin API and polls its status.
+- `heal`: set-up preloads objects, wipes the bucket on `wipe_drives`
+  drives under the running server and heals `warmup_objects` objects of
+  their own (`warm/`), so that what a failure pattern costs once is
+  set-up; the window starts one heal sequence over the rest through the
+  admin API and polls its status.
+
+Any mix may name `trace_cue_share`, the share of the window at which a
+traced run's slice is cued (`runner.trace_cue_at`; the middle otherwise).
 
 Op kinds (`ops[].op`): PUT (fresh keys, bodies from a pool of seed-made
 payloads), GET and STAT (of preloaded objects; with `wipe_drives` a GET is
@@ -42,6 +47,17 @@ from .reference import payload, rng_for
 NS = "{http://s3.amazonaws.com/doc/2006-03-01/}"
 BUCKET = "bench"
 NO_ANSWER = (OSError, http.client.HTTPException)
+
+
+def warm_key(i: int) -> str:
+    """Key of the i-th object the warm-up alone touches."""
+    return f"warm/{i:03d}"
+
+
+def preload_key(i: int) -> str:
+    """Key of the i-th preloaded object (a heal mix's backlog, in the
+    order the sequence walks it)."""
+    return f"obj/{i:05d}"
 
 
 class TrafficError(Exception):
@@ -179,9 +195,9 @@ class Load:
         if pre:
             size = int(pre["size"])
             bodies = self.pool(size)
-            self.preloaded = [(f"obj/{i:05d}", size, i % len(bodies))
+            self.preloaded = [(preload_key(i), size, i % len(bodies))
                               for i in range(int(pre["objects"]))]
-            jobs = [(f"warm/{i:03d}", size, i % len(bodies))
+            jobs = [(warm_key(i), size, i % len(bodies))
                     for i in range(warm_heal)] + self.preloaded
             # one PUT alone first: what compiles, compiles once
             self._put(s3, jobs[0][0], bodies[jobs[0][2]])
@@ -205,8 +221,7 @@ class Load:
         s3.close()
         n_wipe = int(self.t.get("wipe_drives", 0))
         if n_wipe:
-            self.wiped = sorted(int(d) + 1 for d in self.rng.choice(
-                self.drives, size=n_wipe, replace=False))
+            self.wiped = draw_wiped(self.rng, self.drives, n_wipe)
             for d in self.wiped:
                 self.before_wipe[d] = shard_file_hashes(self.drive(d))
                 base = os.path.join(self.drive(d), BUCKET)
@@ -492,6 +507,20 @@ class Load:
         if op.error:
             time.sleep(0.05)        # a server that refuses is not hammered
         return op
+
+
+def draw_wiped(rng, drives: int, n: int) -> list[int]:
+    """n of the drives (1-based), drawn from the seed. A set that a turn
+    of the ring of drives by fewer than all maps onto itself (two drives
+    half the ring apart) is drawn again: objects' shards are placed by
+    turns of that ring, so such a set has fewer distinct failure
+    patterns than the others (8 for 16), and a seed that drew it would
+    pay half the warm-up. Every seed does the same work."""
+    while True:
+        picked = {int(d) for d in rng.choice(drives, size=n, replace=False)}
+        if all({(d + turn) % drives for d in picked} != picked
+               for turn in range(1, drives)):
+            return sorted(d + 1 for d in picked)
 
 
 def shard_file_hashes(drive_dir: str) -> dict[str, str]:

@@ -84,33 +84,6 @@ def test_a_traced_heal_run_reports_every_heal_phase(copy):  # noqa: F811
     assert got["object_ms_per_op.heal"] >= parts > 0, got
     assert got["device_call_ms_per_op.heal"] > 0, got
     assert got["dispatches_per_op.heal"] == 1.0, got
-
-
-def test_each_new_metric_is_a_file_an_entry_and_a_known_reader():
-    """25 entries appended to `per_layer`, a data file each, every one on
-    a reader the harness had and with the cells it can be read in."""
-    from benchmark.harness.readers import READERS
-
-    with open(os.path.join(gate.REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    new = [m for m in bench["per_layer"]
-           if "_ms_per_op." in m["name"]
-           or m["name"].startswith("retraces_per_op.")]
-    assert len(new) == 25
-    assert bench["per_layer"][-25:] == new, "not appended at the end"
-    cells = {"put": "n16dev1-put10m", "ops": "n4dev1-put1m",
-             "heal": "n16dev1-heal2"}
-    e2e = {m["name"] for m in bench["end_to_end"]}
-    for m in new:
-        family = m["name"].rsplit(".", 1)[1]
-        assert m["workloads"] == [cells[family]], m
-        assert m["moves"] in e2e and m["source"] == "program_counter", m
-        path = os.path.join(gate.REPO, "benchmark", "layer_metrics",
-                            m["name"] + ".json")
-        with open(path) as f:
-            doc = json.load(f)
-        assert doc["reader"] in READERS and doc["what"], path
-        if doc["reader"] == "counter_ratio":
-            op = "heal_object" if family == "heal" else "put_object"
-            assert f'op="{op}"' in doc["pattern"]
-            assert f'op="{op}"' in doc["over"] and doc["scale"] == 1000
+    # the warm-up healed an object of every rotation: the window's heals
+    # run on functions it traced
+    assert got["retraces_per_op.heal"] == 0, got

@@ -1493,12 +1493,12 @@ def bench_mesh(total_mib: int = 32,
 
 
 def bench_device() -> dict:
-    """Device-kernel diagnostics: device-resident einsum/pallas GB/s and
-    the host-fed device-engine stream (H2D + MXU + fused hashes + D2H)."""
+    """Device-kernel diagnostics: device-resident einsum GB/s and the
+    host-fed device-engine stream (H2D + MXU + fused hashes + D2H)."""
     out: dict = {}
     import jax
 
-    from minio_tpu.ops import gf, rs_pallas
+    from minio_tpu.ops import gf
     from minio_tpu.ops.rs import _apply_bits
     from minio_tpu.utils import ceil_frac
 
@@ -1526,11 +1526,6 @@ def bench_device() -> dict:
 
     out["einsum_gbps"] = round(measure(jax.jit(_apply_bits),
                                        (bitmat, blocks)), 3)
-    if rs_pallas.pallas_supported():
-        out["pallas_gbps"] = round(
-            measure(lambda b, x: rs_pallas.apply_gf_matrix_pallas(b, x),
-                    (bitmat, blocks)), 3,
-        )
     # H2D bandwidth: the quantity that decides the host-vs-device engine
     # policy. The device pipeline is feed-bound, so it beats the native
     # host engine exactly when H2D GB/s exceeds the native host-fed rate

@@ -2,7 +2,7 @@
 data-plane with the capabilities of the reference MinIO (kubegems/minio).
 
 Hot paths (Reed-Solomon GF(2^8) coding, HighwayHash bitrot, heal
-reconstruction) run as JAX/Pallas kernels; the surrounding runtime
+reconstruction) run as jitted JAX programs; the surrounding runtime
 (storage, quorum, object layer, S3 API) is host-side Python/C++.
 """
 

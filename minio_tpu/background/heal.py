@@ -183,18 +183,10 @@ def heal_erasure_set(object_layer, buckets: list[str] | None = None) -> dict:
         return item
 
     from ..observability import ioflow
-    from ..utils.fanout import SINGLE_CORE
 
     # The sweep's LISTING IO is heal work too (per-object heal re-tags
     # at the heal_object choke point, which is a no-op here — same op).
     with ioflow.tag("heal"):
-        if SINGLE_CORE:
-            # Same fanout policy as the erasure drivers: stage threads
-            # on a single core only add dispatch cost over the serial
-            # sweep.
-            for item in listing():
-                heal_one(item)
-        else:
-            Pipeline("heal-sweep", [Stage("heal", heal_one)],
-                     queue_depth=64).run(listing())
+        Pipeline("heal-sweep", [Stage("heal", heal_one)],
+                 queue_depth=64).run(listing())
     return result

@@ -31,24 +31,6 @@ from ..utils.errors import (
 )
 from . import registry
 
-# Back-compat alias — the threshold now lives with the selection policy.
-_DEVICE_SHARD_THRESHOLD = registry.DEVICE_SHARD_THRESHOLD
-
-
-def _select_engine(shard_len: int, total_shards: int | None = None,
-                   codec: str = registry.DEFAULT_CODEC) -> str:
-    """Pick the GF engine for one application:
-    'native' | 'device' | 'mesh' | 'numpy'.
-
-    Thin shim over the codec registry's selector (erasure/registry.py),
-    which replaced the engine if-chain that used to live here: candidates
-    are gated by (capability, geometry, availability) and ranked by
-    measured throughput, with MTPU_ENCODE_ENGINE preserved as the forced
-    override. See registry.select_engine for the full policy.
-    """
-    return registry.select_engine(shard_len, total_shards, codec)
-
-
 @functools.lru_cache(maxsize=64)
 def cached_erasure(data_blocks: int, parity_blocks: int, block_size: int,
                    codec: str = registry.DEFAULT_CODEC) -> "Erasure":
@@ -182,7 +164,8 @@ class Erasure:
         out_s = shards.shape[-1]
         if self.subshards > 1:
             shards = self._subshard_view(shards)
-        engine = _select_engine(shards.shape[-1], codec=self.codec_id)
+        engine = registry.select_engine(shards.shape[-1],
+                                        codec_id=self.codec_id)
         registry.note_dispatch(self.codec_id, engine)
         if engine == "native":
             if shards.ndim == 3:
@@ -234,7 +217,8 @@ class Erasure:
 
     def _apply_parity(self, shards: np.ndarray) -> np.ndarray:
         on_device = (
-            _select_engine(shards.shape[-1], codec=self.codec_id)
+            registry.select_engine(shards.shape[-1],
+                                   codec_id=self.codec_id)
             == "device"
         )
         return self._apply(
@@ -299,7 +283,7 @@ class Erasure:
         parallelWriter (cmd/erasure-encode.go:93 + bitrot-streaming.go:48).
 
         `blocks` may already be a DEVICE array — the pipelined host-feed
-        stage (ops/rs_pallas.HostFeed) stages the H2D transfer of batch
+        stage (device_engine.HostFeed) stages the H2D transfer of batch
         N+1 while batch N computes; coercing it through numpy here would
         silently pull it back to the host and undo the overlap. The
         device path runs on the fused single-dispatch engine
@@ -314,8 +298,8 @@ class Erasure:
         )
         if not staged_on_device:
             blocks = np.ascontiguousarray(blocks, dtype=np.uint8)
-        engine = _select_engine(blocks.shape[-1], self.total_shards,
-                                self.codec_id)
+        engine = registry.select_engine(blocks.shape[-1],
+                                        self.total_shards, self.codec_id)
         registry.note_dispatch(self.codec_id, engine)
         if staged_on_device and engine not in ("device", "mesh"):
             blocks = np.asarray(blocks)  # tiny-shard fallback: host engines

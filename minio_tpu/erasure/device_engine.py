@@ -14,7 +14,7 @@ This module is structured so each [B, k, S] batch costs:
   ``STATS["dispatches"]`` counts invocations and ``STATS["traces"]``
   counts (re)traces so tests can pin dispatches-per-batch == 1 and
   steady-state recompiles == 0.
-- DONATED input buffers: the staged H2D batch (rs_pallas.HostFeed) is
+- DONATED input buffers: the staged H2D batch (HostFeed, below) is
   donated to XLA (``donate_argnums``), so the runtime recycles the
   8 MiB device allocation into the outputs instead of growing the
   arena every batch. The host copy lives on in the pooled strip
@@ -123,6 +123,50 @@ def _d2h_async(arr) -> None:
         return
     arr.copy_to_host_async()
     _stat("async_d2h")
+
+
+class HostFeed:
+    """Pipelined host→device staging stage for the device encode engine.
+
+    An encode loop that does H2D, dispatch and D2H from ONE host
+    thread leaves the link idle while the host packs or flushes. Run
+    as a stage of pipeline/executor.Pipeline, this callable moves the
+    H2D copy onto its own worker: the transfer of batch N+1 overlaps
+    the MXU compute of batch N and the host write fan-out of batch
+    N-1 — double buffering falls out of the executor's bounded queues
+    (queue_depth=1 keeps exactly one staged batch ahead).
+
+    The transfer is COMPLETED inside the stage (block_until_ready):
+    returning a lazy handle would make the dispatch stage pay the wait
+    and re-serialize the feed. Per-stage items/bytes/timing telemetry
+    comes from the executor's StageStats, not from this class.
+
+    `sharding` stages onto a sharded layout (the mesh engine's
+    dp-groups) instead of the default device; `accept` gates which
+    batches stage at all — a declined batch passes through on the host
+    and the downstream codec stages it itself (the mesh engine declines
+    ragged batches whose row count doesn't divide dp, since those need
+    padding the feed must not own).
+    """
+
+    def __init__(self, name: str = "h2d", sharding=None, accept=None):
+        self.name = name
+        self._sharding = sharding
+        self._accept = accept
+
+    def __call__(self, batch):
+        import jax
+
+        if self._accept is not None and not self._accept(batch):
+            return batch
+        with _spans.span("device-h2d",
+                         "mesh" if self._sharding is not None else "device"):
+            if self._sharding is not None:
+                dev = jax.device_put(batch, self._sharding)
+            else:
+                dev = jax.device_put(batch)
+            dev.block_until_ready()
+        return dev
 
 
 class DeviceCodec:

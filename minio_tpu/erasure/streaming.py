@@ -43,10 +43,10 @@ _io_pool = ThreadPoolExecutor(max_workers=64, thread_name_prefix="mtpu-io")
 
 from ..observability import ioflow as _ioflow
 from ..observability import spans as _spans
+from . import registry
+from .device_engine import HostFeed
 from .device_engine import to_host as _to_host
-from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 from ..utils.fanout import QuorumFanout, StragglerCompensator
-from ..utils.fanout import is_local_sink as _is_local_sink
 
 # Robustness telemetry: module counters always tick (tests read them
 # directly); a registry handle installed at server boot mirrors them
@@ -101,27 +101,18 @@ class ParallelWriter:
         # detached for the rest of the stream.
         self._fan = QuorumFanout(_io_pool, _io_compensator)
 
-    def write(self, blocks: list, digests: list | None = None):
-        def attempt(i):
-            w = self.writers[i]
-            if digests is not None and hasattr(w, "write_with_digest"):
-                w.write_with_digest(blocks[i], digests[i])
-            else:
-                w.write(blocks[i])
-
-        self._fanout(attempt)
+    def write(self, blocks: list):
+        self._fanout(lambda i: self.writers[i].write(blocks[i]))
 
     def _fanout(self, attempt):
-        """Dispatch attempt(i) across writers: remote sinks through the
-        pool, local sinks inline on single-core hosts (fanout cost >
-        overlap gain there). Waits for quorum + grace, not for every
-        writer (QuorumFanout owns the detach protocol)."""
+        """Dispatch attempt(i) for every live writer through the pool.
+        Waits for quorum + grace, not for every writer (QuorumFanout
+        owns the detach protocol)."""
         deadline_s = (self._op_deadline_s if self._op_deadline_s is not None
                       else ROBUST.op_deadline_s)
         grace_s = (self._grace_s if self._grace_s is not None
                    else ROBUST.straggler_grace_s)
         pending: set[int] = set()
-        inline: list[int] = []
         for i, w in enumerate(self.writers):
             if i in self._fan.detached:
                 continue  # straggler from an earlier block; errs latched
@@ -129,10 +120,7 @@ class ParallelWriter:
                 if self.errs[i] is None:
                     self.errs[i] = ErrDiskNotFound(f"writer {i}")
                 continue
-            if _SINGLE_CORE and _is_local_sink(getattr(w, "_sink", w)):
-                inline.append(i)
-            else:
-                pending.add(i)
+            pending.add(i)
 
         def record(i, err):
             if err is None:
@@ -150,8 +138,7 @@ class ParallelWriter:
             self.writers[i] = None
 
         self._fan.dispatch(
-            attempt, pending, inline, self.write_quorum,
-            deadline_s, grace_s,
+            attempt, pending, self.write_quorum, deadline_s, grace_s,
             count_ok=lambda: sum(
                 1 for j in range(len(self.errs))
                 if self.errs[j] is None and j not in pending
@@ -214,7 +201,7 @@ class ParallelWriter:
 class _BlockFiller:
     """Reads a byte stream into block-major [B, k*S] strip buffers: row
     bi holds one whole erasure block's stream bytes followed by split()'s
-    zero pad. Shared by the serial and pipelined encode drivers so their
+    zero pad. Shared by the native-engine encode drivers so their
     tail/empty-object handling cannot drift.
 
     The block-major layout is what makes the downstream stages zero-copy
@@ -291,35 +278,25 @@ def encode_stream(erasure: Erasure, src, writers: list, quorum: int,
     Returns total bytes consumed (ref Erasure.Encode,
     cmd/erasure-encode.go:73-109).
 
-    On multicore hosts both engines run on the staged pipeline
-    (pipeline/executor.py): source-read ∥ md5 (delegated from
-    TeeMD5Reader into its own stage) ∥ GF encode ∥
-    bitrot-frame+shard-write run as overlapped stages over pooled strip
-    buffers, with bounded queues for backpressure and first-error
-    cancellation. `telemetry` labels the per-stage counters ("put",
-    "multipart", ...) on the metrics endpoint. A single-core host keeps
-    the serial drivers — stage threads there only add dispatch cost
-    (the measured fanout policy in utils/fanout.py).
+    Every engine runs on the staged pipeline (pipeline/executor.py):
+    source-read ∥ md5 (delegated from TeeMD5Reader into its own stage)
+    ∥ GF encode ∥ bitrot-frame+shard-write run as overlapped stages
+    over pooled strip buffers, with bounded queues for backpressure and
+    first-error cancellation. `telemetry` labels the per-stage counters
+    ("put", "multipart", ...) on the metrics endpoint.
     """
-    from . import registry
-    from .codec import _select_engine
-
     writer = ParallelWriter(writers, quorum)
     shard = erasure.shard_size()
     want_digests = any(
         getattr(w, "device_hashable", False) for w in writers if w is not None
     )
-    engine = _select_engine(shard, erasure.total_shards,
-                            codec=erasure.codec_id)
+    engine = registry.select_engine(shard, erasure.total_shards,
+                                    codec_id=erasure.codec_id)
     # One span over the whole stream, labelled by the driver that ran.
     with _spans.span("stream") as sp:
         if engine == "native":
             # Host-native engine: the batched strip path (one GFNI encode
             # + one framing call per shard per batch).
-            if _SINGLE_CORE:
-                sp.relabel("native_serial")
-                return _encode_stream_native(erasure, src, writer,
-                                             batch_blocks)
             from ..pipeline import workers as _workers
 
             wpool = (_workers.armed()
@@ -339,11 +316,6 @@ def encode_stream(erasure: Erasure, src, writers: list, quorum: int,
             return _encode_stream_native_pipelined(
                 erasure, src, writer, batch_blocks, telemetry
             )
-        if _SINGLE_CORE:
-            sp.relabel("batched_serial")
-            return _encode_stream_batched(
-                erasure, src, writer, batch_blocks, want_digests
-            )
         sp.relabel("batched_pipelined")
         return _encode_stream_batched_pipelined(
             erasure, src, writer, batch_blocks, want_digests, engine,
@@ -351,28 +323,17 @@ def encode_stream(erasure: Erasure, src, writers: list, quorum: int,
         )
 
 
-_HOST_FEED = None
-
-
-def _host_feed():
-    """Process-wide HostFeed stage (it is stateless): PUTs reuse it
-    instead of constructing one per stream — part of the per-PUT setup
-    the pool-batched path no longer pays."""
-    global _HOST_FEED
-    if _HOST_FEED is None:
-        from ..ops.rs_pallas import HostFeed
-
-        _HOST_FEED = HostFeed()
-    return _HOST_FEED
+# Process-wide H2D stage of the device engine (it is stateless): PUTs
+# reuse it instead of constructing one per stream.
+_HOST_FEED = HostFeed()
 
 
 def _gather_batches(src, block_size: int, batch_blocks: int):
-    """Yield (full_blocks, tail) gathers for the block-list drivers: up
+    """Yield (full_blocks, tail) gathers for the batched driver: up
     to batch_blocks full byte blocks per item, plus the short trailing
     read as `tail` (b"" is the empty-object sentinel, emitted exactly
-    once; None when the stream ended on a block boundary). The single
-    owner of the gather/tail/sentinel logic for both batched drivers —
-    _StripFiller is its strip-layout counterpart."""
+    once; None when the stream ended on a block boundary).
+    _BlockFiller is its strip-layout counterpart."""
     eof = False
     produced = False
     while not eof:
@@ -393,70 +354,13 @@ def _gather_batches(src, block_size: int, batch_blocks: int):
         yield (full, tail)
 
 
-def _encode_stream_batched(erasure: Erasure, src, writer: ParallelWriter,
-                           batch_blocks: int, want_digests: bool) -> int:
-    """Serial driver for the device/numpy engines (SURVEY §7.2(4)):
-    `batch_blocks` full blocks ship to the device as one [B, k, S] batch
-    — parity matmul AND the per-shard HighwayHash fused in one compiled
-    unit — and the dispatch is ASYNC: while the device computes batch N,
-    the host fans out the writes of batch N-1 and reads batch N+1. The
-    short tail block is encoded alone on the host."""
-    total = 0
-    block_size = erasure.block_size
-    k = erasure.data_blocks
-    shard = erasure.shard_size()
-    pending = None  # (data [B,k,S], parity_future, hashes_future, n_blocks)
-
-    def flush(p) -> None:
-        nonlocal total
-        data, parity_f, hashes_f, n = p
-        # blocks until the dispatch finishes
-        parity, hashes = _to_host(parity_f, hashes_f)
-        for bi in range(n):
-            blocks = [data[bi, j] for j in range(erasure.data_blocks)] + [
-                parity[bi, j] for j in range(erasure.parity_blocks)
-            ]
-            digests = (
-                # copy-ok: meta (32-byte digests, not payload)
-                [hashes[bi, j].tobytes() for j in range(erasure.total_shards)]
-                if hashes is not None else None
-            )
-            writer.write(blocks, digests)
-            total += block_size
-
-    for full, tail in _gather_batches(src, block_size, batch_blocks):
-        if full:
-            # Each block zero-pads to k*shard (split semantics) before the
-            # [B, k, S] batch is shipped to the device.
-            data = np.zeros((len(full), k * shard), dtype=np.uint8)
-            for bi, b in enumerate(full):
-                data[bi, :block_size] = np.frombuffer(b, dtype=np.uint8)
-            data = data.reshape(len(full), k, shard)
-            parity_f, hashes_f = erasure.encode_batch_async(
-                data, with_hashes=want_digests
-            )
-            if pending is not None:
-                flush(pending)  # overlap: batch N computes while N-1 writes
-            pending = (data, parity_f, hashes_f, len(full))
-        if tail is not None:
-            # Tail (or empty-object sentinel): host path, after the batches.
-            if pending is not None:
-                flush(pending)
-                pending = None
-            writer.write(erasure.encode_data(tail))
-            total += len(tail)
-    if pending is not None:
-        flush(pending)
-    return total
-
-
 def _encode_stream_batched_pipelined(erasure: Erasure, src,
                                      writer: ParallelWriter,
                                      batch_blocks: int, want_digests: bool,
                                      engine: str, telemetry: str,
                                      sp=_spans.NULL) -> int:
     """Pipelined driver for the device/numpy engines: read → pack →
-    host-feed (double-buffered H2D staging, ops/rs_pallas.HostFeed) →
+    host-feed (double-buffered H2D staging, device_engine.HostFeed) →
     fused dispatch → flush+write as overlapped stages. The H2D transfer
     of batch N+1 proceeds while the MXU computes batch N and the host
     writes batch N-1 — device feeding is no longer serialized on any
@@ -517,7 +421,7 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
         return [buf, data, tail, None, None]
 
     if engine == "device":
-        feed = _host_feed()
+        feed = _HOST_FEED
     elif engine == "mesh":
         # Mesh staging shards the batch over the dp axis (one buffer
         # per dp-group); the feed declines ragged batches, which the
@@ -620,35 +524,6 @@ def _encode_stream_batched_pipelined(erasure: Erasure, src,
     Pipeline(telemetry, stages, queue_depth=1,
              pools=[pool], drop=drop).run(source_from_peeked())
     return totals["bytes"]
-
-
-def _encode_stream_native(erasure: Erasure, src, writer: ParallelWriter,
-                          batch_blocks: int) -> int:
-    """Serial block-major driver for the host-native engine (single-core
-    hosts): gather B full blocks as [B, k*S] rows (one contiguous
-    readinto per block), encode them as one native [B, k, S] batch, then
-    one strided-hash + vectored writev per shard. Every payload byte is
-    copied exactly once (source read) before the kernel write."""
-    from ..ops import gf_native
-
-    total = 0
-    k = erasure.data_blocks
-    m = erasure.parity_blocks
-    shard = erasure.shard_size()
-    filler = _BlockFiller(erasure, src, batch_blocks)
-    buf = np.empty((batch_blocks, k * shard), dtype=np.uint8)
-    while not filler.eof:
-        nb, tail = filler.fill(buf)
-        if nb:
-            parity = erasure.parity_apply_batch_native(
-                buf[:nb].reshape(nb, k, shard)
-            )
-            writer.write_frame_batches(buf, parity, nb, k, m, shard)
-            total += nb * erasure.block_size
-        if tail is not None:
-            writer.write(erasure.encode_data(tail))
-            total += len(tail)
-    return total
 
 
 def _encode_stream_native_pipelined(erasure: Erasure, src,
@@ -1174,93 +1049,79 @@ class ParallelReader:
             i = try_next()
             if i is not None:
                 first.append(i)
-        if _SINGLE_CORE and all(
-            getattr(self.readers[i], "local", False) for i in first
-        ):
-            for i in first:
-                run(i)
-            # Late escalation: if failures left us short but readers
-            # remain untried, keep going serially (no hedging on one
-            # core — there is no thread to overlap the wait with).
-            while (len(results) < self.data_blocks
-                   and state["next"] < len(self.readers)):
-                i = try_next()
-                if i is not None:
-                    run(i)
-        else:
-            from ..observability import carry as _obs_carry
+        from ..observability import carry as _obs_carry
 
-            # Reader threads carry the caller's trace (disk-op and
-            # worker-verify spans) and byte-flow op tag (shard-read
-            # bytes) so both attribute to this request.
-            bound_worker = _obs_carry(worker)
-            with cv:
-                state["active"] = len(first)
-            for i in first:
-                _io_pool.submit(bound_worker, i)
-            hedge_s = ROBUST.hedge_delay_s
-            deadline = time.monotonic() + ROBUST.long_op_deadline_s
-            last_hedge = 0.0
-            state["progress"] = time.monotonic()
-            t_span0 = time.monotonic_ns()
-            with cv:
-                while len(results) < self.data_blocks:
-                    if (state["active"] == 0
-                            and state["next"] >= len(self.readers)):
-                        break  # everyone finished/failed; nothing to try
-                    now = time.monotonic()
-                    if now >= deadline:
-                        break
-                    # STALL-based hedging: fire only when no result has
-                    # arrived for a full hedge window (a batch that is
-                    # merely slower than hedge_delay but making steady
-                    # progress must not pay read amplification).
-                    fire_at = max(state["progress"], last_hedge) + hedge_s
-                    if now >= fire_at:
-                        # A preferred shard is stalled: dispatch the next
-                        # untried (parity) reader instead of blocking on
-                        # it (hedged read; the erasure-decoding dual of
-                        # proceeding once any k of n shards arrive).
-                        last_hedge = now
-                        j = try_next()
-                        if j is not None:
-                            state["active"] += 1
-                            record_stat("hedged_reads_total")
-                            # Event mark: the hedge decision on this
-                            # request's timeline (span dual of the
-                            # hedged_reads_total aggregate).
-                            _spans.record("fanout", f"hedge #{j}", 0)
-                            _io_pool.submit(bound_worker, j)
-                        continue
-                    cv.wait(min(fire_at, deadline) - now)
-                # Close the batch: workers that have not started their
-                # read exit at the closed-check, readers untouched.
-                # Readers still MID-read are abandoned: their stream is
-                # parked on THIS batch's offsets, so reusing them next
-                # batch would interleave two reads on one stream. Drop
-                # them from the rotation — slow is not missing, so no
-                # heal hint, and a late result is simply discarded. Each
-                # abandoned worker still pins a pool thread until its
-                # read returns; compensate the pool ceiling meanwhile.
-                state["closed"] = True
-                for j in list(inflight):
-                    abandoned.add(j)
-                    inflight.discard(j)
-                    _io_compensator.parked()
-                    if self.errs[j] is None:
-                        self.errs[j] = ErrDiskOpTimeout(
-                            f"shard reader {j} abandoned past hedge"
-                        )
-                    # Parked, not destroyed: if its in-flight read
-                    # completes while the stream position still lines up
-                    # with the rotation, the reader rejoins (see run()).
-                    parked[j] = self.readers[j]
-                    self.readers[j] = None
-                    _spans.record("fanout", f"straggler-detach #{j}", 0)
-            # One span per reader fan-out: results-arrival wait + the
-            # hedge/abandon bookkeeping above.
-            _spans.record("fanout", "shard-read-wait",
-                          time.monotonic_ns() - t_span0)
+        # Reader threads carry the caller's trace (disk-op and
+        # worker-verify spans) and byte-flow op tag (shard-read
+        # bytes) so both attribute to this request.
+        bound_worker = _obs_carry(worker)
+        with cv:
+            state["active"] = len(first)
+        for i in first:
+            _io_pool.submit(bound_worker, i)
+        hedge_s = ROBUST.hedge_delay_s
+        deadline = time.monotonic() + ROBUST.long_op_deadline_s
+        last_hedge = 0.0
+        state["progress"] = time.monotonic()
+        t_span0 = time.monotonic_ns()
+        with cv:
+            while len(results) < self.data_blocks:
+                if (state["active"] == 0
+                        and state["next"] >= len(self.readers)):
+                    break  # everyone finished/failed; nothing to try
+                now = time.monotonic()
+                if now >= deadline:
+                    break
+                # STALL-based hedging: fire only when no result has
+                # arrived for a full hedge window (a batch that is
+                # merely slower than hedge_delay but making steady
+                # progress must not pay read amplification).
+                fire_at = max(state["progress"], last_hedge) + hedge_s
+                if now >= fire_at:
+                    # A preferred shard is stalled: dispatch the next
+                    # untried (parity) reader instead of blocking on
+                    # it (hedged read; the erasure-decoding dual of
+                    # proceeding once any k of n shards arrive).
+                    last_hedge = now
+                    j = try_next()
+                    if j is not None:
+                        state["active"] += 1
+                        record_stat("hedged_reads_total")
+                        # Event mark: the hedge decision on this
+                        # request's timeline (span dual of the
+                        # hedged_reads_total aggregate).
+                        _spans.record("fanout", f"hedge #{j}", 0)
+                        _io_pool.submit(bound_worker, j)
+                    continue
+                cv.wait(min(fire_at, deadline) - now)
+            # Close the batch: workers that have not started their
+            # read exit at the closed-check, readers untouched.
+            # Readers still MID-read are abandoned: their stream is
+            # parked on THIS batch's offsets, so reusing them next
+            # batch would interleave two reads on one stream. Drop
+            # them from the rotation — slow is not missing, so no
+            # heal hint, and a late result is simply discarded. Each
+            # abandoned worker still pins a pool thread until its
+            # read returns; compensate the pool ceiling meanwhile.
+            state["closed"] = True
+            for j in list(inflight):
+                abandoned.add(j)
+                inflight.discard(j)
+                _io_compensator.parked()
+                if self.errs[j] is None:
+                    self.errs[j] = ErrDiskOpTimeout(
+                        f"shard reader {j} abandoned past hedge"
+                    )
+                # Parked, not destroyed: if its in-flight read
+                # completes while the stream position still lines up
+                # with the rotation, the reader rejoins (see run()).
+                parked[j] = self.readers[j]
+                self.readers[j] = None
+                _spans.record("fanout", f"straggler-detach #{j}", 0)
+        # One span per reader fan-out: results-arrival wait + the
+        # hedge/abandon bookkeeping above.
+        _spans.record("fanout", "shard-read-wait",
+                      time.monotonic_ns() - t_span0)
 
         if len(results) < self.data_blocks:
             err = reduce_read_quorum_errs(
@@ -1290,7 +1151,7 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
     caller queues a heal, like cmd/erasure-object.go:324-338.
     (ref Erasure.Decode, cmd/erasure-decode.go:205-283)
 
-    On multicore hosts the block loop runs on the staged pipeline
+    Past two blocks the block loop runs on the staged pipeline
     (pipeline/executor.py): shard-read+bitrot-verify of block N+1 and
     decode of block N overlap the client write of block N-1, with
     bounded queues so a slow client applies backpressure instead of
@@ -1341,8 +1202,6 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
         if reader.saw_corrupt and heal_hint is None:
             heal_hint = ErrFileCorrupt("bitrot during read")
 
-    from .codec import _select_engine
-
     # <=2 blocks: read-ahead can overlap at most one handoff — not
     # worth the per-request thread spin-up (the small-object/range-GET
     # fast path stays identical to the serial driver).
@@ -1356,16 +1215,14 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
     # overlap, and on a mesh deployment degraded reconstruction — the
     # thing the collective dispatch accelerates — is what GET latency
     # economics turn on.
-    engine = _select_engine(erasure.shard_size(), erasure.total_shards,
-                            codec=erasure.codec_id)
+    engine = registry.select_engine(erasure.shard_size(),
+                                    erasure.total_shards,
+                                    codec_id=erasure.codec_id)
     wpool = None
-    if engine == "native" and not _SINGLE_CORE:
-        from . import registry as _registry
+    if engine == "native" and registry.supports(erasure.codec_id, "worker"):
+        from ..pipeline import workers as _workers
 
-        if _registry.supports(erasure.codec_id, "worker"):
-            from ..pipeline import workers as _workers
-
-            wpool = _workers.armed()
+        wpool = _workers.armed()
     with _spans.span("stream") as sp:
         try:
             if engine == "mesh":
@@ -1402,7 +1259,7 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
                 bytes_written = _decode_stream_workers(
                     erasure, writer, reader, geoms, note_heal, wpool
                 )
-            elif _SINGLE_CORE or len(geoms) <= 2:
+            elif len(geoms) <= 2:
                 # Serial consumption drains every batch's views before the
                 # next reader fan-out, so the bitrot readers may recycle
                 # their read buffers (readinto a private ring, no fresh
@@ -1762,12 +1619,10 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
     `writers` has one entry per shard position; non-None entries are the
     stale disks to fill.
 
-    On multicore hosts the loop runs on the staged pipeline: shard
-    reads of block N+1 and GF reconstruction of block N overlap the
-    stale-disk writes of block N-1, so heal throughput is bounded by
-    the slowest stage rather than their sum."""
-    from .codec import _select_engine
-
+    Past two blocks the host engines run the loop on the staged
+    pipeline: shard reads of block N+1 and GF reconstruction of block N
+    overlap the stale-disk writes of block N-1, so heal throughput is
+    bounded by the slowest stage rather than their sum."""
     targets = [i for i, w in enumerate(writers) if w is not None]
     if not targets:
         return
@@ -1787,8 +1642,9 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
             copy_add("heal.shard_copy", len(chunk))
             writers[t].write(chunk)
 
-    engine = _select_engine(erasure.shard_size(), erasure.total_shards,
-                            codec=erasure.codec_id)
+    engine = registry.select_engine(erasure.shard_size(),
+                                    erasure.total_shards,
+                                    codec_id=erasure.codec_id)
     with _spans.span("stream") as sp:
         try:
             if engine in ("device", "mesh") and total_blocks:
@@ -1806,13 +1662,12 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
                 return _heal_stream_fused(erasure, writers, reader, targets,
                                           total_blocks, codec)
 
-            if (engine == "native" and not _SINGLE_CORE and total_blocks > 2
+            if (engine == "native" and total_blocks > 2
                     and len(targets) <= erasure.parity_blocks):
-                from . import registry as _registry
                 from ..pipeline import workers as _workers
 
                 wpool = (_workers.armed()
-                         if _registry.supports(erasure.codec_id, "worker")
+                         if registry.supports(erasure.codec_id, "worker")
                          else None)
                 if wpool is not None:
                     # Worker heal driver (ISSUE 11): per-failure-pattern
@@ -1824,7 +1679,7 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
                     return _heal_stream_workers(erasure, writers, reader,
                                                 targets, total_blocks, wpool)
 
-            if _SINGLE_CORE or total_blocks <= 2:
+            if total_blocks <= 2:
                 # Serial heal consumes (reconstructs + copies) each batch
                 # before the next fan-out: safe to recycle the readers'
                 # buffers.

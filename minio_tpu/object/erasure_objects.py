@@ -68,7 +68,6 @@ from ..observability import carry as _obs_carry
 from ..observability import ioflow as _ioflow
 from ..observability import spans as _spans
 from . import readtier as _readtier
-from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 from ..utils.fanout import StragglerCompensator
 from ..utils.fanout import decode_slot as _decode_slot
 from ..utils.fanout import encode_slot as _encode_slot
@@ -91,22 +90,15 @@ def _close_sinks(sinks):
                 pass
 
 
-def _fanout(fn, n: int, disks: list):
-    """Run fn(i) for i in range(n): through the pool when any disk is
-    remote (network overlap pays regardless of cores) or the host has
-    cores to parallelize syscalls; inline on a single-core all-local
-    host, where a 16-task dispatch costs ~280 us of pure overhead."""
-    if _SINGLE_CORE and all(d is None or d.is_local() for d in disks):
-        for i in range(n):
-            fn(i)
-    else:
-        # Pool threads carry the caller's request-scoped observability
-        # context (span trace + byte-flow op tag) so metadata reads/
-        # writes attribute to the request.
-        list(_obj_pool.map(_obs_carry(fn), range(n)))
+def _fanout(fn, n: int):
+    """Run fn(i) for i in range(n) through the pool. Pool threads carry
+    the caller's request-scoped observability context (span trace +
+    byte-flow op tag) so metadata reads/writes attribute to the
+    request."""
+    list(_obj_pool.map(_obs_carry(fn), range(n)))
 
 
-def _quorum_fanout(attempt, n: int, disks: list, errs: list, quorum: int,
+def _quorum_fanout(attempt, n: int, errs: list, quorum: int,
                    op_deadline_s: float | None = None,
                    straggler_grace_s: float | None = None) -> None:
     """Quorum-wait fan-out for commit/delete paths: run attempt(i)
@@ -130,15 +122,6 @@ def _quorum_fanout(attempt, n: int, disks: list, errs: list, quorum: int,
     from ..utils.errors import ErrDiskOpTimeout
     from ..utils.fanout import QuorumFanout
 
-    if _SINGLE_CORE and all(d is None or d.is_local() for d in disks):
-        # One core: serial inline execution, nothing to detach.
-        for i in range(n):
-            try:
-                attempt(i)
-            except Exception as exc:  # noqa: BLE001 - collected for quorum
-                errs[i] = exc
-        return
-
     deadline_s = (op_deadline_s if op_deadline_s is not None
                   else ROBUST.op_deadline_s)
     grace_s = (straggler_grace_s if straggler_grace_s is not None
@@ -155,7 +138,7 @@ def _quorum_fanout(attempt, n: int, disks: list, errs: list, quorum: int,
         )
 
     QuorumFanout(_obj_pool, _obj_compensator).dispatch(
-        attempt, pending, (), quorum, deadline_s, grace_s,
+        attempt, pending, quorum, deadline_s, grace_s,
         count_ok=lambda: sum(1 for j in range(n)
                              if errs[j] is None and j not in pending),
         record=record,
@@ -398,16 +381,6 @@ class ErasureObjects(MultipartMixin):
         # The object layer's span: admission, stream and commit are its
         # children, the rest (set-up, sinks, md5, xl.meta) its self time.
         with _spans.span("object", "put"):
-            if _SINGLE_CORE:
-                # One core: admit ONE whole PUT at a time. Leaving setup
-                # and commit outside the slot lets queued PUTs steal the
-                # GIL between the encoder's native calls — measured 20%
-                # aggregate loss vs serial. Multicore hosts keep the
-                # narrower encode-only slot (overlapping commit IO there
-                # is a win).
-                with _encode_slot():
-                    return self._put_object_inner(bucket, object_, reader,
-                                                  size, opts)
             return self._put_object_inner(bucket, object_, reader, size,
                                           opts)
 
@@ -474,13 +447,11 @@ class ErasureObjects(MultipartMixin):
                 writers[i] = None
 
         try:
-            if _SINGLE_CORE:
+            # The one admission point: the slot covers the encode alone,
+            # so set-up and commit I/O of queued PUTs overlap it.
+            with _encode_slot():
                 total = encode_stream(erasure, tee, writers, write_quorum,
                                       telemetry="put")
-            else:
-                with _encode_slot():
-                    total = encode_stream(erasure, tee, writers,
-                                          write_quorum, telemetry="put")
         except Exception:
             # Close abandoned sinks BEFORE the tmp cleanup: raw-fd
             # (O_DIRECT) sinks hold an fd + staging buffer that GC may
@@ -575,10 +546,10 @@ class ErasureObjects(MultipartMixin):
         # for every disk: a drive hung in rename_data is detached (its
         # errs slot becomes a timeout) and the missed commit heals via
         # the MRF queue below.
-        # The disk ops run on the fan-out pool (inline on one core, where
-        # they are this thread's mirrored leaves instead of the commit).
-        with _spans.span("commit", mirror=not _SINGLE_CORE):
-            _quorum_fanout(commit, n, disks_by_shard, errs, write_quorum)
+        # The disk ops run on the fan-out pool; this thread's leaf on the
+        # profiler's clock is the commit itself.
+        with _spans.span("commit", mirror=True):
+            _quorum_fanout(commit, n, errs, write_quorum)
         err = reduce_write_quorum_errs(errs, OBJECT_OP_IGNORED_ERRS, write_quorum)
         if err is not None:
             # Undo the renames that DID land (ref undoRename /
@@ -957,17 +928,14 @@ class ErasureObjects(MultipartMixin):
             def open_inline(off, ln, b=buf):
                 return io.BytesIO(b[off : off + ln])
 
-            r = StreamingBitrotReader(open_inline, till_offset, shard_size)
-            r.local = True
-            return r
+            return StreamingBitrotReader(open_inline, till_offset,
+                                         shard_size)
         path = f"{object_}/{fi.data_dir}/part.{part_number}"
 
         def open_stream(off, ln, d=disk, p=path):
             return d.read_file_stream(bucket, p, off, ln)
 
-        r = StreamingBitrotReader(open_stream, till_offset, shard_size)
-        r.local = disk.is_local()
-        return r
+        return StreamingBitrotReader(open_stream, till_offset, shard_size)
 
     def _repair_sources(self, avail_by_shard: list, metas_by_shard: list,
                         bucket: str, object_: str, fi, part_number: int):
@@ -1031,7 +999,7 @@ class ErasureObjects(MultipartMixin):
                     raise ErrDiskNotFound(f"disk {i}")
                 self.disks[i].write_metadata(bucket, object_, marker)
 
-            _quorum_fanout(write_marker, n, self.disks, errs, write_quorum)
+            _quorum_fanout(write_marker, n, errs, write_quorum)
             err = reduce_write_quorum_errs(errs, OBJECT_OP_IGNORED_ERRS, write_quorum)
             if err is not None:
                 raise err
@@ -1058,7 +1026,7 @@ class ErasureObjects(MultipartMixin):
         # Quorum-wait: a hung drive must not wedge DELETEs either; the
         # straggler's stale version is invisible (quorum reads pick the
         # deleted majority) and heals on the next MRF/scanner pass.
-        _quorum_fanout(do, n, self.disks, errs, write_quorum)
+        _quorum_fanout(do, n, errs, write_quorum)
         err = reduce_write_quorum_errs(errs, OBJECT_OP_IGNORED_ERRS, write_quorum)
         if err is not None:
             raise self._to_object_err(err, bucket, object_, opts.version_id)

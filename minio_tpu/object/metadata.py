@@ -53,7 +53,7 @@ def read_all_file_info(disks: list, bucket: str, object_: str,
 
     from .erasure_objects import _fanout
 
-    _fanout(do, len(disks), disks)
+    _fanout(do, len(disks))
     return fis, errs
 
 

@@ -21,7 +21,6 @@ from ..observability import ioflow
 from ..erasure.codec import Erasure
 from ..erasure.streaming import encode_stream
 from ..storage.fileinfo import ChecksumInfo, ErasureInfo, FileInfo, new_uuid
-from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 from ..utils.fanout import encode_slot as _encode_slot
 from ..storage.local import SYSTEM_META_BUCKET
 from ..utils.errors import (
@@ -269,18 +268,8 @@ class MultipartMixin:
                         opts: ObjectOptions | None = None) -> PartInfo:
         if not 1 <= part_number <= MAX_PART_ID:
             raise ErrInvalidPart(f"part number {part_number}")
-        # Same admission control as _put_object: concurrent part uploads
-        # must not bypass the PUT slots and thrash the single pipeline a
-        # 1-core host can sustain (measured 20% aggregate loss).
-        if _SINGLE_CORE:
-            with _encode_slot():
-                pi = self._put_object_part_inner(
-                    bucket, object_, upload_id, part_number, reader, size,
-                    opts)
-        else:
-            pi = self._put_object_part_inner(
-                bucket, object_, upload_id, part_number, reader, size,
-                opts)
+        pi = self._put_object_part_inner(
+            bucket, object_, upload_id, part_number, reader, size, opts)
         # Source-payload bytes of a committed part (op=multipart): the
         # write-amplification denominator, like put_object's.
         ioflow.logical(pi.size)
@@ -344,15 +333,11 @@ class MultipartMixin:
                     pass
 
         try:
-            if _SINGLE_CORE:
-                # Already inside the whole-part slot from put_object_part.
+            # Same admission as _put_object: part uploads take the PUT
+            # slots around the encode alone.
+            with _encode_slot():
                 total = encode_stream(erasure, tee, writers, write_quorum,
                                       telemetry="multipart")
-            else:
-                with _encode_slot():
-                    total = encode_stream(erasure, tee, writers,
-                                          write_quorum,
-                                          telemetry="multipart")
         except Exception:
             _drop_tmp()
             raise
@@ -618,8 +603,7 @@ class MultipartMixin:
         from .erasure_objects import _quorum_fanout
 
         with self._locked_write(bucket, object_):
-            _quorum_fanout(commit, len(disks_by_shard), disks_by_shard,
-                           errs, write_quorum)
+            _quorum_fanout(commit, len(disks_by_shard), errs, write_quorum)
         err = reduce_write_quorum_errs(errs, OBJECT_OP_IGNORED_ERRS, write_quorum)
         if err is not None:
             raise err

@@ -244,7 +244,7 @@ def reconstruct_matrix_from(
 def gf_matmul_shards_ref(mat: np.ndarray, shards: np.ndarray) -> np.ndarray:
     """Numpy reference: apply byte matrix [R, K] to shards [K, S] -> [R, S].
 
-    Used as the host-side oracle the JAX/Pallas kernels are tested against.
+    Used as the host-side oracle the JAX kernels are tested against.
     """
     mat = np.asarray(mat, dtype=np.uint8)
     shards = np.asarray(shards, dtype=np.uint8)

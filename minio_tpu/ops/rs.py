@@ -60,24 +60,10 @@ def _apply_bits(bitmat: jax.Array, shards: jax.Array) -> jax.Array:
 
 def apply_gf_matrix(bitmat, shards) -> jax.Array:
     """Public entry: bitmat int8 [8R,8K] (from gf.bit_matrix), shards
-    uint8 [..., K, S]. Leading dims are batch.
-
-    Kernel policy: the shipping path is the einsum, which XLA fuses
-    into one unpack/matmul/pack kernel. MTPU_RS_KERNEL=pallas opts in
-    to the Pallas kernel (ops/rs_pallas.py, kept bit-exact) on a TPU
-    backend; a kernel that does not compile there raises rather than
-    quietly running the einsum. Neither has a timing on record from
-    this attachment (ROADMAP D3).
-    """
-    import os
-
-    from . import rs_pallas
-
+    uint8 [..., K, S]. Leading dims are batch. XLA fuses the einsum
+    into one unpack/matmul/pack kernel."""
     bitmat = jnp.asarray(bitmat, dtype=jnp.int8)
     shards = jnp.asarray(shards, dtype=jnp.uint8)
-    if (os.environ.get("MTPU_RS_KERNEL", "einsum") == "pallas"
-            and rs_pallas.pallas_supported() and shards.shape[-1] >= 128):
-        return rs_pallas.apply_gf_matrix_pallas(bitmat, shards)
     return _apply_bits(bitmat, shards)
 
 

@@ -45,6 +45,7 @@ import threading
 import numpy as np
 
 from ..erasure.device_engine import (
+    HostFeed,
     _d2h_async,
     _is_device_array,
     _quiet_cpu_donation_warning,
@@ -179,8 +180,6 @@ class MeshCodec:
         executor's bounded queues, exactly like the device engine's
         HostFeed). Ragged batches stay on the host — encode_async pads
         and stages those itself."""
-        from ..ops.rs_pallas import HostFeed
-
         feed = getattr(self, "_feed", None)
         if feed is None:
             # Already-padded batches only: anything else staged here

@@ -6,7 +6,7 @@ per-stage telemetry exported through the observability metrics registry.
 This is the structural backbone of the erasure hot paths: PUT
 (source-read ∥ md5 ∥ encode ∥ bitrot-frame ∥ shard-write), GET's
 prefetching decode/bitrot-verify path, heal reconstruction, and the
-device engine's double-buffered host feed (ops/rs_pallas.HostFeed).
+device engine's double-buffered host feed (erasure/device_engine.HostFeed).
 Stages that run back-to-back cap e2e PUT far below the encoder: once
 the GF kernel is fast, pipeline structure, not the codec, dominates
 throughput (arXiv:2108.02692); the same staged overlap discipline feeds

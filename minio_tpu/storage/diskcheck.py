@@ -39,7 +39,6 @@ from dataclasses import dataclass
 
 from ..utils import parse_duration_s
 from ..utils.errors import ErrDiskFaulty, ErrDiskNotFound, ErrDiskOpTimeout
-from ..utils.fanout import SINGLE_CORE as _SINGLE_CORE
 
 # The ops that get counted/timed (the reference enumerates the same set
 # as storageMetric constants).
@@ -302,8 +301,7 @@ class MetricsDisk:
         def call(*args, **kwargs):
             self._check_id()
             h = self._health
-            guarded = h is not None and h.cfg.enabled
-            if guarded and not _SINGLE_CORE:
+            if h is not None and h.cfg.enabled:
                 if _spans.current() is None:
                     return self._call_guarded(op, fn, args, kwargs)
                 # Per-disk op latency on the request's span timeline —
@@ -320,26 +318,12 @@ class MetricsDisk:
                         "disk", f"{op}:{self._disk.endpoint()}",
                         time.monotonic_ns() - t0s,
                     )
-            if guarded and h.is_faulty():
-                # Single-core hosts skip the executor hop (the thread
-                # handoff per op is the measured cost the inline fan-out
-                # policy exists to avoid) but keep the breaker: latched
-                # disks fail fast, and a direct call that RETURNS past
-                # its deadline feeds the breaker post-hoc below so
-                # followers stop paying the stall.
-                raise ErrDiskFaulty(
-                    f"{self._disk.endpoint()}: circuit open, awaiting probe"
-                )
+            # No health tracker, or one switched off: the direct call.
             t0 = time.perf_counter()
             try:
                 with _spans.twin("disk", op):
                     out = fn(*args, **kwargs)
             except Exception:
-                if guarded:
-                    # A SLOW failure (stall that eventually errored) is
-                    # breaker evidence just like a slow success; only a
-                    # fast failure proves the disk responsive.
-                    self._posthoc_breaker(op, time.perf_counter() - t0)
                 if self._metrics is not None:
                     self._metrics.inc(
                         "disk_op_errors_total", op=op,
@@ -360,32 +344,12 @@ class MetricsDisk:
                         int((time.perf_counter() - t0) * 1e9),
                     )
                 _pace_note(time.perf_counter() - t0)
-            if guarded:
-                self._posthoc_breaker(op, time.perf_counter() - t0)
             return out
 
         call.__name__ = op
         return call
 
     # --- deadline + breaker enforcement ---
-
-    def _posthoc_breaker(self, op: str, elapsed: float) -> None:
-        """Breaker feed for the direct-call (single-core) path: a call
-        that RETURNED past its deadline still counts as a timeout so
-        followers stop paying the stall; anything faster resets the
-        streak."""
-        h = self._health
-        if elapsed > self._deadline_for(op):
-            if self._metrics is not None:
-                self._metrics.inc("disk_op_timeouts_total", op=op,
-                                  disk=self._disk.endpoint())
-            if h.record_timeout():
-                if self._metrics is not None:
-                    self._metrics.inc("disk_faulty_total",
-                                      disk=self._disk.endpoint())
-                self._start_probe()
-        else:
-            h.record_ok()
 
     def _deadline_for(self, op: str) -> float:
         cfg = self._health.cfg

@@ -1,20 +1,14 @@
-"""Shared fan-out policy for shard IO: on a single-core host, dispatching
-local (syscall-only) per-disk work through a thread pool buys no
-parallelism and costs ~280 us per 16-task dispatch (measured on the
-1-core bench host); remote/network IO overlaps on wire latency regardless
-of core count, so it always goes through the pool. One module owns the
-policy so the writer path (erasure/streaming.py), the reader path, and
-the object-layer fanouts (object/erasure_objects.py, object/metadata.py)
-can't drift apart."""
+"""Shared fan-out machinery for shard IO: local and remote per-disk work
+both go through a thread pool. One module owns the quorum-wait and
+straggler-detach protocol and the admission slots, so the writer path
+(erasure/streaming.py), the reader path, and the object-layer fanouts
+(object/erasure_objects.py, object/metadata.py) can't drift apart."""
 
 from __future__ import annotations
 
-import io
 import os
 import threading
 import time
-
-SINGLE_CORE = (os.cpu_count() or 1) == 1
 
 # Late straggler outcomes discarded after detach: the slot already
 # carries its timeout and MRF repairs the shard, but the DROP itself
@@ -46,12 +40,9 @@ def _note_late_drop(err) -> None:
 # concurrently; excess uploads queue FAIRLY (round-robin across
 # clients, per-client in-flight caps), deep queues reject immediately,
 # and a queue wait past the deadline returns 503 like the reference's
-# maxClients throttle (cmd/handler-api.go:36-78) — on a small host, N
-# concurrent encode pipelines thrash caches and aggregate BELOW one
-# serial stream (measured: 8-way 0.229 GB/s vs serial 0.283 on 1
-# core). The policy lives in pipeline/admission.AdmissionGovernor;
-# this wrapper exists so every encode entry point (PUT, multipart)
-# keeps one call shape.
+# maxClients throttle (cmd/handler-api.go:36-78). The policy lives in
+# pipeline/admission.AdmissionGovernor; this wrapper exists so every
+# encode entry point (PUT, multipart) keeps one call shape.
 ENCODE_SLOT_DEADLINE_S = float(
     os.environ.get("MTPU_ENCODE_SLOT_DEADLINE_S", "30")
 )
@@ -88,15 +79,6 @@ def heal_slot():
     from ..background.healpace import pacer
 
     return pacer().heal_slot()
-
-
-def is_local_sink(sink) -> bool:
-    """A sink whose write() is a local syscall/memory op (raw or buffered
-    file, fsync wrapper, BytesIO) — safe to run inline on 1 core."""
-    return (
-        hasattr(sink, "fileno")
-        or isinstance(sink, (io.BytesIO, io.BufferedWriter))
-    )
 
 
 class StragglerCompensator:
@@ -186,7 +168,7 @@ class QuorumFanout:
     """The detach state machine around quorum_wait, shared by the shard
     -write fan-out (ParallelWriter) and the commit/delete fan-outs
     (_quorum_fanout): dispatch attempt(i) for every index in `pending`
-    (plus `inline` synchronously), wait for quorum + grace, then detach
+    to the pool, wait for quorum + grace, then detach
     whatever is still in flight — stamping its outcome via on_detach,
     pairing each parked straggler with one compensator release when its
     worker finally frees, and discarding late results. One protocol,
@@ -209,7 +191,7 @@ class QuorumFanout:
             self.straggling.discard(i)
             self.comp.released()
 
-    def dispatch(self, attempt, pending, inline, quorum,
+    def dispatch(self, attempt, pending, quorum,
                  deadline_s, grace_s, *, count_ok, record,
                  on_detach, skip=None, on_stragglers=None):
         from ..observability import carry as _obs_carry
@@ -255,8 +237,6 @@ class QuorumFanout:
         bound_run = _obs_carry(run)
         for i in sorted(pending):
             self.pool.submit(bound_run, i)
-        for i in inline:
-            run(i)
 
         quorum_wait(cv, pending, count_ok, quorum, deadline_s, grace_s)
         with cv:

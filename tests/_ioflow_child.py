@@ -6,11 +6,11 @@ snapshots, the metrics exposition, and the new admin endpoint payloads
 as JSON so the parent can reconcile byte totals against the payload
 sizes it knows.
 
-cpu_count is pinned to 4 BEFORE any minio_tpu import so
-fanout.SINGLE_CORE and the worker-pool probe see a multicore host —
-the worker processes and shm segments are real; only the core count is
-faked (the ledger counts parent-side syscall bytes, identical either
-way)."""
+cpu_count is pinned to 4 for the worker-pool probe alone
+(pipeline/workers.py disarms with reason `cores` under two, and sizes
+the pool from the count): the worker processes and shm segments are
+real; only the core count is faked (the ledger counts parent-side
+syscall bytes, identical either way)."""
 
 import json
 import os
@@ -21,7 +21,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.pop("MTPU_WORKER_POOL", None)
 os.environ.pop("MTPU_IOFLOW", None)
-os.cpu_count = lambda: 4  # must precede every minio_tpu import
+os.cpu_count = lambda: 4  # read by workers.armed() and the governors
 
 PAYLOAD_MIB = 12
 K, M = 12, 4
@@ -46,9 +46,6 @@ def main(tmp: str) -> None:
     from minio_tpu.observability.metrics_v2 import MetricsCollector
     from minio_tpu.pipeline import workers
     from minio_tpu.storage.local import LocalStorage
-    from minio_tpu.utils import fanout
-
-    assert not fanout.SINGLE_CORE, "cpu_count pin must precede imports"
 
     reg = Metrics()
     access, secret = "tpuadmin", "tpuadmin-secret-key"

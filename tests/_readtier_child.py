@@ -6,10 +6,10 @@ armed serves a 6 MiB hot key, and the byte-flow ledger shows that
   exactly ONE decode's dir="read" shard bytes (single-flight), and
 - a warm GET costs ZERO dir="read" bytes (decoded-block cache hit).
 
-cpu_count is pinned to 4 BEFORE any minio_tpu import so
-fanout.SINGLE_CORE and the worker-pool probe see a multicore host —
-the worker processes, shm segments, and the threaded server are real;
-only the core count is faked (this container has 1 core)."""
+cpu_count is pinned to 4 for the worker-pool probe alone
+(pipeline/workers.py disarms with reason `cores` under two, and sizes
+the pool from the count): the worker processes, shm segments, and the
+threaded server are real; only the core count is faked."""
 
 import json
 import os
@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.pop("MTPU_WORKER_POOL", None)
 os.environ["MTPU_READTIER"] = "on"
-os.cpu_count = lambda: 4  # must precede every minio_tpu import
+os.cpu_count = lambda: 4  # read by workers.armed() and the governors
 
 
 def main(tmp: str) -> None:
@@ -41,9 +41,6 @@ def main(tmp: str) -> None:
     from minio_tpu.pipeline import workers
     from minio_tpu.pipeline.admission import read_governor
     from minio_tpu.storage.local import LocalStorage
-    from minio_tpu.utils import fanout
-
-    assert not fanout.SINGLE_CORE, "cpu_count pin must precede imports"
 
     access, secret = "tpuadmin", "tpuadmin-secret-key"
     disks = [

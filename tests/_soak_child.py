@@ -1,9 +1,10 @@
 """Forced-multicore child for the soak gate's worker-kill proof
-(tests/test_chaos_soak.py): cpu_count is pinned to 4 BEFORE any
-minio_tpu import (the _span_child/_ioflow_child convention) so the
-worker pool REALLY spawns child processes on the 1-core CI host — the
-scenario's kill -9 then lands on a live worker pid, and the pool must
-fall back byte-identically, respawn, and leave no orphans.
+(tests/test_chaos_soak.py): cpu_count is pinned to 4 (the
+_span_child/_ioflow_child convention) for the worker-pool probe alone
+(pipeline/workers.py disarms with reason `cores` under two), so the
+pool REALLY spawns child processes on any CI host — the scenario's
+kill -9 then lands on a live worker pid, and the pool must fall back
+byte-identically, respawn, and leave no orphans.
 
 Prints the scenario artifact plus the pool snapshot as JSON."""
 
@@ -15,15 +16,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.pop("MTPU_WORKER_POOL", None)
-os.cpu_count = lambda: 4  # must precede every minio_tpu import
+os.cpu_count = lambda: 4  # read by workers.armed() and the governors
 
 
 def main(tmp: str, seed: int) -> None:
     from minio_tpu.faults.scenarios import ScenarioSpec, run_scenario
     from minio_tpu.pipeline import workers
-    from minio_tpu.utils import fanout
 
-    assert not fanout.SINGLE_CORE, "cpu_count pin must precede imports"
     pool = workers.armed()
     out: dict = {"arm_reason": workers.arm_reason()}
     if pool is None:

@@ -4,11 +4,11 @@ serves a signed PUT and a degraded GET (both data shards destroyed)
 under MTPU_TRACE_SLOW_MS=0, then emits the captured span trees, the
 admin slow-requests payload, and the metrics exposition as JSON.
 
-cpu_count is pinned to 4 BEFORE any minio_tpu import so
-fanout.SINGLE_CORE and the worker-pool probe see a multicore host —
-the worker processes and shm segments are real; only the core count is
-faked (byte paths are identical either way; this container has 1
-core)."""
+cpu_count is pinned to 4 for the worker-pool probe alone
+(pipeline/workers.py disarms with reason `cores` under two, and sizes
+the pool from the count): the worker processes and shm segments are
+real; only the core count is faked (byte paths are identical either
+way)."""
 
 import json
 import os
@@ -19,7 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["MTPU_TRACE_SLOW_MS"] = "0"
 os.environ.pop("MTPU_WORKER_POOL", None)
-os.cpu_count = lambda: 4  # must precede every minio_tpu import
+os.cpu_count = lambda: 4  # read by workers.armed() and the governors
 
 
 def main(tmp: str) -> None:
@@ -41,9 +41,6 @@ def main(tmp: str) -> None:
     from minio_tpu.pipeline import admission as _admission
     from minio_tpu.pipeline import workers
     from minio_tpu.storage.local import LocalStorage
-    from minio_tpu.utils import fanout
-
-    assert not fanout.SINGLE_CORE, "cpu_count pin must precede imports"
 
     reg = Metrics()
     hub = TraceHub()

@@ -19,6 +19,7 @@ from minio_tpu.erasure.streaming import encode_stream, heal_stream
 from minio_tpu.ops import gf
 from minio_tpu.ops.gf import gf_matmul_shards_ref
 from minio_tpu.ops.highwayhash import hash256
+from minio_tpu.observability import spans
 
 GEOMETRIES = [(2, 2), (8, 4), (12, 4)]
 
@@ -205,3 +206,66 @@ def test_heal_stream_device_matches_host(monkeypatch):
         )
     # The two full blocks rode the fused device path (>= 1 dispatch).
     assert device_engine.stats_snapshot()["dispatches"] >= 1
+
+
+# --- HostFeed, the H2D staging stage of both engines ---
+
+
+@pytest.fixture
+def captured(monkeypatch):
+    monkeypatch.setenv("MTPU_TRACE_SLOW_MS", "0")
+    monkeypatch.delenv("MTPU_TRACE", raising=False)
+    spans.reset()
+    yield
+    spans.reset()
+
+
+def _fed(feed, batch):
+    """-> (what the feed returned, its device-h2d spans' labels)."""
+    with spans.request_trace("put_object"):
+        out = feed(batch)
+    tree = spans.slow_requests()[-1]
+    return out, [s["label"] for s in tree["spans"]
+                 if s["kind"] == "device-h2d"]
+
+
+def test_host_feed_declines_what_accept_refuses(captured):
+    """A declined batch passes through on the host, the same object, and
+    no transfer is recorded: the codec downstream stages it itself."""
+    feed = device_engine.HostFeed(accept=lambda b: b.shape[0] % 4 == 0)
+    ragged = np.zeros((3, 4, 128), dtype=np.uint8)
+    out, labels = _fed(feed, ragged)
+    assert out is ragged
+    assert labels == []
+
+
+def test_host_feed_stages_and_completes_the_transfer(captured):
+    """An accepted batch comes back as a device array whose transfer is
+    done inside the stage, byte for byte the host batch."""
+    feed = device_engine.HostFeed()
+    assert feed.name == "h2d"
+    batch = np.random.default_rng(5).integers(
+        0, 256, size=(4, 4, 128), dtype=np.uint8)
+    out, labels = _fed(feed, batch)
+    assert device_engine._is_device_array(out)
+    assert out.is_ready()
+    assert np.array_equal(np.asarray(out), batch)
+    assert len(labels) == 1
+
+
+def test_host_feed_labels_the_span_by_engine(captured):
+    """`device_h2d_ms_per_op.*` reads the span by kind; its label says
+    which engine staged: `mesh` with a sharding, `device` without."""
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    batch = np.ones((4, 4, 128), dtype=np.uint8)
+    _, labels = _fed(device_engine.HostFeed(), batch)
+    assert labels == ["device"]
+    sharding = NamedSharding(
+        Mesh(np.array(jax.devices()[:4]), ("dp",)), PartitionSpec("dp"))
+    out, labels = _fed(
+        device_engine.HostFeed("h2d-mesh", sharding=sharding), batch)
+    assert labels == ["mesh"]
+    assert out.sharding == sharding
+    assert np.array_equal(np.asarray(out), batch)

@@ -14,20 +14,6 @@ import time
 
 import pytest
 
-# The hung-drive tolerance mechanisms these scenarios assert — per-op
-# executor deadlines, stall-based hedging, fan-out thread overlap — are
-# DELIBERATELY disabled on 1-core hosts by the measured fanout policy
-# (utils/fanout.SINGLE_CORE; diskcheck skips the executor hop there).
-# On such a host the injected hang blocks the calling thread inline for
-# the full MAX_HANG_S cap (120 s each), so the tests would burn 480 s
-# of tier-1 budget asserting behavior the policy intentionally does not
-# provide. Multicore CI keeps them load-bearing.
-needs_cores = pytest.mark.skipif(
-    (os.cpu_count() or 1) < 2,
-    reason="deadline/hedge enforcement is executor-based; 1-core hosts "
-           "run storage ops inline by design",
-)
-
 from minio_tpu.erasure import streaming as _streaming
 from minio_tpu.faults import FaultDisk, NaughtyDisk
 from minio_tpu.object.erasure_objects import ErasureObjects
@@ -277,7 +263,6 @@ def test_seeded_latency_and_bitrot_kinds(tmp_path):
 # hung-drive tolerance (quorum-wait fan-out, hedged reads, breaker)
 
 
-@needs_cores
 def test_hung_writer_mid_put_returns_at_quorum(tmp_path):
     """One drive hangs indefinitely on shard writes: the PUT must return
     once write quorum + straggler grace pass (bounded by the knobs, not
@@ -307,7 +292,6 @@ def test_hung_writer_mid_put_returns_at_quorum(tmp_path):
     assert sum(1 for d in disks if _readable(d, "flt", "hungput")) == 4
 
 
-@needs_cores
 def test_hedged_get_beats_hung_shard(tmp_path):
     """A drive hangs on read_file_stream for a shard the reader prefers:
     after the hedge delay a parity shard is dispatched instead, and the
@@ -375,7 +359,6 @@ def test_fanout_fails_fast_when_quorum_impossible():
         release.set()
 
 
-@needs_cores
 def test_breaker_latches_and_probe_readmits(tmp_path):
     """Consecutive op timeouts latch the disk faulty (ErrDiskFaulty,
     instantly — no more deadline waits); once the fault clears, the
@@ -411,7 +394,6 @@ def test_breaker_latches_and_probe_readmits(tmp_path):
         assert health.readmitted_total >= 1
 
 
-@needs_cores
 def test_hung_drive_end_to_end_put_get_latch_readmit_heal(tmp_path):
     """Acceptance: one drive armed to hang indefinitely. A
     quorum-satisfiable PUT and GET both complete within

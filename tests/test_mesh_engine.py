@@ -16,7 +16,8 @@ from minio_tpu.erasure.bitrot import (
     StreamingBitrotReader,
     StreamingBitrotWriter,
 )
-from minio_tpu.erasure.codec import Erasure, _select_engine
+from minio_tpu.erasure import registry
+from minio_tpu.erasure.codec import Erasure
 from minio_tpu.erasure.streaming import (
     decode_stream,
     encode_stream,
@@ -56,16 +57,16 @@ def test_engine_selection_mesh_and_fallbacks(monkeypatch):
     monkeypatch.delenv("MTPU_MESH_SHAPE", raising=False)
     shard = 1 << 14
     monkeypatch.setenv("MTPU_ENCODE_ENGINE", "mesh")
-    assert _select_engine(shard, 16) == "mesh"
+    assert registry.select_engine(shard, 16) == "mesh"
     # No geometry -> the one-shot host helpers never route to the mesh.
-    assert _select_engine(shard) != "mesh"
+    assert registry.select_engine(shard) != "mesh"
     # Geometry that shares no lane divisor with 8 devices -> fallback.
-    assert _select_engine(shard, 5) in ("native", "numpy")
+    assert registry.select_engine(shard, 5) in ("native", "numpy")
     # Tiny shards stay on the host engines (dispatch cost dominates).
-    assert _select_engine(64, 16) in ("native", "numpy")
+    assert registry.select_engine(64, 16) in ("native", "numpy")
     # 'auto' on a CPU virtual mesh must NOT self-select collectives.
     monkeypatch.setenv("MTPU_ENCODE_ENGINE", "auto")
-    assert _select_engine(shard, 16) != "mesh"
+    assert registry.select_engine(shard, 16) != "mesh"
 
 
 # ---------------------------------------------------------------------------

@@ -205,39 +205,52 @@ def _mk_writers(n=8):
     ]
 
 
-def test_pipelined_encode_stream_matches_serial():
-    """The pipelined encode driver must produce byte-identical shard
-    files to the serial one, for sizes crossing every batch/tail edge."""
-    from minio_tpu.erasure.codec import Erasure
+def _oracle_shard_files(er, payload: bytes) -> list[bytes]:
+    """Shard files made without any streaming driver: Erasure.encode_data
+    block by block, each shard framed by its own StreamingBitrotWriter."""
+    sinks, writers = _mk_writers()
+    bs = er.block_size
+    blocks = [payload[o:o + bs] for o in range(0, len(payload), bs)] or [b""]
+    for block in blocks:
+        for w, shard in zip(writers, er.encode_data(block)):
+            w.write(shard)
+    return [s.getvalue() for s in sinks]
+
+
+def _run_native_pipelined(er, src, writers):
     from minio_tpu.erasure.streaming import (
         ParallelWriter,
-        _encode_stream_native,
         _encode_stream_native_pipelined,
-        encode_stream,
     )
 
+    return _encode_stream_native_pipelined(
+        er, src, ParallelWriter(writers, 7), 8, "test"
+    )
+
+
+def _run_encode_stream(er, src, writers):
+    from minio_tpu.erasure.streaming import encode_stream
+
+    return encode_stream(er, src, writers, 7, telemetry="test")
+
+
+@pytest.mark.parametrize("driver", [_run_native_pipelined,
+                                    _run_encode_stream],
+                         ids=["native_pipelined", "encode_stream"])
+@pytest.mark.parametrize("size", [0, 1, (1 << 16) - 1, 1 << 16,
+                                  9 * (1 << 16) + 13, 17 * (1 << 16)])
+def test_encode_drivers_match_block_by_block_oracle(driver, size):
+    """Every encode driver must produce shard files byte-identical to
+    the block-by-block oracle, for sizes crossing every batch/tail
+    edge: the pipelined native driver by name, and the public entry
+    point with whichever driver it picks on this host."""
+    from minio_tpu.erasure.codec import Erasure
+
     er = Erasure(6, 2, 1 << 16)  # small blocks: many batches, fast
-    for size in (0, 1, (1 << 16) - 1, 1 << 16, 9 * (1 << 16) + 13,
-                 17 * (1 << 16)):
-        payload = os.urandom(size)
-        sinks_a, writers_a = _mk_writers()
-        n_a = _encode_stream_native(
-            er, io.BytesIO(payload), ParallelWriter(writers_a, 7), 8
-        )
-        sinks_b, writers_b = _mk_writers()
-        n_b = _encode_stream_native_pipelined(
-            er, io.BytesIO(payload), ParallelWriter(writers_b, 7), 8, "test"
-        )
-        assert n_a == n_b == size
-        for sa, sb in zip(sinks_a, sinks_b):
-            assert sa.getvalue() == sb.getvalue(), size
-        # And the public entry point agrees with whichever driver it picked.
-        sinks_c, writers_c = _mk_writers()
-        n_c = encode_stream(er, io.BytesIO(payload), writers_c, 7,
-                            telemetry="test")
-        assert n_c == size
-        for sa, sc in zip(sinks_a, sinks_c):
-            assert sa.getvalue() == sc.getvalue(), size
+    payload = os.urandom(size)
+    sinks, writers = _mk_writers()
+    assert driver(er, io.BytesIO(payload), writers) == size
+    assert [s.getvalue() for s in sinks] == _oracle_shard_files(er, payload)
 
 
 def test_pipelined_encode_cancels_on_writer_failure():
@@ -293,7 +306,7 @@ def test_shared_strip_pool_flat_across_puts():
 
     one_put()  # warm the pool to its high-water mark
     key = ("blocks-major", 6, 8, er.shard_size())
-    if key not in _shared:  # single-core host: serial driver, no pool
+    if key not in _shared:  # worker-pool or non-native driver: other pool
         pytest.skip("pipelined driver not active on this host")
     high_water = _shared[key].stats()["allocated"]
     for _ in range(5):

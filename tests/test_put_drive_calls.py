@@ -3,8 +3,8 @@ calls as they were: in turn, on the request's thread). On fake drives:
 the bucket check's answer for every mix of drives, and that it asks
 them every time; a PUT or a part whose drives fail at the open holds
 write quorum or leaves nothing staged behind; every sink is closed and
-synced before the first rename; on one core the commit stays inline
-too; the request's tree keeps every disk record."""
+synced before the first rename; the commit alone goes through the
+pool; the request's tree keeps every disk record."""
 
 import io
 import os
@@ -12,7 +12,6 @@ import threading
 
 import pytest
 
-from minio_tpu.object import erasure_objects as eo
 from minio_tpu.object.erasure_objects import ErasureObjects
 from minio_tpu.observability import spans
 from minio_tpu.storage import local as local_storage
@@ -246,12 +245,11 @@ def test_every_sink_is_closed_before_the_first_rename(tmp_path, monkeypatch,
     assert _get(es, "k") == BODY
 
 
-def test_on_one_core_the_calls_stay_inline_and_in_turn(tmp_path, monkeypatch):
-    """utils.fanout.SINGLE_CORE, as the object layer reads it: the
-    commit's renames join the bucket check, the opens and the closes on
-    the caller's thread, one drive after the other."""
+def test_the_commit_alone_leaves_the_callers_thread(tmp_path):
+    """The bucket check, the opens and the closes run on the caller's
+    thread, one drive after the other; the commit's renames go through
+    the fan-out pool, one task a drive, on any host."""
     es, _drives, log = _set(tmp_path, 6, 2)
-    monkeypatch.setattr(eo, "_SINGLE_CORE", True)
     del log[:]
     assert es.bucket_exists("b")
     es.put_object("b", "k", io.BytesIO(BODY), len(BODY))
@@ -259,7 +257,8 @@ def test_on_one_core_the_calls_stay_inline_and_in_turn(tmp_path, monkeypatch):
     for what in ("stat_vol", "create_file_writer", "closed", "rename_data"):
         calls = [(i, t) for w, i, t in log if w == what]
         assert len(calls) == 6, (what, calls)
-        assert {t for _i, t in calls} == {me}, what
+        on_caller = {t for _i, t in calls} == {me}
+        assert on_caller == (what != "rename_data"), what
     # in turn: by drive for the check, by shard for the opens and closes
     assert [i for w, i, _ in log if w == "stat_vol"] == list(range(6))
     opened = [i for w, i, _ in log if w == "create_file_writer"]
@@ -295,8 +294,6 @@ def test_the_tree_keeps_every_disk_record(tmp_path, captured):
     the PUT. Every per-drive call is a `disk` record under the root,
     whichever thread made it: the check and the opens on the request's
     own, the shard writes and the renames on the pools'."""
-    if eo._SINGLE_CORE:
-        pytest.skip("the health wrapper records no disk span on one core")
     es, _drives, _log = _set(tmp_path, 16, 4, health=True)
     body = bytes(range(256)) * (3 * MIB // 256)
     with spans.request_trace("put_object"):

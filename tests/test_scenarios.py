@@ -478,7 +478,7 @@ def test_bounded_hang_stalls_then_proceeds():
 
 def test_legacy_hang_wedges_until_disarm():
     """hold_s=0 keeps the legacy wedge: the op blocks until disarm
-    (or MAX_HANG_S) — the shape diskcheck's posthoc breaker exists
+    (or MAX_HANG_S) — the shape diskcheck's per-op deadline exists
     for."""
     from minio_tpu.faults.injector import FaultSchedule
 

@@ -20,7 +20,6 @@ from minio_tpu.api.server import LimitedReader
 from minio_tpu.erasure import registry
 from minio_tpu.observability import spans
 from minio_tpu.observability.metrics import Metrics
-from minio_tpu.utils.fanout import SINGLE_CORE
 
 MIB = 1 << 20
 # what every traced PUT or heal of the device engine has to show
@@ -145,15 +144,12 @@ def test_put_10mib_12p4_tree_holds_every_layer(plane):
     d0, t0 = _dispatches(reg), _traces(reg)
     tree = n16.put("k", 10 * MIB)
     kinds = _kinds(tree)
-    want = LAYER_KINDS | {"body-read", "admission"}
-    if not SINGLE_CORE:
-        want |= {"stage"}
+    want = LAYER_KINDS | {"body-read", "admission", "stage"}
     assert want <= set(kinds), want - set(kinds)
     _assert_nested(tree)
     assert tree["api"] == "put_object" and "stats" not in tree
     assert [s["label"] for s in kinds["object"]] == ["put"]
-    assert [s["label"] for s in kinds["stream"]] == [
-        "batched_serial" if SINGLE_CORE else "batched_pipelined"]
+    assert [s["label"] for s in kinds["stream"]] == ["batched_pipelined"]
     # 10 blocks: a batch of 8 and a batch of 2, one dispatch each
     assert len(kinds["device-call"]) == _dispatches(reg) - d0 == 2
     assert {s["label"] for s in kinds["device-call"]} == {"enc"}
@@ -172,8 +168,6 @@ def test_put_10mib_fans_its_shard_writes_out_once_a_batch(plane):
     a whole batch in one task (ISSUE 28): a 10 MiB PUT waits for three
     quorums (the 8-block batch, the 2-block batch, the commit), not for
     eleven, and what it wrote reads back bitrot-verified."""
-    if SINGLE_CORE:
-        pytest.skip("the serial driver writes block by block, inline")
     n16 = plane["n16"]
     body = os.urandom(10 * MIB)
     with spans.request_trace("put_object"):
@@ -196,11 +190,10 @@ def test_put_1mib_2p2_runs_inline_and_is_not_dark(plane):
     assert LAYER_KINDS | {"body-read", "admission"} <= set(kinds), \
         set(kinds)
     _assert_nested(tree)
-    if not SINGLE_CORE:
-        # one block never builds a Pipeline: no stage, and still every
-        # device phase is on the tree
-        assert [s["label"] for s in kinds["stream"]] == ["inline"]
-        assert "stage" not in kinds
+    # one block never builds a Pipeline: no stage, and still every
+    # device phase is on the tree
+    assert [s["label"] for s in kinds["stream"]] == ["inline"]
+    assert "stage" not in kinds
     assert len(kinds["device-call"]) == _dispatches(reg) - d0 == 1
 
 
@@ -294,6 +287,35 @@ def test_heal_under_a_request_keeps_the_outer_root(plane):
     trees = spans.slow_requests()
     assert [t["api"] for t in trees] == ["heal_admin"]
     assert "object" in _kinds(trees[0])
+
+
+def test_put_get_and_heal_record_disk_spans_behind_the_health_wrapper(plane):
+    """The hung-drive guard of storage/diskcheck holds on every host, and
+    with it the `disk` span: a PUT's per-drive calls, the shard reads of
+    a ten-block GET on the reader's pool threads and a heal's reads and
+    renames each land on their request's tree, by op and drive."""
+    n16 = plane["n16"]
+    put = n16.put("d1", 10 * MIB)
+    spans.clear_slow_requests()
+    sink = io.BytesIO()
+    with spans.request_trace("get_object"):
+        n16.es.get_object("b", "d1", sink)
+    get = spans.slow_requests()[-1]
+    assert len(sink.getvalue()) == 10 * MIB
+    n16.wipe(3, 7)
+    spans.clear_slow_requests()
+    assert len(n16.es.heal_object("b", "d1")["healed"]) == 2
+    heal = spans.slow_requests()[-1]
+    drives = {f"d{i}" for i in range(16)}
+    for tree, api, op, at_least in ((put, "put_object", "rename_data", 16),
+                                    (get, "get_object", "read_file_stream",
+                                     12),
+                                    (heal, "heal_object", "rename_data", 2)):
+        assert tree["api"] == api
+        labels = [s["label"] for s in _kinds(tree)["disk"]]
+        assert all(lb.split(":")[1] in drives for lb in labels), labels
+        hit = {lb.split(":")[1] for lb in labels if lb.startswith(op + ":")}
+        assert len(hit) >= at_least, (api, sorted(labels))
 
 
 def test_heal_root_stays_out_of_the_slow_request_window(monkeypatch):
@@ -436,9 +458,8 @@ def test_mirrored_spans_never_nest_on_a_thread(plane, monkeypatch):
     assert all(not held for held in stub.open_by_thread.values())
     kinds = {n.split()[0] for n in stub.names}
     want = {"mtpu:body-read", "mtpu:admission", "mtpu:device-h2d",
-            "mtpu:device-call", "mtpu:device-wait", "mtpu:disk"}
-    if not SINGLE_CORE:
-        want |= {"mtpu:stage", "mtpu:stage-wait", "mtpu:commit"}
+            "mtpu:device-call", "mtpu:device-wait", "mtpu:disk",
+            "mtpu:stage", "mtpu:stage-wait", "mtpu:commit"}
     assert want <= kinds, want - kinds
     # the parents that would swallow every gap under them stay off
     assert not kinds & {"mtpu:request", "mtpu:object", "mtpu:stream"}
@@ -446,10 +467,8 @@ def test_mirrored_spans_never_nest_on_a_thread(plane, monkeypatch):
     assert "mtpu:disk rename_data" in stub.names
     assert {"mtpu:device-call enc", "mtpu:device-call rec"} <= set(
         stub.names)
-    if not SINGLE_CORE:
-        stages = {n for n in stub.names if n.startswith("mtpu:stage ")}
-        assert stages <= {"mtpu:stage put/md5", "mtpu:stage put/pack"}, \
-            stages
+    stages = {n for n in stub.names if n.startswith("mtpu:stage ")}
+    assert stages <= {"mtpu:stage put/md5", "mtpu:stage put/pack"}, stages
     # an outer annotation suppresses the inner one instead of nesting
     stub.names.clear()
     with spans.request_trace("put_object"):

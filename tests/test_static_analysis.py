@@ -60,6 +60,35 @@ def test_repo_scan_is_clean():
     )
 
 
+def test_the_core_count_fork_and_what_went_with_it_stay_out():
+    """One path per job (ISSUE 33): the fork on the host's core count,
+    the Pallas RS kernel with its knob, and the engine selector's shim
+    in erasure/codec.py are gone from the product tree and its docs.
+    The names are spelled in halves so that a grep of tests/ for them
+    finds nothing either."""
+    import re
+
+    gone = re.compile("|".join((
+        "SINGLE" + "_CORE",
+        "MTPU_RS" + "_KERNEL",
+        "rs" + "_pallas",
+        r"\b_select" + "_engine\\b",
+        "_DEVICE_SHARD" + "_THRESHOLD",
+    )))
+    paths = [os.path.join(REPO, "README.md")]
+    for top in ("minio_tpu", "docs"):
+        for root, _dirs, files in os.walk(os.path.join(REPO, top)):
+            paths += [os.path.join(root, f) for f in files
+                      if f.endswith((".py", ".md", ".c", ".h"))]
+    assert len(paths) > 100
+    hits = []
+    for path in paths:
+        with open(path, encoding="utf-8", errors="replace") as fh:
+            hits += [f"{os.path.relpath(path, REPO)}:{n}: {line.strip()}"
+                     for n, line in enumerate(fh, 1) if gone.search(line)]
+    assert hits == [], "\n".join(hits)
+
+
 def test_self_check_scans_the_analyzer():
     paths = engine.discover(REPO)
     assert "tools/analysis/engine.py" in paths

@@ -80,10 +80,8 @@ def _readers(er: Erasure, shard_files: list, total: int, kill=()):
         def open_stream(off, ln, b=sf):
             return io.BytesIO(b[off: off + ln])
 
-        r = StreamingBitrotReader(open_stream, er.shard_file_size(total),
-                                  er.shard_size())
-        r.local = True
-        rs.append(r)
+        rs.append(StreamingBitrotReader(
+            open_stream, er.shard_file_size(total), er.shard_size()))
     return rs
 
 

@@ -53,6 +53,9 @@ DESCRIPTORS: list[tuple[str, str, str]] = [
      "Storage ops abandoned at their wall-clock deadline"),
     ("disk_inflight_rejected_total", "counter",
      "Storage ops rejected because the per-disk token budget was full"),
+    ("disk_guard_inline_total", "counter",
+     "Guarded storage ops run on their quorum fan-out's worker, with no "
+     "hand-off to the drive's executor, by op"),
     ("disk_faulty_total", "counter",
      "Circuit-breaker latch events (disk marked faulty)"),
     ("disk_readmit_total", "counter",

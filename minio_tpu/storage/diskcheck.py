@@ -12,6 +12,7 @@ surface. Metrics land in the shared registry as
   mtpu_disk_op_errors_total{op=...,disk=...}
   mtpu_disk_op_seconds{op=...}            (histogram)
   mtpu_disk_op_timeouts_total{op=...,disk=...}
+  mtpu_disk_guard_inline_total{op=...}
   mtpu_disk_faulty_total{disk=...} / mtpu_disk_readmit_total{disk=...}
 mirroring the reference's storageMetric counters
 (cmd/xl-storage-disk-id-check.go:33-75).
@@ -19,7 +20,12 @@ mirroring the reference's storageMetric counters
 Health tracking (opt-in via a DiskHealth instance):
 - every timed op runs under a per-op wall-clock deadline — a hung NFS
   mount or dying HDD costs the caller at most the deadline, never an
-  unbounded stall (ref diskHealthCheck's context deadlines);
+  unbounded stall (ref diskHealthCheck's context deadlines). The op runs
+  on a thread of the drive's own executor and the caller waits for it
+  with the deadline — unless the caller is itself a QuorumFanout worker
+  whose dispatcher waits no longer than that and detaches it
+  (utils/fanout.Waited): then the op runs on the worker, and the
+  fan-out's detach is what starts the deadline's consequences;
 - a bounded per-disk in-flight token budget: once `max_inflight` ops
   are stuck on one disk, further calls fail fast with ErrDiskFaulty
   instead of queueing more threads behind the hang;
@@ -39,6 +45,7 @@ from dataclasses import dataclass
 
 from ..utils import parse_duration_s
 from ..utils.errors import ErrDiskFaulty, ErrDiskNotFound, ErrDiskOpTimeout
+from ..utils.fanout import waited as _waited
 
 # The ops that get counted/timed (the reference enumerates the same set
 # as storageMetric constants).
@@ -249,6 +256,65 @@ class DiskHealth:
         }
 
 
+class _Watch:
+    """One guarded op running on a QuorumFanout worker. Nobody waits on
+    a future for it; the fan-out's detach (detached) and the call's
+    return (ended) race instead, and exactly one of them accounts for
+    the op. A detach is no timeout yet — a fan-out leaves a straggler
+    one grace after quorum — so it arms a timer for what is left of the
+    op's own deadline, and an op still running then is written off as
+    the hop's waiter would have written it off."""
+
+    __slots__ = ("_disk", "_op", "_deadline_s", "_due", "_mu", "_over",
+                 "_timer")
+
+    def __init__(self, disk: "MetricsDisk", op: str, deadline_s: float):
+        self._disk = disk
+        self._op = op
+        self._deadline_s = deadline_s
+        self._due = time.monotonic() + deadline_s
+        self._mu = threading.Lock()
+        self._over = False   # guarded-by: _mu
+        self._timer = None   # guarded-by: _mu
+
+    def detached(self) -> None:
+        """The fan-out has walked away. Runs on its dispatcher's thread
+        (or this op's own, see Waited.watch); a second call is a no-op."""
+        from ..observability import carry as _obs_carry
+
+        with self._mu:
+            if self._over or self._timer is not None:
+                return
+            left = self._due - time.monotonic()
+            if left > 0:
+                # The caller's byte-flow tag rides along: the pacer
+                # filters background ops by it.
+                self._timer = threading.Timer(left, _obs_carry(self._expire))
+                self._timer.name = "mtpu-dh-watch"
+                self._timer.daemon = True
+                self._timer.start()
+                return
+        self._expire()
+
+    def _expire(self) -> None:
+        with self._mu:
+            if self._over:
+                return
+            self._over = True
+        self._disk._note_timeout(self._op, self._deadline_s)
+
+    def ended(self) -> bool:
+        """The call returned. True when it made its deadline, and the
+        caller accounts for it; False when it was written off."""
+        with self._mu:
+            in_time = not self._over
+            self._over = True
+            timer = self._timer
+        if timer is not None:
+            timer.cancel()
+        return in_time
+
+
 class MetricsDisk:
     """Transparent StorageAPI proxy adding per-op metrics, periodic
     disk-id re-validation (ref checkDiskStale,
@@ -360,9 +426,13 @@ class MetricsDisk:
         # Lazily created per disk; sized to the token budget, so the
         # pool can never queue behind stuck ops (acquire() bounds
         # submissions). One hung disk pins at most max_inflight threads
-        # HERE instead of draining the shared erasure IO pool. Creation
-        # is double-checked under a lock: two racing first ops must not
-        # each build an executor and leak the loser's worker thread.
+        # HERE instead of draining the caller's pool — except under a
+        # QuorumFanout (_run_on_worker), where the op stays on the
+        # fan-out's worker: a hung commit pins that one worker, not it
+        # and a thread here, and the fan-out's StragglerCompensator
+        # makes the worker up. Creation is double-checked under a lock:
+        # two racing first ops must not each build an executor and leak
+        # the loser's worker thread.
         # guardedby-ok: double-checked fast path — a stale None read
         # falls through to the locked re-check below
         pool = self._deadline_pool
@@ -401,6 +471,11 @@ class MetricsDisk:
                 f"{ep}: {h.cfg.max_inflight} ops in flight for {deadline_s}s"
             )
 
+        mark = _waited()
+        if mark is not None and mark.deadline_s <= deadline_s:
+            return self._run_on_worker(mark, op, ep, fn, args, kwargs,
+                                       deadline_s, t0)
+
         def run():
             try:
                 return fn(*args, **kwargs)
@@ -419,40 +494,69 @@ class MetricsDisk:
         try:
             out = fut.result(timeout=deadline_s)
         except _FutTimeout:
-            latched = h.record_timeout()
-            if self._metrics is not None:
-                self._metrics.inc("disk_op_timeouts_total", op=op, disk=ep)
-                self._metrics.inc("disk_op_errors_total", op=op, disk=ep)
-                self._metrics.inc("disk_ops_total", op=op, disk=ep)
-                if latched:
-                    self._metrics.inc("disk_faulty_total", disk=ep)
-            if latched:
-                self._start_probe()
-            # An abandoned op cost its caller the FULL deadline — that
-            # is the latency the pacer's pressure window must see.
-            _pace_note(deadline_s)
+            self._note_timeout(op, deadline_s)
             raise ErrDiskOpTimeout(
                 f"{op} on {ep} exceeded {deadline_s}s deadline"
             ) from None
         except Exception:
-            # A FAST failure (missing file, bad volume) proves the disk
-            # responsive: reset the consecutive-timeout streak.
-            h.record_ok()
-            if self._metrics is not None:
-                self._metrics.inc("disk_op_errors_total", op=op, disk=ep)
-                self._metrics.inc("disk_ops_total", op=op, disk=ep)
-                self._metrics.observe(
-                    "disk_op_seconds", time.perf_counter() - t0, op=op
-                )
+            self._note_done(op, ep, t0, failed=True)
             raise
-        h.record_ok()
-        if self._metrics is not None:
-            self._metrics.inc("disk_ops_total", op=op, disk=ep)
-            self._metrics.observe(
-                "disk_op_seconds", time.perf_counter() - t0, op=op
-            )
-        _pace_note(time.perf_counter() - t0)
+        self._note_done(op, ep, t0, failed=False)
         return out
+
+    def _run_on_worker(self, mark, op: str, ep: str, fn, args, kwargs,
+                       deadline_s: float, t0: float):
+        """The guarded op of a QuorumFanout worker, token held: run it
+        here. The fan-out waits no longer than this op's deadline and
+        then detaches this thread, so a second thread that waits the
+        same time guards nothing more. What the hop's timeout arm did
+        follows the detach instead (_Watch), and the token still goes
+        back only when the call returns."""
+        if self._metrics is not None:
+            self._metrics.inc("disk_guard_inline_total", op=op)
+        watch = _Watch(self, op, deadline_s)
+        mark.watch(watch.detached)
+        failed = True
+        try:
+            out = fn(*args, **kwargs)
+            failed = False
+            return out
+        finally:
+            mark.unwatch()
+            self._health.release()
+            if watch.ended():
+                self._note_done(op, ep, t0, failed)
+
+    def _note_done(self, op: str, ep: str, t0: float, failed: bool) -> None:
+        """An op that returned inside its deadline. Even a FAST failure
+        (missing file, bad volume) proves the disk responsive: reset
+        the consecutive-timeout streak."""
+        self._health.record_ok()
+        elapsed = time.perf_counter() - t0
+        if self._metrics is not None:
+            if failed:
+                self._metrics.inc("disk_op_errors_total", op=op, disk=ep)
+            self._metrics.inc("disk_ops_total", op=op, disk=ep)
+            self._metrics.observe("disk_op_seconds", elapsed, op=op)
+        if not failed:
+            _pace_note(elapsed)
+
+    def _note_timeout(self, op: str, deadline_s: float) -> None:
+        """An op given up at its deadline: feed the breaker, and start
+        the probe when this miss latches it."""
+        latched = self._health.record_timeout()
+        if self._metrics is not None:
+            ep = self._disk.endpoint()
+            self._metrics.inc("disk_op_timeouts_total", op=op, disk=ep)
+            self._metrics.inc("disk_op_errors_total", op=op, disk=ep)
+            self._metrics.inc("disk_ops_total", op=op, disk=ep)
+            if latched:
+                self._metrics.inc("disk_faulty_total", disk=ep)
+        if latched:
+            self._start_probe()
+        # An abandoned op cost its caller the FULL deadline — that
+        # is the latency the pacer's pressure window must see.
+        _pace_note(deadline_s)
 
     # --- re-admission probe (ref the monitor's reconnect loop, scoped
     # --- to the breaker: latched -> probed -> re-admitted) ---

@@ -81,6 +81,49 @@ def heal_slot():
     return pacer().heal_slot()
 
 
+class Waited:
+    """What a QuorumFanout worker's thread carries while attempt(i)
+    runs: "my caller waits `deadline_s` for me and then detaches me".
+    Code under attempt(i) that would otherwise put a thread of its own
+    between itself and a hang (the drive guard, storage/diskcheck.py)
+    reads it with waited() and runs on this thread instead; what it
+    must learn of the detach it registers with watch()."""
+
+    __slots__ = ("deadline_s", "_detached", "_on_detach")
+
+    def __init__(self, deadline_s: float):
+        self.deadline_s = deadline_s
+        self._detached = False
+        self._on_detach = None
+
+    def watch(self, on_detach) -> None:
+        """Worker side: call on_detach() when the fan-out walks away —
+        now, if it already has (an attempt goes on after its detach).
+        on_detach may be called twice in that race; it must not mind."""
+        self._on_detach = on_detach
+        if self._detached:
+            on_detach()
+
+    def unwatch(self) -> None:
+        self._on_detach = None
+
+    def detach(self) -> None:
+        """Fan-out side, once, after its wait gave this worker up."""
+        self._detached = True
+        on_detach = self._on_detach
+        if on_detach is not None:
+            on_detach()
+
+
+_waited = threading.local()
+
+
+def waited() -> Waited | None:
+    """The calling thread's mark: set for the length of one attempt(i)
+    of a QuorumFanout, None on every other thread."""
+    return getattr(_waited, "mark", None)
+
+
 class StragglerCompensator:
     """Keeps a fan-out ThreadPoolExecutor's HEALTHY capacity constant
     while detached stragglers occupy workers, possibly forever (a write
@@ -199,6 +242,7 @@ class QuorumFanout:
 
         cv = self.cv
         detached = self.detached
+        marks: dict[int, Waited] = {}  # written and read under cv
 
         def run(i):
             with cv:
@@ -211,11 +255,16 @@ class QuorumFanout:
                     self._release(i)
                     cv.notify_all()
                     return
+                mark = marks[i] = Waited(deadline_s)
             err = None
+            _waited.mark = mark
             try:
                 attempt(i)
             except Exception as exc:  # noqa: BLE001 - collected for quorum
                 err = exc
+            finally:
+                # Pool threads serve callers that do not wait this way.
+                _waited.mark = None
             with cv:
                 if i in detached:
                     # Straggler finished after detach: result discarded
@@ -239,6 +288,7 @@ class QuorumFanout:
             self.pool.submit(bound_run, i)
 
         quorum_wait(cv, pending, count_ok, quorum, deadline_s, grace_s)
+        given_up = []
         with cv:
             if pending and on_stragglers is not None:
                 on_stragglers(len(pending))
@@ -248,6 +298,12 @@ class QuorumFanout:
                 self.comp.parked()
                 on_detach(i)
                 pending.discard(i)
+                if i in marks:
+                    given_up.append(marks[i])
                 # Zero-duration event mark: the detach decision itself
                 # is a fact worth seeing on a slow request's timeline.
                 _spans.record("fanout", f"straggler-detach #{i}", 0)
+        # Whatever a started straggler is inside learns, outside cv,
+        # that nobody waits for it any more.
+        for mark in given_up:
+            mark.detach()

@@ -218,6 +218,10 @@ class StreamingBitrotReader:
                  algo: BitrotAlgorithm = BitrotAlgorithm.HIGHWAYHASH256S):
         self._open = open_stream
         self._algo = algo
+        # chunk bytes that passed their digests; the stream that owns the
+        # reader publishes the sum when it ends
+        # (bitrot_verified_bytes_total), so a batch costs no lock here
+        self.verified_bytes = 0
         self._shard_size = shard_size
         # Physical end offset incl. hash framing (cmd/bitrot-streaming.go:178)
         self._till = ceil_frac(till_offset, shard_size) * algo.digest_size + till_offset
@@ -405,6 +409,7 @@ class StreamingBitrotReader:
                 f"content hash mismatch: want {hash_want.hex()}, got {h.digest().hex()}"
             )
         self._curr += length
+        self.verified_bytes += length
         return buf
 
     def read_chunks(self, offset: int, lengths: list[int]) -> list:
@@ -502,7 +507,10 @@ class StreamingBitrotReader:
                         raise ErrFileCorrupt("streaming bitrot mismatch")
                     out.append(chunk)
                     off += ds + ln
-            self._curr += sum(lengths)
+            n = sum(lengths)
+            self._curr += n
+            # every branch above has verified or raised by here
+            self.verified_bytes += n
             return out
         finally:
             self._exit_read()

@@ -166,7 +166,7 @@ class Erasure:
             shards = self._subshard_view(shards)
         engine = registry.select_engine(shards.shape[-1],
                                         codec_id=self.codec_id)
-        registry.note_dispatch(self.codec_id, engine)
+        registry.note_dispatch(self.codec_id, engine, "apply")
         if engine == "native":
             if shards.ndim == 3:
                 out = gf_native.apply_matrix_batch(mat_gf, shards)
@@ -300,7 +300,7 @@ class Erasure:
             blocks = np.ascontiguousarray(blocks, dtype=np.uint8)
         engine = registry.select_engine(blocks.shape[-1],
                                         self.total_shards, self.codec_id)
-        registry.note_dispatch(self.codec_id, engine)
+        registry.note_dispatch(self.codec_id, engine, "encode")
         if staged_on_device and engine not in ("device", "mesh"):
             blocks = np.asarray(blocks)  # tiny-shard fallback: host engines
         if engine == "native":

@@ -326,7 +326,7 @@ class DeviceCodec:
         fn = self._get_fn(key, make)
         bitmat = self._dev_mat(key[:3], self._recon_bits(present, targets))
         dev = self._stage(src)
-        registry.note_dispatch(self.codec_id, "device")
+        registry.note_dispatch(self.codec_id, "device", "reconstruct")
         _stat("dispatches")
         _stat("donated_batches")
         with _spans.span("device-call", "rec"):

@@ -74,6 +74,22 @@ CODEC_DESCRIPTORS: list[tuple[str, str, str]] = [
      "Codec selections at write time, labeled codec + geometry (k+m)"),
     ("mtpu_codec_dispatch_total", "counter",
      "Erasure batch dispatches, labeled codec + engine substrate"),
+    ("codec_dispatch_kind_total", "counter",
+     "The same dispatches by what they do, labeled engine + kind: "
+     "encode (a PUT's fused batch), reconstruct (a fused rebuild of a "
+     "heal or a batched degraded GET), apply (the unfused per-block "
+     "call of codec._apply: a degraded GET's block, a tail block)"),
+    ("bitrot_verified_bytes_total", "counter",
+     "Shard bytes read from a drive and verified against their frame "
+     "digests, labeled path (get/heal); counted by the bitrot readers "
+     "after the verify passed and published when the read stream ends, "
+     "so a read that went out unverified shows as a shortfall"),
+    ("get_reconstructed_blocks_total", "counter",
+     "Erasure blocks of GETs that had a data shard missing and were "
+     "rebuilt from parity before they were written to the client"),
+    ("get_mrf_queued_total", "counter",
+     "GETs whose read saw a missing or corrupt shard and queued an "
+     "MRF heal of their object"),
     ("codec_trace_total", "counter",
      "Traces of a fused device function (jax.jit building a new one), "
      "labeled codec + engine; the mesh engine counts its own under "
@@ -85,6 +101,9 @@ CODEC_DESCRIPTORS: list[tuple[str, str, str]] = [
      "platform + device_kind + devices"),
 ]
 
+# what a dispatch does: the `kind` label of codec_dispatch_kind_total
+DISPATCH_KINDS = ("encode", "reconstruct", "apply")
+
 _metrics = None  # guarded-by: _metrics_mu
 _metrics_mu = threading.Lock()
 
@@ -93,6 +112,19 @@ def set_metrics(registry) -> None:
     global _metrics
     with _metrics_mu:
         _metrics = registry
+    if registry is not None:
+        # present at 0 from the start: a scrape that finds the series
+        # reads "none rebuilt", one that finds none reads nothing
+        for engine in _FORCED_ENGINES:
+            if engine == "auto":
+                continue
+            for kind in DISPATCH_KINDS:
+                registry.inc("codec_dispatch_kind_total", 0,
+                             engine=engine, kind=kind)
+        for path in ("get", "heal"):
+            registry.inc("bitrot_verified_bytes_total", 0, path=path)
+        registry.inc("get_reconstructed_blocks_total", 0)
+        registry.inc("get_mrf_queued_total", 0)
 
 
 def _reg():
@@ -594,10 +626,22 @@ def note_trace(codec_id: str, engine: str) -> None:
         reg.inc("codec_trace_total", codec=codec_id, engine=engine)
 
 
-def note_dispatch(codec_id: str, engine: str) -> None:
+def note_dispatch(codec_id: str, engine: str, kind: str) -> None:
     """Per-batch dispatch accounting (codec x engine substrate) — wired
-    from the codec core's engine dispatch points."""
+    from the codec core's engine dispatch points. `kind` (one of
+    DISPATCH_KINDS) says what the dispatch does, on a series of its own,
+    so a GET's per-block calls read apart from a PUT's fused batches."""
     reg = _reg()
     if reg is not None:
         reg.inc("mtpu_codec_dispatch_total", codec=codec_id,
                 engine=engine)
+        reg.inc("codec_dispatch_kind_total", engine=engine, kind=kind)
+
+
+def note_read(name: str, n: float = 1, **labels) -> None:
+    """Read-side accounting (bitrot_verified_bytes_total,
+    get_reconstructed_blocks_total, get_mrf_queued_total): raised once
+    a stream by decode_stream / heal_stream and by the object layer."""
+    reg = _reg()
+    if reg is not None:
+        reg.inc(name, n, **labels)

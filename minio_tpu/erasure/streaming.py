@@ -900,6 +900,9 @@ class ParallelReader:
         self.reader_to_buf = list(range(len(readers)))
         self.saw_missing = False
         self.saw_corrupt = False
+        # blocks handed out with a data shard missing: the caller has to
+        # rebuild each before it can join the data
+        self.degraded_blocks = 0
         self._queue: list = []  # prefetched per-block buf lists
         self._blocks_wanted = None  # caller hint: don't prefetch past it
 
@@ -1129,6 +1132,8 @@ class ParallelReader:
             )
             raise err if err else ErrErasureReadQuorum()
 
+        if any(i not in results for i in range(self.data_blocks)):
+            self.degraded_blocks += len(lengths)
         for t in range(len(lengths)):
             new_buf: list = [None] * len(self.org_readers)
             for buf_idx, chunks in results.items():
@@ -1137,6 +1142,21 @@ class ParallelReader:
         self.offset += sum(lengths)
         if self._blocks_wanted is not None:
             self._blocks_wanted -= len(lengths)
+
+
+def _release_readers(readers: list, path: str) -> None:
+    """The end of a read stream. Pooled shm ring slots go back to their
+    pool (parked fan-out threads defer their own slot's release), and
+    what the bitrot readers verified is published, one increment a
+    stream: bitrot_verified_bytes_total{path}."""
+    verified = 0
+    for r in readers:
+        if hasattr(r, "release_buffers"):
+            r.release_buffers()
+        verified += getattr(r, "verified_bytes", 0)
+    if verified:
+        registry.note_read("bitrot_verified_bytes_total", verified,
+                           path=path)
 
 
 def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
@@ -1300,12 +1320,11 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
                         block_length
                     )
         finally:
-            # Pooled shm ring slots go back to their pool when the stream
-            # ends (parked fan-out threads defer their own slot's release).
-            for r in readers:
-                if hasattr(r, "release_buffers"):
-                    r.release_buffers()
+            _release_readers(readers, "get")
 
+    if reader.degraded_blocks:
+        registry.note_read("get_reconstructed_blocks_total",
+                           reader.degraded_blocks)
     if bytes_written != length:
         raise ErrLessData(f"wrote {bytes_written}, want {length}")
     return bytes_written, heal_hint
@@ -1702,9 +1721,7 @@ def heal_stream(erasure: Erasure, writers: list, readers: list,
             for shards in pipe.results(range(total_blocks)):
                 write_targets(shards)
         finally:
-            for r in readers:
-                if hasattr(r, "release_buffers"):
-                    r.release_buffers()
+            _release_readers(readers, "heal")
 
 
 # Blocks per fused heal-reconstruction dispatch; matches the read-side

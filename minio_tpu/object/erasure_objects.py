@@ -809,6 +809,17 @@ class ErasureObjects(MultipartMixin):
     def _get_object(self, bucket: str, object_: str, writer,
                     offset: int, length: int,
                     opts: ObjectOptions) -> ObjectInfo:
+        # The object layer's span, as a PUT and a heal have it: the read
+        # slot's wait (`admission`), the read tier's answer (`readtier`)
+        # and `stream` are its children, the rest (the metadata quorum,
+        # opening the shard readers) its self time.
+        with _spans.span("object", "get"):
+            return self._get_object_inner(bucket, object_, writer, offset,
+                                          length, opts)
+
+    def _get_object_inner(self, bucket: str, object_: str, writer,
+                          offset: int, length: int,
+                          opts: ObjectOptions) -> ObjectInfo:
         fi, fis, errs = self._read_quorum_file_info(
             bucket, object_, opts.version_id, read_data=True
         )
@@ -866,6 +877,7 @@ class ErasureObjects(MultipartMixin):
         if heal_hint is not None:
             # On-read heal trigger (ref cmd/erasure-object.go:319-338).
             self.queue_mrf(bucket, object_, fi.version_id)
+            _codec_registry.note_read("get_mrf_queued_total")
         return ObjectInfo.from_file_info(fi, bucket, object_, opts.versioned)
 
     def _decode_range(self, bucket: str, object_: str, fi, fis, erasure,

@@ -57,6 +57,7 @@ import time
 from collections import OrderedDict
 
 from ..observability import ioflow as _ioflow
+from ..observability import spans as _spans
 from ..pipeline.buffers import copy_add
 from ..utils.errors import ErrOperationTimedOut
 from ..utils.fanout import decode_slot as _decode_slot
@@ -373,14 +374,22 @@ class ReadTier:
         if not plan:
             return None
         role, fl, datas = self._decide(plan)
+        # Where the tier itself answers (no decode of this request's
+        # own), that is a leaf on the request's span tree; a leader's
+        # time is its `stream`.
         if role == "hit":
-            self._slice(plan, datas, writer, offset, length, "hit")
+            with _spans.span("readtier", "hit", mirror=True):
+                self._slice(plan, datas, writer, offset, length, "hit")
             return ("hit", None)
         if role == "leader":
             hint = self._lead(objects, bucket, object_, fi, fis, erasure,
                               plan, fl, writer, offset, length)
             return ("leader", hint)
-        return self._follow(plan, fl, writer, offset, length)
+        with _spans.span("readtier", "coalesced", mirror=True) as sp:
+            served = self._follow(plan, fl, writer, offset, length)
+            if served is None:
+                sp.relabel("fallback")
+            return served
 
     def _decide(self, plan: list[_BlockRef]):
         """One atomic admission decision: full cache hit, follower

@@ -66,8 +66,8 @@ from collections import deque
 SPAN_DESCRIPTORS: list[tuple[str, str, str]] = [
     ("span_seconds", "histogram",
      "Request-span latency by span kind (request/body-read/admission/"
-     "object/commit/stream/stage/device-h2d/device-call/device-wait/"
-     "worker/fanout/disk) and by op, the root's API name"),
+     "object/commit/readtier/stream/stage/device-h2d/device-call/"
+     "device-wait/worker/fanout/disk) and by op, the root's API name"),
     ("trace_slow_captures_total", "counter",
      "Slow-request span trees captured into the exemplar store"),
 ]

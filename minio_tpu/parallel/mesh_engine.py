@@ -333,7 +333,7 @@ class MeshCodec:
         bitmat = self._dev_mat(("rec", present, targets),
                                self._recon_bits(present, targets))
         b_padded = dev.shape[0]
-        registry.note_dispatch(self.codec_id, "mesh")
+        registry.note_dispatch(self.codec_id, "mesh", "reconstruct")
         self._record_batch(
             blocks=n_rows,
             collective=b_padded * len(targets) * s

@@ -156,13 +156,48 @@ def test_selection_and_dispatch_counters():
     registry.set_metrics(stub)
     try:
         registry.select_codec(4, 2, forced=registry.CAUCHY_XOR)
-        registry.note_dispatch(registry.CAUCHY_XOR, "native")
+        registry.note_dispatch(registry.CAUCHY_XOR, "native", "apply")
     finally:
         registry.set_metrics(None)
     assert ("mtpu_codec_selected_total",
             {"codec": registry.CAUCHY_XOR, "geometry": "4+2"}) in stub.incs
     assert ("mtpu_codec_dispatch_total",
             {"codec": registry.CAUCHY_XOR, "engine": "native"}) in stub.incs
+
+
+def test_read_side_series_stand_at_zero_from_set_metrics():
+    """A scrape that finds a series reads "none", one that finds no
+    series reads nothing: the four series of the read side are there
+    from the moment the registry is set, and a dispatch raises the old
+    series and its kind's, each by one."""
+    from minio_tpu.observability.metrics import Metrics
+
+    m = Metrics()
+    registry.set_metrics(m)
+    try:
+        page = m.render_prometheus()
+        for line in (
+                'mtpu_codec_dispatch_kind_total{engine="device",kind="apply"} 0',
+                'mtpu_codec_dispatch_kind_total{engine="mesh",kind="reconstruct"} 0',
+                'mtpu_codec_dispatch_kind_total{engine="native",kind="encode"} 0',
+                'mtpu_bitrot_verified_bytes_total{path="get"} 0',
+                'mtpu_bitrot_verified_bytes_total{path="heal"} 0',
+                "mtpu_get_reconstructed_blocks_total 0",
+                "mtpu_get_mrf_queued_total 0"):
+            assert any(ln.startswith(line) for ln in page.splitlines()), line
+        assert "codec_dispatch_total{" not in page
+        for kind in registry.DISPATCH_KINDS:
+            registry.note_dispatch(registry.DENSE_GF8, "device", kind)
+        registry.note_read("bitrot_verified_bytes_total", 131072, path="get")
+    finally:
+        registry.set_metrics(None)
+    assert m.counter_value("mtpu_codec_dispatch_total",
+                           codec=registry.DENSE_GF8, engine="device") == 3
+    for kind in registry.DISPATCH_KINDS:
+        assert m.counter_value("codec_dispatch_kind_total", engine="device",
+                               kind=kind) == 1
+    assert m.counter_value("bitrot_verified_bytes_total", path="get") == 131072
+    assert m.counter_value("bitrot_verified_bytes_total", path="heal") == 0
 
 
 def test_codec_descriptors_in_catalog():

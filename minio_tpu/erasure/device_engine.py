@@ -25,14 +25,17 @@ This module is structured so each [B, k, S] batch costs:
   transfer of batch N overlaps the compute of batch N+1 and the
   shard-write fan-out of batch N-1 (the 3-deep ring the streaming
   drivers run on pipeline/executor.Pipeline).
-- Geometry-keyed caches: codecs, compiled functions, device-resident
-  bit-matrices and reconstruction matrices are all cached by
-  (k, m[, survivors, targets]) so steady-state PUT/heal never
-  re-derives a matrix or recompiles.
+- Geometry-keyed caches: codecs and compiled functions are cached by
+  (k, m) and by what the function reads; device-resident bit-matrices
+  and reconstruction matrices by (k, m[, survivors, targets]). A
+  failure pattern is a matrix, an argument of the one compiled
+  reconstruct function, so steady-state PUT/GET/heal never re-derives
+  a matrix, and a new pattern of a known batch shape never re-traces.
 
-The same fused/overlapped treatment covers heal: ``reconstruct_async``
-rebuilds target shards AND their bitrot digests in one dispatch per
-batch of blocks (consumed by erasure/streaming._heal_stream_fused).
+The same fused/overlapped treatment covers the read side:
+``reconstruct_async`` rebuilds target shards (for heal, AND their
+bitrot digests) in one dispatch per batch of blocks (consumed by
+erasure/streaming._heal_stream_fused and _decode_stream_fused).
 
 Everything here runs identically on CPU (JAX_PLATFORMS=cpu), which is
 how tier-1 exercises the fused path bit-exactly against the host
@@ -301,12 +304,14 @@ class DeviceCodec:
         """One fused dispatch rebuilding `targets` shards from the first
         k `present` shards: src [B, k, S] (rows ordered as present[:k])
         -> (rebuilt [B, T, S], digests [B, T, 32] | None), D2H in
-        flight, input donated. The compiled function and the
-        reconstruction matrix are cached per (present, targets) failure
-        pattern, so an N-block heal compiles once."""
+        flight, input donated. The compiled function is keyed by what
+        `impl` reads, `with_hashes`; the batch's shape and the matrix's
+        rows (the target count) are jax.jit's own key. The failure
+        pattern is an argument, the device-resident matrix cached per
+        (present, targets): a pattern never seen before costs one small
+        device_put and no trace."""
         present = tuple(present[: self.k])
         targets = tuple(targets)
-        key = ("rec", present, targets, with_hashes)
 
         def make():
             from ..ops.highwayhash_jax import hash256_batch_jax
@@ -323,8 +328,9 @@ class DeviceCodec:
 
         from . import registry
 
-        fn = self._get_fn(key, make)
-        bitmat = self._dev_mat(key[:3], self._recon_bits(present, targets))
+        fn = self._get_fn(("rec", with_hashes), make)
+        bitmat = self._dev_mat(("rec", present, targets),
+                               self._recon_bits(present, targets))
         dev = self._stage(src)
         registry.note_dispatch(self.codec_id, "device", "reconstruct")
         _stat("dispatches")

@@ -1171,11 +1171,14 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
     caller queues a heal, like cmd/erasure-object.go:324-338.
     (ref Erasure.Decode, cmd/erasure-decode.go:205-283)
 
-    Past two blocks the block loop runs on the staged pipeline
-    (pipeline/executor.py): shard-read+bitrot-verify of block N+1 and
-    decode of block N overlap the client write of block N-1, with
-    bounded queues so a slow client applies backpressure instead of
-    buffering the object in memory.
+    Past two blocks the loop runs on the staged pipeline
+    (pipeline/executor.py): the shard read and bitrot verify of what
+    comes next and the rebuild of what was read overlap the client
+    write, with bounded queues so a slow client applies backpressure
+    instead of buffering the object in memory. The device and the mesh
+    engine move whole reader batches through it (`fused`: one rebuild
+    dispatch a batch), the host engines blocks (`pipelined`) or, with
+    the worker pool armed, batches on one thread (`workers`).
     """
     if offset < 0 or length < 0 or offset + length > total_length:
         raise ErrInvalidArgument("bad range")
@@ -1225,16 +1228,10 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
     # <=2 blocks: read-ahead can overlap at most one handoff — not
     # worth the per-request thread spin-up (the small-object/range-GET
     # fast path stays identical to the serial driver).
-    # The mesh engine owns the whole GET stream, not just degraded
-    # blocks: shard loss is only discovered at read time (a destroyed
-    # part file still yields a non-None reader that fails on its first
-    # fetch), so there is no up-front healthy/degraded split to route
-    # on. Healthy blocks still get batched parallel shard IO from
-    # ParallelReader's BATCH_BLOCKS prefetch; what the mesh driver
-    # forgoes vs the Pipeline branch is only decode/client-write
-    # overlap, and on a mesh deployment degraded reconstruction — the
-    # thing the collective dispatch accelerates — is what GET latency
-    # economics turn on.
+    # Shard loss is only discovered at read time (a destroyed part file
+    # still yields a non-None reader that fails on its first fetch), so
+    # there is no up-front healthy/degraded split to route on: every
+    # driver takes healthy and degraded blocks as they come.
     engine = registry.select_engine(erasure.shard_size(),
                                     erasure.total_shards,
                                     codec_id=erasure.codec_id)
@@ -1245,18 +1242,23 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
         wpool = _workers.armed()
     with _spans.span("stream") as sp:
         try:
-            if engine == "mesh":
-                # Mesh serving path: degraded blocks reconstruct in fused
-                # collective dispatches batched per failure pattern; healthy
-                # blocks stream straight through on the host — written before
-                # the next fetch, so the recycled readinto ring is safe here
-                # too (batched degraded rows are copied out at append time).
-                for r in readers:
-                    if hasattr(r, "reuse_buffers"):
-                        r.reuse_buffers()
-                sp.relabel("mesh")
-                bytes_written = _decode_stream_mesh(
-                    erasure, writer, reader, geoms, note_heal
+            if engine in ("device", "mesh") and len(geoms) > 2:
+                # Same fused driver for both accelerator engines, as in
+                # heal_stream; only the codec differs (one chip vs the
+                # mesh). It keeps reader batches in flight, so the
+                # readers' recycled rings stay off, as for `pipelined`.
+                if engine == "mesh":
+                    from ..parallel.mesh_engine import for_geometry
+                else:
+                    from .device_engine import for_geometry
+
+                codec = for_geometry(erasure.data_blocks,
+                                     erasure.parity_blocks,
+                                     erasure.codec_id)
+                sp.relabel("fused")
+                bytes_written = _decode_stream_fused(
+                    erasure, writer, reader, geoms, note_heal, codec,
+                    telemetry
                 )
             elif (wpool is not None and len(geoms) > 2
                   and _worker_read_profitable(erasure, readers)):
@@ -1330,77 +1332,47 @@ def decode_stream(erasure: Erasure, writer, readers: list, offset: int,
     return bytes_written, heal_hint
 
 
-def _decode_stream_mesh(erasure: Erasure, writer, reader, geoms: list,
-                        note_heal) -> int:
-    """Mesh decode driver for the GET path: consecutive degraded blocks
-    sharing one failure pattern batch into a single fused mesh
-    reconstruct dispatch (parallel/mesh_engine.reconstruct_async — the
-    all-gather + matmul plane of ShardedErasure, serving disk-sourced
-    shards). The dispatch of batch N overlaps the client writes of
-    batch N-1; healthy blocks and ragged tail blocks take the host path
-    after draining the ring, so client writes stay strictly in stream
-    order."""
-    from ..parallel.mesh_engine import for_geometry as mesh_geometry
+# A block of a reader batch that the fused decode driver rebuilds on the
+# host: the ragged tail, whose short shards fit no batch.
+_HOST_BLOCK = ("host",)
+
+
+def _decode_stream_fused(erasure: Erasure, writer, reader, geoms: list,
+                         note_heal, codec, telemetry: str) -> int:
+    """Fused decode driver for the GET path of the device and the mesh
+    engine: the unit of a rebuild is the reader's batch, not the block.
+    `codec` is device_engine.DeviceCodec or mesh_engine.MeshCodec; both
+    speak reconstruct_async.
+
+    Three threads, as `pipelined` has them: a `shard-read` stage hands
+    on whole reader batches (ParallelReader.BATCH_BLOCKS blocks a
+    fan-out), a `rebuild` stage gathers the survivors of consecutive
+    degraded blocks that share a failure pattern into one [B, k, S]
+    array (the one host copy, counted) and dispatches it, and the
+    caller's thread waits for the rebuilt rows and writes to the
+    client. So the fetch of batch N+1 and the rebuild of batch N
+    overlap the client write of batch N-1, for healthy streams too.
+    Healthy blocks pass through untouched and open no device span; the
+    ragged tail block is rebuilt on the host; a pattern that changes
+    inside a batch closes the run. Every block is written from the
+    reader's own buffers, the lost rows from the device's output, in
+    stream order."""
+    from ..pipeline import Pipeline, Stage
     from ..pipeline.buffers import copy_add
     from ..utils.errors import ErrShardSize, ErrTooFewShards
 
-    codec = mesh_geometry(erasure.data_blocks, erasure.parity_blocks,
-                          erasure.codec_id)
     k = erasure.data_blocks
     shard = erasure.shard_size()
-    bytes_written = 0
 
-    pending = None  # (bufs_list, geom_list, targets, rebuilt_future)
-
-    def flush(p) -> None:
-        nonlocal bytes_written
-        bufs_list, geom_list, targets, fut = p
-        rebuilt = _to_host(fut)  # D2H started at dispatch
-        for bi, (bufs, (off, ln)) in enumerate(zip(bufs_list, geom_list)):
-            for t_i, t in enumerate(targets):
-                bufs[t] = rebuilt[bi, t_i]
-            bytes_written += _write_data_blocks(writer, bufs, k, off, ln)
-
-    batch_bufs: list = []
-    batch_geoms: list = []
-    batch_key: tuple = ()
-
-    def dispatch_batch() -> None:
-        nonlocal pending, batch_bufs, batch_geoms
-        if not batch_bufs:
-            return
-        present, targets = batch_key
-        src = np.stack([
-            np.stack([np.frombuffer(memoryview(bufs[i]), dtype=np.uint8)
-                      for i in present])
-            for bufs in batch_bufs
-        ])
-        fut, _ = codec.reconstruct_async(src, present, targets,
-                                         with_hashes=False)
-        done, batch_bufs, batch_geoms = (batch_bufs, batch_geoms), [], []
-        if pending is not None:
-            flush(pending)  # overlap: batch N computes while N-1 writes
-        pending = (done[0], done[1], targets, fut)
-
-    def drain() -> None:
-        nonlocal pending
-        dispatch_batch()
-        if pending is not None:
-            flush(pending)
-            pending = None
-
-    for off, ln in geoms:
-        bufs = reader.read()
-        note_heal()
+    def pattern(bufs):
+        """None for a healthy block, _HOST_BLOCK for the ragged tail,
+        else (the k survivors to read, the data shards to rebuild)."""
         present = tuple(
             i for i, b in enumerate(bufs) if b is not None and len(b)
         )
-        missing_data = tuple(i for i in range(k) if i not in set(present))
+        missing_data = tuple(i for i in range(k) if i not in present)
         if not missing_data:
-            # Healthy block: no reconstruction, plain ordered write.
-            drain()
-            bytes_written += _write_data_blocks(writer, bufs, k, off, ln)
-            continue
+            return None
         if len(present) < k:
             raise ErrTooFewShards(
                 f"{len(present)} shards present, need {k}"
@@ -1409,35 +1381,59 @@ def _decode_stream_mesh(erasure: Erasure, writer, reader, geoms: list,
         for i in present:
             if len(bufs[i]) != blen:
                 raise ErrShardSize("present shards differ in size")
-        if blen != shard:
-            # Ragged tail block: host reconstruction, in order.
-            drain()
-            erasure.decode_data_blocks(bufs)
+        return (present[:k], missing_data) if blen == shard else _HOST_BLOCK
+
+    def fetch(chunk):
+        return chunk, [reader.read() for _ in chunk]
+
+    def rebuild(item):
+        chunk, blocks = item
+        keys = [pattern(bufs) for bufs in blocks]
+        runs = []  # (first block, one past the last, targets, future)
+        i = 0
+        while i < len(blocks):
+            key, j = keys[i], i + 1
+            if key is _HOST_BLOCK:
+                erasure.decode_data_blocks(blocks[i])
+            elif key is not None:
+                while j < len(blocks) and keys[j] == key:
+                    j += 1
+                present, targets = key
+                src = np.empty((j - i, k, shard), dtype=np.uint8)
+                for row, bufs in enumerate(blocks[i:j]):
+                    for r_i, si in enumerate(present):
+                        src[row, r_i] = np.frombuffer(
+                            memoryview(bufs[si]), dtype=np.uint8
+                        )
+                copy_add("get.fused_gather", src.nbytes)
+                fut, _ = codec.reconstruct_async(src, present, targets,
+                                                 with_hashes=False)
+                runs.append((i, j, targets, fut))
+            i = j
+        return chunk, blocks, runs
+
+    # An item is a reader batch, so a queue holds one: a batch read
+    # ahead and one rebuilt ahead of the client write are the overlap
+    # there is to have, and a stream never holds more than five.
+    pipe = Pipeline(telemetry, [
+        Stage("shard-read", fetch),
+        Stage("rebuild", rebuild,
+              bytes_of=lambda out: sum(ln for _, ln in out[0])),
+    ], queue_depth=1)
+    nb = ParallelReader.BATCH_BLOCKS
+    bytes_written = 0
+    # The client write stays on the CALLER's thread — response framing
+    # and socket state must not move across threads.
+    for chunk, blocks, runs in pipe.results(
+            geoms[at:at + nb] for at in range(0, len(geoms), nb)):
+        note_heal()
+        for i, j, targets, fut in runs:
+            rebuilt = _to_host(fut)  # D2H started at dispatch
+            for bi, bufs in enumerate(blocks[i:j]):
+                for t_i, t in enumerate(targets):
+                    bufs[t] = rebuilt[bi, t_i]
+        for (off, ln), bufs in zip(chunk, blocks):
             bytes_written += _write_data_blocks(writer, bufs, k, off, ln)
-            continue
-        key = (present[:k], missing_data)
-        if batch_bufs and key != batch_key:
-            dispatch_batch()  # failure pattern changed mid-stream
-        batch_key = key
-        # Copy out of the reader's recycled ring at append time: this
-        # batch (and the overlapped pending one) outlives further
-        # fetches, which reuse the ring's buffers. Healthy/tail blocks
-        # need no copy — they are written before the next fetch. Only
-        # present[:k] is ever read again (reconstruct sources, and the
-        # client write's data rows all sort within it); surviving
-        # parity beyond that would be copied for nothing.
-        held: list = [None] * len(bufs)
-        for i in present[:k]:
-            # copy-ok: get.mesh_hold
-            held[i] = np.frombuffer(
-                memoryview(bufs[i]), dtype=np.uint8
-            ).copy()
-            copy_add("get.mesh_hold", len(held[i]))
-        batch_bufs.append(held)
-        batch_geoms.append((off, ln))
-        if len(batch_bufs) >= ParallelReader.BATCH_BLOCKS:
-            dispatch_batch()
-    drain()
     return bytes_written
 
 
@@ -1566,7 +1562,7 @@ def _decode_stream_workers(erasure: Erasure, writer, reader, geoms: list,
             # Gather the k survivor rows out of the reader's recycled
             # ring into the shm strip — the batch outlives further
             # fetches, which reuse the ring's buffers (the worker-plane
-            # dual of get.mesh_hold).
+            # dual of get.fused_gather).
             src = state["strip"].recon_src(ParallelReader.BATCH_BLOCKS)
             row = state["nb"]
             for r_i, si in enumerate(key[0]):

@@ -20,9 +20,11 @@ host (dp=1 x lane=4; PERF.md):
   return. The staged input batch is donated to XLA.
 - ``reconstruct_async(src, present, targets, with_hashes)`` — fused
   rebuild of `targets` shards from the first k `present` shards, one
-  compiled program per failure pattern (cached), shard bytes split
-  over 'lane' inside the program so reconstruction uses the whole mesh
-  even at dp=1, gathered back for the stale-disk writers.
+  compiled program per batch shape and target count (the failure
+  pattern is its matrix argument), shard bytes split over 'lane'
+  inside the program so reconstruction uses the whole mesh even at
+  dp=1, gathered back for the stale-disk writers and the GET's client
+  write (erasure/streaming._decode_stream_fused).
 
 Batch padding: the dp axis shards the batch dim, so a ragged last
 batch (B % dp != 0) is zero-padded on the host and the outputs lazily
@@ -290,7 +292,7 @@ class MeshCodec:
         targets = tuple(targets)
         dev, n_rows = self._stage(src)
         s = dev.shape[-1]
-        key = ("rec", present, targets, with_hashes, dev.shape)
+        key = ("rec", with_hashes, dev.shape)
 
         def make():
             import jax

@@ -153,6 +153,17 @@ def test_reconstruct_async_pattern_cache_no_retrace():
     stats = device_engine.stats_snapshot()
     assert stats["dispatches"] == 3
     assert stats["traces"] == 0
+    # a second pattern, never seen: another matrix into the same
+    # compiled function, rightly applied
+    full = np.concatenate(
+        [src, Erasure(k, m, k * s).encode_batch(src)], axis=1)
+    present, targets = (0, 1, 3, 5), (2,)
+    r, _ = codec.reconstruct_async(
+        np.ascontiguousarray(full[:, list(present)]), present, targets)
+    np.testing.assert_array_equal(np.asarray(r), full[:, [2]])
+    stats = device_engine.stats_snapshot()
+    assert stats["dispatches"] == 4
+    assert stats["traces"] == 0
 
 
 class _MemShard:

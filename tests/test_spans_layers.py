@@ -204,16 +204,18 @@ def test_heal_is_its_own_root_and_counts_traces_per_failure_pattern(
     n16, reg = plane["n16"], plane["reg"]
     for key in ("h1", "h2"):
         n16.put(key, 10 * MIB)
-    # what jax.jit keys a function and its trace on: the failure pattern
-    # (which survivors were read is part of it, and a hedged read may
-    # change them from one batch to the next) and the batch's shape
+    # what a trace is keyed on: the batch's shape, the matrix's rows (the
+    # target count) and `with_hashes`. The failure pattern (which shards
+    # are rebuilt from which survivors; a hedged read may change them from
+    # one batch to the next) is the matrix, an argument
     seen: set = set()
     fresh: list = []
+    patterns: set = set()
     recon = DeviceCodec.reconstruct_async
 
     def logged(self, src, present, targets, with_hashes=False):
-        key = (src.shape, tuple(present[: self.k]), tuple(targets),
-               with_hashes)
+        key = (src.shape, len(targets), with_hashes)
+        patterns.add((tuple(present[: self.k]), tuple(targets)))
         if key not in seen:
             seen.add(key)
             fresh.append(key)
@@ -242,20 +244,28 @@ def test_heal_is_its_own_root_and_counts_traces_per_failure_pattern(
     assert [s["label"] for s in kinds["stream"]] == ["heal_fused"]
     assert {s["label"] for s in kinds["device-call"]} == {"rec"}
     assert len(kinds["device-call"]) == _dispatches(reg) - d0 == 2
-    # a new failure pattern traces its 8-block and its 2-block batch
+    # the first heal traces its 8-block and its 2-block batch
     assert traced == new_keys == 2, (traced, fresh)
-    # the same object and drives again, another object after it: a trace
-    # for every pattern and shape not seen before, and for no other
-    for key in ("h1", "h2", "h2"):
-        n16.wipe(3, 7)
+    # the same object and drives again, another object after it, then
+    # other drives (other survivors, other targets): patterns never seen,
+    # in batch shapes that are known, and not one trace
+    # (a wipe takes both objects off its drives)
+    known = 0
+    for key, drives in (("h1", (3, 7)), ("h2", ()), ("h2", (3, 7)),
+                        ("h1", ()), ("h1", (0, 12)), ("h2", ())):
+        if drives:
+            n16.wipe(*drives)
+        if drives == (0, 12):
+            known = len(patterns)
         _, traced, new_keys = heal(key)
-        assert traced == new_keys, (key, traced, fresh)
+        assert traced == new_keys == 0, (key, drives, traced, fresh)
+    assert len(patterns) > known >= 2, patterns
     text = reg.render_prometheus()
     assert "mtpu_codec_trace_total{" in text
     assert "mtpu_mtpu_codec_trace_total" not in text
 
 
-def test_codec_trace_total_rises_on_a_new_pattern_only(plane):
+def test_codec_trace_total_rises_on_a_new_shape_only(plane):
     import numpy as np
 
     from minio_tpu.erasure import device_engine
@@ -270,9 +280,10 @@ def test_codec_trace_total_rises_on_a_new_pattern_only(plane):
         assert device_engine.to_host(out).shape == (1, len(targets), 4096)
         return _traces(reg) - t0
 
-    assert rebuild((0, 1), (2, 3)) == 1         # a pattern of its own
+    assert rebuild((0, 1), (2, 3)) == 1         # a batch shape of its own
     assert rebuild((0, 1), (2, 3)) == 0         # again: the cached function
-    assert rebuild((1, 2), (0, 3)) == 1         # each new pattern traces
+    assert rebuild((1, 2), (0, 3)) == 0         # a new pattern is a matrix
+    assert rebuild((0, 2), (1,)) == 1           # its rows are jit's key
     assert rebuild((1, 2), (0, 3)) == 0
     assert rebuild((0, 1), (2, 3)) == 0
 

@@ -152,7 +152,7 @@ def test_armed_degraded_get_copy_floor(armed, monkeypatch):
     """Zero payload over the pipe: the armed degraded-GET's only copy
     sites are the framed source read and the survivor gather into the
     shm strip (get.worker_hold — the worker-plane dual of
-    get.mesh_hold)."""
+    get.fused_gather)."""
     er = Erasure(4, 2, BLOCK)
     size = BLOCK * 20
     monkeypatch.setenv("MTPU_WORKER_POOL", "off")

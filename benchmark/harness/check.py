@@ -9,8 +9,10 @@ Every comparison is exact, so every limit is 0. Numbers compared:
 - `never_answered`: requests that got no answer within the wait after the
   close (a late answer is late, not wrong);
 - `readback_bytes_differ`: bytes of a seed-drawn sample of the window's
-  PUTs, read back over S3 (front end, object layer, quorum), that differ
-  from what was sent, a missing byte counting as one;
+  PUTs, read back over S3 (front end, object layer, quorum; in a
+  deployment of several nodes through another node than the one that
+  acknowledged the PUT), that differ from what was sent, a missing byte
+  counting as one;
 - `nothing_compared`: 1 where the window finished nothing that could be
   compared;
 - `layout_faults`: sampled objects whose xl.meta or shard files do not
@@ -182,7 +184,7 @@ def compare_shards(checks: Checks, root: str, cell, objects, pool_of,
                     checks.bump("digest_bytes_differ", bad)
 
 
-def check_window(cell, load: Load, win: Window, seed: int, host: str,
+def check_window(cell, load: Load, win: Window, seed: int, hosts: list[str],
                  root: str) -> Checks:
     checks = Checks()
     n_sample = int(cell.traffic.get("check_sample", 8))
@@ -211,9 +213,21 @@ def check_window(cell, load: Load, win: Window, seed: int, host: str,
     picked = _sample(puts, n_sample, seed, always=longest)
     checks.note(f"compared {len(picked)} of {len(puts)} PUTs of the window, "
                 f"the longest ({longest.latency * 1e3:.0f} ms) among them")
-    s3 = S3(host)
+    # "An acknowledged write is readable" is the cluster's promise: every
+    # sampled object is read back through the node after the one that
+    # acknowledged it (the same one where there is one)
+    conns = [S3(h) for h in hosts]
+
+    def via(o) -> int:
+        return (o.node + 1) % len(hosts)
+
+    if len(hosts) > 1:
+        checks.note("each read back through the node after the one that "
+                    "acknowledged it: " + ", ".join(
+                        f"{o.key} {o.node + 1}->{via(o) + 1}" for o in picked))
     for o in picked:
         want = load.pool(o.size)[o.body].data
+        s3 = conns[via(o)]
         try:
             st, _, data = s3.request("GET", f"/{BUCKET}/{o.key}")
         except NO_ANSWER as exc:
@@ -229,7 +243,8 @@ def check_window(cell, load: Load, win: Window, seed: int, host: str,
         if bad:
             checks.note(f"read back {o.key}: {bad} bytes differ")
             checks.bump("readback_bytes_differ", bad)
-    s3.close()
+    for s3 in conns:
+        s3.close()
     compare_shards(checks, root, cell,
                    [(o.key, o.size, o.body) for o in picked], load.pool)
     return checks

@@ -71,14 +71,15 @@ class S3:
         return data.decode()
 
 
-def wait_ready(host: str, proc, deadline_s: float) -> float:
-    """Seconds until /minio/health/live answered 200."""
+def wait_ready(host: str, gone, deadline_s: float) -> float:
+    """Seconds until /minio/health/live answered 200. `gone()` names a
+    server that has exited, or returns nothing."""
     t0 = time.monotonic()
     s3 = S3(host, timeout=5)
     while time.monotonic() - t0 < deadline_s:
-        if proc.poll() is not None:
-            raise OSError(f"the server exited with {proc.returncode} "
-                          "before it answered")
+        who = gone()
+        if who:
+            raise OSError(f"{who} before it answered")
         try:
             st, _, _ = s3.request("GET", "/minio/health/live")
             if st == 200:
@@ -111,6 +112,17 @@ def counters(text: str) -> dict[str, float]:
         except ValueError:
             continue
     return out
+
+
+def add_up(pages: list[dict[str, float]]) -> dict[str, float]:
+    """The nodes' samples added up, series by series: counters and a
+    histogram's `_sum` and `_count` add, so a rise over the cluster reads
+    as a rise on one node does."""
+    total: dict[str, float] = {}
+    for page in pages:
+        for name, val in page.items():
+            total[name] = total.get(name, 0.0) + val
+    return total
 
 
 def dispatch_count(samples: dict[str, float], engine: str) -> float:

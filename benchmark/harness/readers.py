@@ -231,9 +231,11 @@ def roofline_share_pct(ev: Evidence, p: dict):
                                 cell.block_size)
     peaks = roofline.peaks_for(ev.device_kind, ev.peaks_path)
     least, _ = roofline.least_seconds(work, peaks)
-    # every chip of the cell works on each dispatch; the fullest sets the
-    # share
-    return 100.0 * least / len(ev.trace["devices"]) / per_dispatch_s
+    # every chip of the process that dispatched works on each dispatch
+    # (all the cell's, where the deployment is one node); the fullest sets
+    # the share
+    chips = ev.trace.get("devices_per_process") or len(ev.trace["devices"])
+    return 100.0 * least / chips / per_dispatch_s
 
 
 READERS = {f.__name__: f for f in (

@@ -7,8 +7,9 @@ It adds three things, none of them on the served path:
 - `prctl(PR_SET_PDEATHSIG, SIGKILL)`, so that the server dies with a
   parent that is itself killed;
 - a thread that sleeps on a named pipe (`MTPU_BENCH_CTL`) and acts on the
-  parent's cues: `device <file>` writes what JAX says of the device and
-  its peak memory, `trace <dir> <seconds> <until> <file>` takes a
+  parent's cues: `device <file>` writes what JAX says of this process's
+  devices (platform, kind, ids, coordinates, the chips' device files it
+  holds open) and their peak memory, `trace <dir> <seconds> <until> <file>` takes a
   `jax.profiler` trace of that many seconds (only the process that holds
   the chip can trace it). With `--trace 0` the only cue is one `device`, after the
   window has closed;
@@ -23,6 +24,7 @@ import ctypes
 import glob
 import json
 import os
+import re
 import shutil
 import signal
 import sys
@@ -58,7 +60,29 @@ def _device(path: str) -> None:
                        "kind": devs[0].device_kind,
                        "count": len(jax.devices()),
                        "memory_peak_bytes": max(peaks),
-                       "memory_peak_bytes_per_device": peaks})
+                       "memory_peak_bytes_per_device": peaks,
+                       "ids": [int(d.id) for d in devs],
+                       "coords": [[int(c) for c in getattr(d, "coords", ())]
+                                  + [int(getattr(d, "core_on_chip", 0))]
+                                  for d in devs],
+                       # which chips this process holds: a process that
+                       # is given some of a host's chips numbers them
+                       # from 0 again, so the ids do not tell two nodes'
+                       # chips apart; the device files it has open do
+                       "chip_files": _chip_files()})
+
+
+def _chip_files() -> list[str]:
+    """The accelerator device files this process holds open."""
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            link = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:
+            continue
+        if re.match(r"/dev/(accel|vfio/)\d+$", link):
+            held.add(link)
+    return sorted(held)
 
 
 def _device_ran(trace_dir: str) -> bool:

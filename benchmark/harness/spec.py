@@ -5,7 +5,13 @@ to one configuration, one traffic mix or one per-layer metric is a file of
 its own under the benchmark's data root:
 
     configs/<config>.json          the deployment: geometry, environment,
-                                   guarantees, source, assumed, reduced
+                                   guarantees, source, assumed, reduced;
+                                   `deployment.nodes` (1 where absent) is
+                                   the number of server processes that
+                                   form the one cluster: each holds
+                                   drives / nodes of the drives and
+                                   chips / nodes of the cell's chips, so
+                                   it has to divide both
     end_to_end/<metric>.json       one end-to-end metric: its reader and
                                    the reader's parameters
     traffic/<traffic>.json         the mix: loop kind, clients, ops, sizes,
@@ -75,6 +81,11 @@ class Cell:
         return self.config["deployment"]["drives"]
 
     @property
+    def nodes(self) -> int:
+        """Server processes of the deployment, one cluster together."""
+        return int(self.config["deployment"].get("nodes", 1))
+
+    @property
     def block_size(self) -> int:
         return self.config["deployment"]["block_size"]
 
@@ -140,5 +151,13 @@ def load_cell(workload: str, bench_json: str | None = None,
                         "end_to_end")
     layers = _with_readers(bench["per_layer"], workload, data_root,
                            "layer_metrics")
-    return Cell(name=workload, chips=int(entry["chips"]), config=config,
+    cell = Cell(name=workload, chips=int(entry["chips"]), config=config,
                 traffic=traffic, end_to_end=e2e, per_layer=layers)
+    for what, n in (("drives", cell.drives), ("chips", cell.chips)):
+        if cell.nodes < 1 or n % cell.nodes:
+            raise SpecError(
+                f"{cfg_path} states {cell.nodes} nodes, which do not divide "
+                f"the {n} {what} of workload {workload!r}: every node holds "
+                "the same share of the drives and chips of its own (one "
+                "process a chip: four nodes on one chip are no deployment)")
+    return cell

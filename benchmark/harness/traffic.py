@@ -16,6 +16,12 @@ Loop kinds (`kind`):
   set-up; the window starts one heal sequence over the rest through the
   admin API and polls its status.
 
+Against a deployment of N nodes the load is dealt over them as `warp
+--host a,b,c,d` deals its clients (assumed, from memory): client c of a
+window and job j of a set-up fan-out talk to node `c mod N`, `j mod N`;
+the admin calls of a heal go to node 1. With one host every request is
+what it is with no list at all.
+
 Any mix may name `trace_cue_share`, the share of the window at which a
 traced run's slice is cued (`runner.trace_cue_at`; the middle otherwise).
 
@@ -84,6 +90,7 @@ class Op:
     wrong: str = ""              # the answer came and said the wrong thing
     error: str = ""              # refused, failed, or never answered
     body: int = -1               # index into the pool of its size
+    node: int = 0                # which node of the deployment answered
 
     @property
     def latency(self) -> float:
@@ -109,11 +116,12 @@ class Window:
 
 
 class Load:
-    """One cell's traffic against one server."""
+    """One cell's traffic against one deployment: `hosts` are its nodes'
+    S3 endpoints, node 1 first."""
 
-    def __init__(self, traffic: dict, seed: int, host: str, root: str,
+    def __init__(self, traffic: dict, seed: int, hosts: list[str], root: str,
                  drives: int, say):
-        self.t, self.seed, self.host, self.root = traffic, seed, host, root
+        self.t, self.seed, self.hosts, self.root = traffic, seed, hosts, root
         self.drives, self.say = drives, say
         self.kind = traffic["kind"]
         if self.kind not in ("closed_loop", "open_loop", "heal"):
@@ -154,25 +162,26 @@ class Load:
             raise TrafficError(f"set-up PUT {key}: {st} {data[:200]!r}")
 
     def _fan(self, jobs: list, fn, clients: int) -> None:
-        """Run fn(s3, job) over jobs from `clients` threads; the first
-        error ends set-up."""
+        """Run fn(s3, job) over jobs from `clients` threads, job j on a
+        connection to node `j mod N`; the first error ends set-up."""
         errors: list[BaseException] = []
-        it = iter(jobs)
+        it = enumerate(jobs)
         lock = threading.Lock()
 
         def work():
-            s3 = S3(self.host)
+            conns = [S3(h) for h in self.hosts]    # opened on first use
             try:
                 while not errors:
                     with lock:
-                        job = next(it, None)
+                        j, job = next(it, (0, None))
                     if job is None:
                         return
-                    fn(s3, job)
+                    fn(conns[j % len(conns)], job)
             except BaseException as exc:  # noqa: BLE001 - re-raised below
                 errors.append(exc)
             finally:
-                s3.close()
+                for s3 in conns:
+                    s3.close()
 
         threads = [threading.Thread(target=work, daemon=True)
                    for _ in range(max(1, min(clients, len(jobs))))]
@@ -186,7 +195,7 @@ class Load:
     def setup(self) -> None:
         """Bucket, warm-up of this cell's own shapes, preload, and what
         the mix injects. All of it is set-up time."""
-        s3 = S3(self.host)
+        s3 = S3(self.hosts[0])
         st, _, data = s3.request("PUT", f"/{BUCKET}")
         if st != 200:
             raise TrafficError(f"make bucket: {st} {data[:200]!r}")
@@ -204,14 +213,20 @@ class Load:
             self._fan(jobs[1:],
                       lambda c, j: self._put(c, j[0], bodies[j[2]]),
                       int(pre.get("clients", 8)))
-        # warm every op the window will send, once alone and then from
-        # all clients at once
+        # warm every op the window will send, once alone (on every node
+        # in turn: each process compiles, or loads, its own programs) and
+        # then from all clients at once
         rounds = int(self.t.get("warmup_ops_per_client", 0))
         puts = [op for op in self.t.get("ops", []) if op["op"] == "PUT"]
         if rounds and puts:
-            for op in puts:
-                self._put(s3, f"warm/first-{op['size']}",
-                          self.pool(int(op["size"]))[0])
+            for n, host in enumerate(self.hosts, 1):
+                conn = s3 if n == 1 else S3(host)
+                for op in puts:
+                    self._put(conn, f"warm/first-{op['size']}"
+                              + ("" if n == 1 else f"-n{n}"),
+                              self.pool(int(op["size"]))[0])
+                if n > 1:
+                    conn.close()
             jobs = [(f"warm/c{c:02d}-{r}-{op['size']}", int(op["size"]))
                     for r in range(rounds) for c in range(self.clients)
                     for op in puts]
@@ -239,7 +254,7 @@ class Load:
         return os.path.join(self.root, f"d{d}")
 
     def _admin(self) -> AdminClient:
-        return AdminClient(self.host, ACCESS, SECRET, timeout=120.0)
+        return AdminClient(self.hosts[0], ACCESS, SECRET, timeout=120.0)
 
     def _heal_prefix(self, prefix: str, deadline_s: float) -> dict:
         adm = self._admin()
@@ -358,7 +373,7 @@ class Load:
         stop = threading.Event()
 
         def client(c: int):
-            s3 = S3(self.host)
+            s3 = S3(self.hosts[c % len(self.hosts)])
             rng = rng_for(self.seed, 4, c)
             own: list[tuple[str, int, int]] = []       # its PUTs, to DELETE
             once = self.preloaded[c::self.clients]     # its share, each once
@@ -431,6 +446,11 @@ class Load:
         elif kind in ("GET", "STAT"):
             if spec.get("keys") == "each_once":
                 if not once:
+                    # its share is read: the chance passes, and with a
+                    # pause, so that a client left with nothing but such
+                    # chances does not spin (no accepted cell comes here:
+                    # their preloads outlast their windows)
+                    time.sleep(0.05)
                     return None
                 key, size, bi = once.pop()
             else:
@@ -449,6 +469,7 @@ class Load:
             op = Op(kind, "obj/", 0, c, 0.0)
         else:
             raise TrafficError(f"unknown op kind {kind!r}")
+        op.node = c % len(self.hosts)
         op.sent = time.monotonic()
         op.due = op.sent if t_due is None else t_due
         try:

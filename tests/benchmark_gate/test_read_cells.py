@@ -142,10 +142,13 @@ def test_a_traced_degraded_get_run_reports_the_get_family(copy):
     # (a reader that failed over reads a ninth)
     assert got["verified_bytes_per_op.get"] >= 8 * 3 * 131072 == SIZE
     # one of twelve drives is wiped: two objects in three have a data
-    # shard on it, and each of their three blocks is a round trip
+    # shard on it, and their three blocks are one reader batch and one
+    # dispatch (PR 37: a block a round trip before), so at most a
+    # dispatch for every three rebuilt blocks, a tail or a failed-over
+    # read allowed for
     assert 0 < got["reconstructed_blocks_per_op.get"] <= 3
-    assert got["dispatches_per_op.get"] == pytest.approx(
-        got["reconstructed_blocks_per_op.get"])
+    assert 0 < got["dispatches_per_op.get"] <= \
+        got["reconstructed_blocks_per_op.get"] / 2
     assert got["device_call_ms_per_req.get"] > 0
     assert got["object_ms_per_req.get"] >= got["stream_ms_per_req.get"] > 0
     assert got["op_p95_ms.get"] >= got["op_p50_ms.get"] > 0
@@ -157,9 +160,10 @@ def test_a_traced_degraded_get_run_reports_the_get_family(copy):
 # --- the arithmetic the cell rests on -----------------------------------------
 
 
-# the fastest client of the builder's chip runs (PERF.md section 2) read
-# this many of its 120 keys in a 30 s window, and the run this many GETs
-FASTEST_CLIENT_GETS = 72
+# the fastest client of the builders' chip runs (PERF.md section 4: 75 to
+# 84 since PR 37, no run passed 110) read this many of its 120 keys in a
+# 30 s window, and the run this many GETs
+FASTEST_CLIENT_GETS = 84
 FASTEST_RUN_GETS = 534
 
 

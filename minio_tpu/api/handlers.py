@@ -20,7 +20,7 @@ import xml.etree.ElementTree as ET
 from dataclasses import dataclass, field
 
 from ..object.types import CompletePart, ObjectOptions
-from ..utils.errors import StorageError
+from ..utils.errors import ErrBucketNotFound, StorageError
 from .errors import S3Error, from_object_error
 
 MAX_OBJECT_SIZE = 5 * 1024 ** 4         # 5 TiB
@@ -236,13 +236,16 @@ class S3ApiHandlers:
 
     def __init__(self, object_layer, bucket_meta, iam, notify=None,
                  config=None, sse_config=None, repl_pool=None, quota=None,
-                 tier_engine=None):
+                 tier_engine=None, notification=None):
         from ..bucket.quota import BucketQuotaSys
 
         self.ol = object_layer
         self.bm = bucket_meta
         self.iam = iam
         self.notify = notify
+        # The peer broadcast (distributed/peer.NotificationSys); None on
+        # a single node.
+        self.notification = notification
         self.config = config
         self.sse_config = sse_config
         self.repl = repl_pool
@@ -385,6 +388,11 @@ class S3ApiHandlers:
         except StorageError as exc:
             raise from_object_error(exc) from exc
         self.bm.delete(ctx.bucket)
+        if self.notification is not None:
+            # Every other node forgets the bucket's metadata and its memo
+            # of the bucket now, not when the memo lapses (ref
+            # DeleteBucketHandler -> DeleteBucketMetadata).
+            self.notification.delete_bucket_metadata(ctx.bucket)
         self._event("s3:BucketRemoved:*", ctx.bucket)
         return Response(204)
 
@@ -395,8 +403,12 @@ class S3ApiHandlers:
         return Response.xml(root)
 
     def _check_bucket(self, bucket: str):
-        if not self.ol.bucket_exists(bucket):
-            raise S3Error("NoSuchBucket", bucket)
+        """The object layer's check, which answers from its memo of
+        buckets seen on the drives; HeadBucket alone asks every drive."""
+        try:
+            self.ol.check_bucket(bucket)
+        except ErrBucketNotFound as exc:
+            raise S3Error("NoSuchBucket", bucket) from exc
 
     def listen_notification(self, ctx) -> Response:
         """GET /bucket?events=...&prefix=&suffix= — live bucket event

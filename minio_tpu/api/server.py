@@ -503,7 +503,7 @@ class S3Server:
             object_layer, bucket_meta, iam, notify,
             config=config_sys.config if config_sys is not None else None,
             sse_config=sse_config, repl_pool=self.repl_pool, quota=quota,
-            tier_engine=tier_engine,
+            tier_engine=tier_engine, notification=notification,
         )
         self.admin = AdminHandlers(
             object_layer, iam, config_sys=config_sys, metrics=metrics,

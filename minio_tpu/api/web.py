@@ -209,7 +209,10 @@ class WebHandlers:
     def _m_delete_bucket(self, params, access_key):
         bucket = params.get("bucketName", "")
         self._authorize(access_key, "s3:DeleteBucket", bucket)
-        self.ol.delete_bucket(bucket)
+        # Through the S3 handler, so the bucket's metadata goes and every
+        # peer forgets the bucket, as after an S3 DeleteBucket.
+        self.h.delete_bucket(self._sub_ctx("DELETE", bucket, "",
+                                           access_key=access_key))
         return {}
 
     def _m_list_objects(self, params, access_key):

@@ -63,8 +63,12 @@ class PeerRESTServer:
         return {}
 
     def _h_delete_bucket_metadata(self, args, body):
+        """A peer deleted the bucket: drop its metadata and the object
+        layer's memo of it, so the next request here asks the drives."""
         if self.bucket_meta is not None:
             self.bucket_meta.invalidate(args["bucket"])
+        if self.object_layer is not None:
+            self.object_layer.forget_bucket(args["bucket"])
         return {}
 
     def _h_load_user(self, args, body):

@@ -10,7 +10,7 @@ override what they support, everything else answers NotImplemented).
 
 from __future__ import annotations
 
-from .utils.errors import ErrMethodNotAllowed
+from .utils.errors import ErrBucketNotFound, ErrMethodNotAllowed
 
 
 class GatewayUnsupported:
@@ -39,9 +39,11 @@ class GatewayUnsupported:
         except ErrMethodNotAllowed:
             return False
 
-    def get_bucket_info(self, bucket):
-        from .utils.errors import ErrBucketNotFound
+    def check_bucket(self, bucket):
+        if not self.bucket_exists(bucket):
+            raise ErrBucketNotFound(bucket)
 
+    def get_bucket_info(self, bucket):
         for b in self.list_buckets():
             if b.name == bucket:
                 return b

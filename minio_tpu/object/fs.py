@@ -92,7 +92,7 @@ class FSObjects:
         sha = hashlib.sha256(f"{bucket}/{object_}".encode()).hexdigest()
         return os.path.join(self.root, SYS_DIR, "multipart", sha, upload_id)
 
-    def _check_bucket(self, bucket: str):
+    def check_bucket(self, bucket: str):
         if not os.path.isdir(self._bucket_path(bucket)):
             raise ErrBucketNotFound(bucket)
 
@@ -106,7 +106,7 @@ class FSObjects:
 
     def delete_bucket(self, bucket: str, force: bool = False):
         p = self._bucket_path(bucket)
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         if not force and any(os.scandir(p)):
             raise ErrBucketNotEmpty(bucket)
         shutil.rmtree(p)
@@ -117,7 +117,7 @@ class FSObjects:
         return os.path.isdir(self._bucket_path(bucket))
 
     def get_bucket_info(self, bucket: str) -> BucketInfo:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         st = os.stat(self._bucket_path(bucket))
         return BucketInfo(bucket, int(st.st_mtime_ns))
 
@@ -134,7 +134,7 @@ class FSObjects:
     # --- objects ---
 
     def put_object(self, bucket, object_, reader, size, opts=None) -> ObjectInfo:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         opts = opts or ObjectOptions()
         tmp = os.path.join(
             self.root, SYS_DIR, "tmp", f"put-{os.getpid()}-{time.time_ns()}"
@@ -250,14 +250,14 @@ class FSObjects:
         )
 
     def get_object_info(self, bucket, object_, opts=None) -> ObjectInfo:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         if not os.path.isfile(self._obj_path(bucket, object_)):
             raise ErrObjectNotFound(f"{bucket}/{object_}")
         return self._info(bucket, object_, self._load_meta(bucket, object_))
 
     def get_object_bytes(self, bucket, object_, offset=0, length=-1,
                          opts=None) -> bytes:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         p = self._obj_path(bucket, object_)
         try:
             with open(p, "rb") as f:
@@ -285,7 +285,7 @@ class FSObjects:
         return self.get_object_info(bucket, object_, opts)
 
     def delete_object(self, bucket, object_, opts=None):
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         p = self._obj_path(bucket, object_)
         if not os.path.isfile(p):
             raise ErrObjectNotFound(f"{bucket}/{object_}")
@@ -341,7 +341,7 @@ class FSObjects:
     def list_objects(self, bucket: str, prefix: str = "", marker: str = "",
                      delimiter: str = "", max_keys: int = 1000,
                      opts=None) -> ListObjectsInfo:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         base = self._bucket_path(bucket)
         names: list[str] = []
 
@@ -398,7 +398,7 @@ class FSObjects:
     # --- multipart (ref cmd/fs-v1-multipart.go) ---
 
     def new_multipart_upload(self, bucket, object_, opts=None) -> str:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         from ..storage.fileinfo import new_uuid
 
         upload_id = new_uuid()
@@ -477,7 +477,7 @@ class FSObjects:
         return out[: max_parts + 1]
 
     def list_multipart_uploads(self, bucket, prefix="") -> list[MultipartInfo]:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         root = os.path.join(self.root, SYS_DIR, "multipart")
         out = []
         for sha in sorted(os.listdir(root)):
@@ -549,7 +549,7 @@ class FSObjects:
         return {"healed": False, "backend": "fs"}
 
     def heal_bucket(self, bucket) -> dict:
-        self._check_bucket(bucket)
+        self.check_bucket(bucket)
         return {"healed": False, "backend": "fs"}
 
     def heal_format(self) -> dict:

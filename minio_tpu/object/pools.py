@@ -23,6 +23,27 @@ from ..utils.errors import (
 from .sets import ErasureSets
 from .types import ListObjectsInfo, ObjectInfo, ObjectOptions
 
+# `bucket_check_total{answer}`: the front end's bucket checks by where the
+# answer came from, at 0 from `set_metrics` on.
+BUCKET_CHECK_ANSWERS = ("memo", "drives")
+_metrics = None
+
+
+def set_metrics(registry) -> None:
+    global _metrics
+    _metrics = registry
+    if registry is not None:
+        for answer in BUCKET_CHECK_ANSWERS:
+            registry.inc("bucket_check_total", 0, answer=answer)
+
+
+def _count_check(answer: str) -> None:
+    """One registry write, under the registry's own lock and no other; a
+    check racing `set_metrics` counts into the old registry or none."""
+    reg = _metrics
+    if reg is not None:
+        reg.inc("bucket_check_total", answer=answer)
+
 
 class ErasureServerPools:
     """ObjectLayer over one or more ErasureSets pools."""
@@ -48,10 +69,11 @@ class ErasureServerPools:
         # when set, pages route to the listing's owner node and mutations
         # broadcast generation bumps to peers.
         self.listing_coordinator = None
-        # Positive bucket-existence cache: _check_bucket used to stat the
+        # Positive bucket-existence memo: a bucket check used to stat the
         # bucket volume on EVERY disk per object op (16 syscalls per PUT
         # on the batched path). Positives are safe to cache briefly —
-        # delete_bucket invalidates — and negatives are never cached, so
+        # delete_bucket forgets, here and (through the S3 handler's
+        # broadcast) on every peer — and negatives are never cached, so
         # a just-created bucket is visible immediately.
         self._bucket_seen: dict[str, float] = {}
         self._bucket_seen_lock = threading.Lock()
@@ -128,13 +150,13 @@ class ErasureServerPools:
             self.update_tracker.mark(bucket)
 
     def delete_bucket(self, bucket: str, force: bool = False):
-        self._forget_bucket(bucket)
+        self.forget_bucket(bucket)
         for pool in self.pools:
             pool.delete_bucket(bucket, force=force)
-        # Forget AGAIN after the volumes are gone: a _check_bucket racing
+        # Forget AGAIN after the volumes are gone: a bucket check racing
         # the deletes above can observe the still-present bucket and
         # re-cache it; this second invalidation closes that window.
-        self._forget_bucket(bucket)
+        self.forget_bucket(bucket)
         self._metacache.invalidate_bucket(bucket)
         self._list_gen.pop(bucket, None)
         if self.update_tracker is not None:
@@ -157,18 +179,34 @@ class ErasureServerPools:
                 seen.setdefault(b.name, b)
         return [seen[k] for k in sorted(seen)]
 
-    def _check_bucket(self, bucket: str):
+    def check_bucket(self, bucket: str):
+        """Raise ErrBucketNotFound unless `bucket` exists: the S3 front
+        end's check before every request that names a bucket. Counted once
+        a call by where the answer came from; the object ops below check
+        again through `_check_bucket`, uncounted, and find the memo the
+        front end has just filled."""
+        answer = "drives"  # a negative comes from the drives, and raises
+        try:
+            answer = self._check_bucket(bucket)
+        finally:
+            _count_check(answer)
+
+    def _check_bucket(self, bucket: str) -> str:
+        """The memo while it holds a positive younger than the TTL, else
+        every drive (`bucket_exists`); returns which answered. A negative
+        raises and is never kept."""
         now = time.monotonic()
         with self._bucket_seen_lock:
             seen = self._bucket_seen.get(bucket, 0.0)
         if now - seen < self._BUCKET_SEEN_TTL_S:
-            return
+            return "memo"
         if not self.bucket_exists(bucket):
             raise ErrBucketNotFound(bucket)
         with self._bucket_seen_lock:
             self._bucket_seen[bucket] = now
+        return "drives"
 
-    def _forget_bucket(self, bucket: str):
+    def forget_bucket(self, bucket: str):
         with self._bucket_seen_lock:
             self._bucket_seen.pop(bucket, None)
 

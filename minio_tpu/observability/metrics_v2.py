@@ -72,6 +72,9 @@ DESCRIPTORS: list[tuple[str, str, str]] = [
      "Detached-straggler successes discarded after the grace window"),
     ("dsync_unlock_failures_total", "counter",
      "dsync unlock RPCs that failed (grant leaks until expiry)"),
+    ("bucket_check_total", "counter",
+     "S3 front-end bucket checks by answer: the object layer's memo of "
+     "buckets seen on the drives, or the drives asked"),
     # --- erasure/heal + the heal/MRF scoreboard (ISSUE 14) ---
     ("heal_objects_total", "counter", "Objects healed by trigger"),
     ("heal_failures_total", "counter", "Object heal failures"),

@@ -149,6 +149,11 @@ class Server:
         _fanout.set_metrics(self.metrics)
         # RPC transient-retry accounting (mtpu_rpc_retries_total).
         _rest.set_metrics(self.metrics)
+        # The front end's bucket checks, memo against drives
+        # (mtpu_bucket_check_total).
+        from .object import pools as _pools
+
+        _pools.set_metrics(self.metrics)
         # Concurrency plane: the encode/read admission governors and
         # the GIL-free worker pool mirror admitted/queued/rejected and
         # worker-health series onto the same registry (mtpu_admission_*

@@ -30,6 +30,7 @@ from benchmark.harness import child as childmod
 from benchmark.harness import reference
 from minio_tpu.api.sign import sign_v4_request
 from minio_tpu.distributed import rest
+from minio_tpu.object.pools import ErasureServerPools
 from minio_tpu.observability import spans
 from minio_tpu.server import Server
 from minio_tpu.storage.xlmeta import read_xl_meta
@@ -290,6 +291,34 @@ def test_a_put_on_one_node_records_no_rpc_span(tmp_path, monkeypatch):
             assert "rpc" not in kinds
         finally:
             srv.stop()
+
+
+def test_a_bucket_deleted_through_one_node_is_refused_by_another(
+        cluster, monkeypatch):
+    """Node 1 answers its bucket checks from a memo of buckets seen on the
+    drives; node 2's DeleteBucket makes every peer forget the bucket, so
+    node 1's very next request asks the drives, and no commit brings the
+    bucket's directory back. The memo is held from lapsing, so that only
+    the peer's forgetting can make node 1 ask."""
+    monkeypatch.setattr(ErasureServerPools, "_BUCKET_SEEN_TTL_S", 3600.0)
+    with limit(60):
+        bucket, key = "gone", "k"
+        body = reference.payload(40, key, MIB + 3)
+        assert request(cluster.nodes[0], "PUT", f"/{bucket}")[0] == 200
+        # the memo warm on node 1
+        assert request(cluster.nodes[0], "PUT", f"/{bucket}/{key}",
+                       body)[0] == 200
+        assert request(cluster.nodes[1], "DELETE",
+                       f"/{bucket}/{key}")[0] == 204
+        assert request(cluster.nodes[1], "DELETE", f"/{bucket}")[0] == 204
+        for method, data in (("PUT", body), ("GET", b"")):
+            status, resp = request(cluster.nodes[0], method,
+                                   f"/{bucket}/{key}", data)
+            assert status == 404, resp
+            assert b"<Code>NoSuchBucket</Code>" in resp
+        for d in range(1, DRIVES + 1):
+            assert not os.path.exists(os.path.join(cluster.tmp, f"d{d}",
+                                                   bucket))
 
 
 def test_one_node_stopped_leaves_put_and_get_served(cluster):

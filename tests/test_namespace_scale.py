@@ -74,6 +74,12 @@ class SyntheticLayer:
     def bucket_exists(self, bucket):
         return bucket == "synth"
 
+    def check_bucket(self, bucket):
+        from minio_tpu.utils.errors import ErrBucketNotFound
+
+        if not self.bucket_exists(bucket):
+            raise ErrBucketNotFound(bucket)
+
     def make_bucket(self, bucket):
         pass
 

@@ -363,3 +363,32 @@ def test_console_page_has_new_controls(server):
     for needle in ("web.ListObjectVersions", "web.RestoreVersion",
                    "web.SetBucketPolicy", "shareexp", "Delete selected"):
         assert needle in page, needle
+
+
+def test_delete_bucket_takes_the_buckets_metadata_along(server, token):
+    """The console's DeleteBucket is the S3 handler's: a bucket made again
+    under the same name starts without the old one's policy."""
+    assert rpc(server, "web.MakeBucket",
+               {"bucketName": "webgone"}, token)[1].get("result") == {}
+    policy = json.dumps({
+        "Version": "2012-10-17",
+        "Statement": [{
+            "Effect": "Allow", "Principal": {"AWS": ["*"]},
+            "Action": ["s3:GetObject"],
+            "Resource": ["arn:aws:s3:::webgone/*"],
+        }],
+    })
+    st, resp = rpc(server, "web.SetBucketPolicy",
+                   {"bucketName": "webgone", "policy": policy}, token)
+    assert st == 200 and resp.get("result") == {}, resp
+    st, resp = rpc(server, "web.DeleteBucket",
+                   {"bucketName": "webgone"}, token)
+    assert st == 200 and resp.get("result") == {}, resp
+    st, resp = rpc(server, "web.GetBucketPolicy",
+                   {"bucketName": "webgone"}, token)
+    assert "result" not in resp, resp
+    assert rpc(server, "web.MakeBucket",
+               {"bucketName": "webgone"}, token)[1].get("result") == {}
+    st, resp = rpc(server, "web.GetBucketPolicy",
+                   {"bucketName": "webgone"}, token)
+    assert st == 200 and resp["result"]["policy"] == "", resp

@@ -221,6 +221,8 @@ class RPCServer:
 
     def register(self, name: str, fn):
         self._methods[name] = fn
+        # a client's `rpc` span of this method keeps it as its label
+        _spans.name_rpc(self.plane, name)
 
     def start(self):
         self._thread = threading.Thread(

@@ -1067,7 +1067,9 @@ class ParallelReader:
         last_hedge = 0.0
         state["progress"] = time.monotonic()
         t_span0 = time.monotonic_ns()
-        with cv:
+        # The wait is on the profiler's clock as well: the span is
+        # recorded below, with the bookkeeping after the wait in it.
+        with _spans.twin("fanout", "shard-read-wait"), cv:
             while len(results) < self.data_blocks:
                 if (state["active"] == 0
                         and state["next"] >= len(self.readers)):

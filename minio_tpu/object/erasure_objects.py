@@ -94,8 +94,10 @@ def _fanout(fn, n: int):
     """Run fn(i) for i in range(n) through the pool. Pool threads carry
     the caller's request-scoped observability context (span trace +
     byte-flow op tag) so metadata reads/writes attribute to the
-    request."""
-    list(_obj_pool.map(_obs_carry(fn), range(n)))
+    request. The caller's wait for all of them is a `fanout` span,
+    label `all`, on the profiler's clock too."""
+    with _spans.span("fanout", "all", mirror=True):
+        list(_obj_pool.map(_obs_carry(fn), range(n)))
 
 
 def _quorum_fanout(attempt, n: int, errs: list, quorum: int,

@@ -30,8 +30,9 @@ Design (ISSUE 12):
 - **Export** — every span observes `mtpu_span_seconds{kind=...,op=...}`
   (the registry's log-spaced latency buckets; `op` is the root's API
   name, so a cell that mixes operations reads each apart) when a
-  registry is installed; finished trees also stream to `mc admin
-  trace`-style consumers that subscribed with `?spans=true`
+  registry is installed; an `rpc` or a `fanout` span adds its label,
+  cut to a closed set (`series_label`); finished trees also stream to
+  `mc admin trace`-style consumers that subscribed with `?spans=true`
   (TraceHub.publish_spans), and the exemplar store answers the admin
   query. The tree's own `device-call` spans say how many fused
   dispatches the request made.
@@ -48,9 +49,16 @@ Design (ISSUE 12):
   gap takes the event with the longest overlap, and an outer one would
   swallow every gap under it.
 
+- **The interpreter's wake-up lateness** — while a registry is
+  installed one daemon thread (`mtpu-interp-probe`) sleeps `PROBE_S`
+  and observes how much later than that it runs Python again
+  (`mtpu_interp_wait_seconds`): the interpreter lock's hand-over plus
+  the host's scheduler, which every pool task, stage hand-over and
+  fan-out wake-up pays. One series a process, no `op`.
+
 Always-on: `MTPU_TRACE=0` (or off/false/no) disarms the whole plane —
-`request_trace` then yields no context and every instrumentation site
-degrades to one contextvar read.
+`request_trace` then yields no context, every instrumentation site
+degrades to one contextvar read, and the probe observes nothing.
 """
 
 from __future__ import annotations
@@ -69,9 +77,13 @@ SPAN_DESCRIPTORS: list[tuple[str, str, str]] = [
      "Request-span latency by span kind (request/body-read/admission/"
      "lock/object/commit/readtier/stream/stage/device-h2d/device-call/"
      "device-wait/worker/fanout/disk/rpc) and by op, the root's API "
-     "name"),
+     "name; rpc and fanout spans also by label (<plane>:<method>, or "
+     "the fan-out's phase)"),
     ("trace_slow_captures_total", "counter",
      "Slow-request span trees captured into the exemplar store"),
+    ("interp_wait_seconds", "histogram",
+     "How late a thread that slept 10 ms runs Python again: the "
+     "interpreter lock's hand-over plus the host's scheduler"),
 ]
 
 RING_RECORDS = 1024        # per-thread ring slots (fixed-size records)
@@ -80,16 +92,49 @@ SLOW_BACKGROUND_CAP = 8    # and, apart from them, of background roots
 P99_WINDOW = 512           # request durations feeding the auto threshold
 P99_RECALC_EVERY = 32      # recompute cadence (finishes per recompute)
 MAX_TREE_SPANS = 2048      # exemplar size bound (ring scan result cap)
+PROBE_S = 0.01             # the interpreter probe's sleep
 
 _metrics = None  # guarded-by: _metrics_mu
 _metrics_mu = threading.Lock()
+# (thread, stop event) of the interpreter probe, while a registry is
+# installed
+_probe = None  # guarded-by: _metrics_mu
 _hub = None  # TraceHub for ?spans=true streaming (server boot wires it)
 
 
 def set_metrics(registry) -> None:
-    global _metrics
+    """Install the registry (None takes it away). The interpreter probe
+    runs while one is installed: the first registry starts it, None
+    stops and joins it."""
+    global _metrics, _probe
     with _metrics_mu:
         _metrics = registry
+        probe = _probe
+        if registry is not None:
+            # A forked child inherits the record but not the thread.
+            if probe is None or not probe[0].is_alive():
+                stop = threading.Event()
+                thread = threading.Thread(
+                    target=_probe_loop, args=(stop,),
+                    name="mtpu-interp-probe", daemon=True)
+                _probe = (thread, stop)
+                thread.start()
+            return
+        _probe = None
+    if probe is not None:
+        probe[1].set()
+        probe[0].join()
+
+
+def _probe_loop(stop: threading.Event) -> None:
+    period_ns = int(PROBE_S * 1e9)
+    while not stop.is_set():
+        t0 = time.monotonic_ns()
+        time.sleep(PROBE_S)
+        late_ns = time.monotonic_ns() - t0 - period_ns
+        reg = _reg()
+        if reg is not None and enabled():
+            reg.observe("interp_wait_seconds", max(late_ns, 0) / 1e9)
 
 
 def _reg():
@@ -276,9 +321,38 @@ def bound(carrier, fn):
 # ---------------------------------------------------------------------------
 # recording
 
-def _observe(ctx: TraceCtx, kind: str, dur_ns: int) -> None:
+# The labels an `rpc` or a `fanout` span's series may keep: every
+# `<plane>:<method>` an RPC server of this process serves (`name_rpc`),
+# and the fan-out's phases (`hedge #j` and `straggler-detach #j` without
+# their reader). Any other label of theirs reads `other`.
+FANOUT_PHASES = frozenset((
+    "all", "quorum-wait", "shard-read-wait", "hedge", "straggler-detach",
+))
+_rpc_labels: set[str] = set()
+
+
+def name_rpc(plane: str, method: str) -> None:
+    """Admit `<plane>:<method>` as an `rpc` span's series label (an RPC
+    server calls this for each method it registers)."""
+    _rpc_labels.add(f"{plane}:{method}")
+
+
+def series_label(kind: str, label: str) -> str:
+    """The label that an `rpc` or a `fanout` span's series keeps."""
+    if kind == "rpc":
+        return label if label in _rpc_labels else "other"
+    phase = label.split(" #", 1)[0]
+    return phase if phase in FANOUT_PHASES else "other"
+
+
+def _observe(ctx: TraceCtx, kind: str, label: str, dur_ns: int) -> None:
     reg = _reg()
-    if reg is not None:
+    if reg is None:
+        return
+    if kind == "rpc" or kind == "fanout":
+        reg.observe("span_seconds", dur_ns / 1e9, kind=kind,
+                    label=series_label(kind, label), op=ctx.label)
+    else:
         reg.observe("span_seconds", dur_ns / 1e9, kind=kind, op=ctx.label)
 
 
@@ -300,7 +374,7 @@ def record(kind: str, label: str, dur_ns: int,
         ctx.trace_id, ctx.alloc(), _parent_var.get(), kind, label,
         start_ns, dur_ns, threading.current_thread().name,
     ))
-    _observe(ctx, kind, dur_ns)
+    _observe(ctx, kind, label, dur_ns)
 
 
 # Kinds that hold no other span on their thread, and so go onto the
@@ -394,7 +468,7 @@ class _Span:
             self.label, self._t0, end - self._t0,
             threading.current_thread().name,
         ))
-        _observe(self._ctx, self.kind, end - self._t0)
+        _observe(self._ctx, self.kind, self.label, end - self._t0)
         return False
 
 
@@ -496,7 +570,7 @@ def _finish(ctx: TraceCtx) -> None:
         ctx.trace_id, ctx.root_id, 0, "request", ctx.label,
         ctx.start_ns, dur_ns, threading.current_thread().name,
     ))
-    _observe(ctx, "request", dur_ns)
+    _observe(ctx, "request", ctx.label, dur_ns)
     dur_ms = dur_ns / 1e6
     threshold = slow_threshold_ms()
     if not ctx.background:

@@ -20,10 +20,10 @@ cheap. The governor keeps the same bounded-slot model and adds:
 - **straggler-fair scheduling** — freed slots grant round-robin
   ACROSS clients (FIFO within a client), so the Nth upload of a hot
   client queues behind the 1st upload of everyone else;
-- **telemetry** — admitted/queued/rejected counters and
-  inflight/queue-depth gauges exported as `mtpu_admission_*` via the
-  metrics registry (server boot wires it), with a jax-free snapshot
-  for tests and bench.
+- **telemetry** — admitted/rejected counters and the inflight gauge
+  exported as `mtpu_admission_*` via the metrics registry (server
+  boot wires it), with a jax-free snapshot (queue depth and queued
+  totals among it) for tests and bench.
 
 Client identity flows through a contextvar set at the API dispatch
 (access key, falling back to anonymous); internal callers (heal,
@@ -156,16 +156,10 @@ class AdmissionConfig:
 ADMISSION_DESCRIPTORS: list[tuple[str, str, str]] = [
     ("admission_admitted_total", "counter",
      "Encode streams admitted by the concurrency governor"),
-    ("admission_queued_total", "counter",
-     "Encode streams that waited in the admission queue"),
     ("admission_rejected_total", "counter",
      "Encode streams rejected by the governor (by reason)"),
     ("admission_inflight", "gauge",
      "Encode streams currently admitted"),
-    ("admission_queue_depth", "gauge",
-     "Encode streams waiting for admission"),
-    ("admission_clients_waiting", "gauge",
-     "Distinct clients with queued encode streams"),
     ("admission_coalesced_bypass_total", "counter",
      "GET streams served without consuming a decode slot (hot-tier "
      "cache hits and single-flight followers riding another "
@@ -341,7 +335,6 @@ class AdmissionGovernor:
             self._waiting += 1
             self.queued_total += 1
             sp.relabel(f"{self.domain or 'put'}/queued")
-            self._mirror_queued()
             # Capacity may be free right now (fast path declined only
             # because others were already waiting): run one grant pass
             # so the head of the rotation — possibly us — proceeds.
@@ -466,19 +459,9 @@ class AdmissionGovernor:
 
     def _mirror_gauges(self) -> None:  # guarded-by: _cv
         reg = _reg()
-        if reg is None:
-            return
-        lb = self._labels()
-        reg.set_gauge("admission_inflight", self._inflight, **lb)
-        reg.set_gauge("admission_queue_depth", self._waiting, **lb)
-        reg.set_gauge("admission_clients_waiting", len(self._queues), **lb)
-
-    def _mirror_queued(self) -> None:  # guarded-by: _cv
-        reg = _reg()
         if reg is not None:
-            lb = self._labels()
-            reg.inc("admission_queued_total", **lb)
-            reg.set_gauge("admission_queue_depth", self._waiting, **lb)
+            reg.set_gauge("admission_inflight", self._inflight,
+                          **self._labels())
 
     def _mirror_reject(self, reason: str) -> None:
         reg = _reg()

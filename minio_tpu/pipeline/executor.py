@@ -11,8 +11,8 @@ Semantics:
   flag turns every queue wait into a prompt abort, workers exit, and
   run()/results() re-raise the original error after all threads have
   been joined (deterministic draining: no worker outlives the call).
-- **Telemetry** — per-stage items/bytes/busy/starve/stall and queue
-  depth, flushed once per run into pipeline.metrics.
+- **Telemetry** — per-stage items/bytes/busy/starve/stall, flushed
+  once per run into pipeline.metrics.
 
 The executor deliberately offers ONE topology: a linear chain. Shard
 fan-out (one write per disk) stays inside a stage via the existing IO
@@ -197,9 +197,6 @@ class Pipeline:
             if not ok:
                 self._drop_item(out)
                 return
-            # no-ops internally when no registry is installed
-            _pmetrics.record_queue_depth(self.name, stage.name,
-                                         out_q.qsize())
 
     # ------------------------------------------------------------------
     # driving
@@ -291,8 +288,7 @@ class Pipeline:
                         self._drop_item(q.get_nowait())
                     except _queue.Empty:
                         break
-        _pmetrics.record_run(self.name, self.stages,
-                             error=self._error is not None)
+        _pmetrics.record_run(self.name, self.stages)
         for p in self.pools:
             _pmetrics.record_pool(p)
 

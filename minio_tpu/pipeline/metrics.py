@@ -6,8 +6,8 @@ The registry is process-global and settable (the server wires its
 Metrics instance at startup; bench and tests read the module-local
 snapshot instead) because the hot paths construct pipelines deep inside
 the erasure layer where no registry handle is plumbed. Recording is
-coarse-grained — one flush per pipeline RUN plus a queue-depth gauge
-per item handoff — so telemetry never adds per-byte cost.
+coarse-grained — one flush per pipeline RUN — so telemetry never adds
+per-item cost.
 """
 
 from __future__ import annotations
@@ -25,8 +25,6 @@ _pool_totals: dict[str, dict] = {}
 # Descriptors contributed to observability/metrics_v2.DESCRIPTORS.
 PIPELINE_DESCRIPTORS: list[tuple[str, str, str]] = [
     ("pipeline_runs_total", "counter", "Pipeline runs by pipeline"),
-    ("pipeline_errors_total", "counter",
-     "Pipeline runs cancelled by a stage error"),
     ("pipeline_stage_items_total", "counter",
      "Items processed by pipeline stage"),
     ("pipeline_stage_bytes_total", "counter",
@@ -37,14 +35,6 @@ PIPELINE_DESCRIPTORS: list[tuple[str, str, str]] = [
      "Seconds the stage starved on its input queue"),
     ("pipeline_stage_stall_seconds_total", "counter",
      "Seconds the stage blocked on downstream backpressure"),
-    ("pipeline_stage_errors_total", "counter",
-     "Exceptions raised by pipeline stage functions"),
-    ("pipeline_queue_depth", "gauge",
-     "Items currently queued ahead of a stage"),
-    ("pipeline_buffer_pool_allocated", "gauge",
-     "Buffers ever allocated by a pool (flat under steady state)"),
-    ("pipeline_buffer_pool_reused_total", "counter",
-     "Buffer acquisitions served from the freelist"),
 ]
 
 
@@ -60,14 +50,12 @@ def get_registry():
         return _registry
 
 
-def record_run(pipeline_name: str, stages, error: bool) -> None:
+def record_run(pipeline_name: str, stages) -> None:
     """Flush one finished run's per-stage stats (executor calls this
     exactly once per run, success or cancellation)."""
     reg = get_registry()
     if reg is not None:
         reg.inc("pipeline_runs_total", pipeline=pipeline_name)
-        if error:
-            reg.inc("pipeline_errors_total", pipeline=pipeline_name)
     with _mu:
         for st in stages:
             s = st.stats
@@ -95,29 +83,13 @@ def record_run(pipeline_name: str, stages, error: bool) -> None:
         reg.inc("pipeline_stage_busy_seconds_total", s.busy_s, **labels)
         reg.inc("pipeline_stage_wait_seconds_total", s.wait_s, **labels)
         reg.inc("pipeline_stage_stall_seconds_total", s.stall_s, **labels)
-        if s.errors:
-            reg.inc("pipeline_stage_errors_total", s.errors, **labels)
-
-
-def record_queue_depth(pipeline_name: str, stage_name: str,
-                       depth: int) -> None:
-    reg = get_registry()
-    if reg is not None:
-        reg.set_gauge("pipeline_queue_depth", depth,
-                      pipeline=pipeline_name, stage=stage_name)
 
 
 def record_pool(pool) -> None:
-    """Mirror a BufferPool's counters (executor flushes per run)."""
+    """Keep a BufferPool's counters (executor flushes per run)."""
     stats = pool.stats()
     with _mu:
         _pool_totals[pool.name] = stats
-    reg = get_registry()
-    if reg is not None:
-        reg.set_gauge("pipeline_buffer_pool_allocated", stats["allocated"],
-                      pool=pool.name)
-        reg.set_gauge("pipeline_buffer_pool_reused_total", stats["reused"],
-                      pool=pool.name)
 
 
 def stage_stats_snapshot(pipeline_name: str | None = None) -> dict:

@@ -130,10 +130,9 @@ class Server:
         # counters from the very first format read.
         self.metrics = Metrics()
         # The erasure hot paths flush per-stage pipeline telemetry
-        # (put/get/heal stage timings, queue depths, buffer-pool reuse)
-        # through this process-global hook — plumbing a registry handle
-        # down into erasure/streaming.py would thread it through every
-        # call site.
+        # (put/get/heal stage timings) through this process-global hook
+        # — plumbing a registry handle down into erasure/streaming.py
+        # would thread it through every call site.
         from .pipeline import metrics as pipeline_metrics
 
         pipeline_metrics.set_registry(self.metrics)
@@ -244,7 +243,9 @@ class Server:
                 )
             else:
                 def mk_disk(ep):
-                    return self._wrap_disk(LocalStorage(ep, endpoint=ep), ep)
+                    return self._wrap_disk(
+                        LocalStorage(ep, endpoint=ep, metrics=self.metrics),
+                        ep)
             pools = []
             for pi, endpoints in enumerate(layout["pools"]):
                 # Every disk is wrapped in the per-op metrics/disk-id
@@ -510,7 +511,8 @@ class Server:
         for ep in all_eps:
             netloc, path = _split_url(ep)
             if netloc == storage_address:
-                local_disks.append(LocalStorage(path, endpoint=ep))
+                local_disks.append(LocalStorage(path, endpoint=ep,
+                                               metrics=self.metrics))
         if not local_disks:
             raise ValueError(
                 f"no endpoint matches this node ({storage_address})"

@@ -46,6 +46,10 @@ SYSTEM_TMP = SYSTEM_META_BUCKET + "/tmp"
 SYSTEM_MULTIPART = SYSTEM_META_BUCKET + "/multipart"
 XL_META_FILE = "xl.meta"
 
+# The ops that take the drive's metadata lock (`LocalStorage._take_lock`).
+_LOCKED_OPS = ("rename_data", "write_metadata", "update_metadata",
+               "delete_version")
+
 # Shard files at or below this size are inlined into xl.meta
 # (smallFileThreshold, ref cmd/xl-storage.go:66): a small PUT becomes
 # ONE metadata write per disk instead of shard-write + rename-commit.
@@ -74,12 +78,21 @@ def _check_path(p: str):
 class LocalStorage(StorageAPI):
     """POSIX StorageAPI over one directory tree ("disk")."""
 
-    def __init__(self, root: str, endpoint: str = "", fsync: bool = False):
+    def __init__(self, root: str, endpoint: str = "", fsync: bool = False,
+                 metrics=None):
         self.root = os.path.abspath(root)
         self._endpoint = endpoint or self.root
         self._fsync = fsync
         self._disk_id = ""
+        # The drive's metadata lock, taken through `_take_lock`, which
+        # counts the waits for it into `metrics` (the registry the drive
+        # guard raises disk_ops_total with); their series stand at 0.
         self._lock = threading.RLock()
+        self._metrics = metrics
+        if metrics is not None:
+            for op in _LOCKED_OPS:
+                metrics.inc("drive_lock_wait_seconds_total", 0.0, op=op)
+                metrics.inc("drive_lock_waits_total", 0.0, op=op)
         self._online = True
         os.makedirs(os.path.join(self.root, *SYSTEM_TMP.split("/")), exist_ok=True)
         # O_DIRECT shard writes (ref cmd/xl-storage.go:1089 + fallocate):
@@ -92,6 +105,24 @@ class LocalStorage(StorageAPI):
             self._odirect = supports_odirect(self.root)
 
     # --- helpers ---
+
+    def _take_lock(self, op: str) -> None:
+        """Take the drive's metadata lock for `op`; the caller releases
+        it. Free, it is one non-blocking acquire and nothing recorded;
+        held by another thread, the blocking acquire is timed into
+        drive_lock_wait_seconds_total{op} and drive_lock_waits_total{op}:
+        counters and not spans, since a remote drive's ops run on the
+        storage plane's threads, where no trace is active."""
+        # lock-ok: the caller's try/finally releases what this takes
+        if self._lock.acquire(blocking=False):
+            return
+        t0 = time.monotonic_ns()
+        self._lock.acquire()  # lock-ok: as above
+        metrics = self._metrics
+        if metrics is not None:
+            metrics.inc("drive_lock_wait_seconds_total",
+                        (time.monotonic_ns() - t0) / 1e9, op=op)
+            metrics.inc("drive_lock_waits_total", op=op)
 
     def _vol_path(self, volume: str) -> str:
         _check_path(volume)
@@ -446,7 +477,8 @@ class LocalStorage(StorageAPI):
 
     def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         self._require_online()
-        with self._lock:
+        self._take_lock("write_metadata")
+        try:
             blob = self._fresh_meta_blob(volume, path, fi)
             if blob is not None:
                 self._write_meta_blob(volume, path, blob)
@@ -457,14 +489,19 @@ class LocalStorage(StorageAPI):
                 meta = XLMeta()
             meta.add_version(fi)
             self._write_meta(volume, path, meta)
+        finally:
+            self._lock.release()
 
     def update_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         self._require_online()
-        with self._lock:
+        self._take_lock("update_metadata")
+        try:
             meta = self._read_meta(volume, path)
             meta.find_version(fi.version_id)  # must exist
             meta.add_version(fi)
             self._write_meta(volume, path, meta)
+        finally:
+            self._lock.release()
 
     def read_version(self, volume: str, path: str, version_id: str = "",
                      read_data: bool = False) -> FileInfo:
@@ -488,7 +525,8 @@ class LocalStorage(StorageAPI):
         """Remove one version; drop xl.meta + dirs when journal empties
         (ref cmd/xl-storage.go DeleteVersion)."""
         self._require_online()
-        with self._lock:
+        self._take_lock("delete_version")
+        try:
             meta = self._read_meta(volume, path)
             data_dir = meta.delete_version(fi)
             if data_dir:
@@ -507,6 +545,8 @@ class LocalStorage(StorageAPI):
                 obj_dir = self._file_path(volume, path)
                 shutil.rmtree(obj_dir, ignore_errors=True)
                 self._cleanup_empty_dirs(volume, path)
+        finally:
+            self._lock.release()
 
     def delete_versions(self, volume: str, versions: list[FileInfo]) -> list:
         errs = []
@@ -538,7 +578,8 @@ class LocalStorage(StorageAPI):
         # lock-ok: per-disk metadata transaction lock — the
         # rename+journal-merge must be atomic per disk (the reference
         # holds xl-storage's lock across RenameData the same way)
-        with self._lock:
+        self._take_lock("rename_data")
+        try:
             dst_dir = self._file_path(dst_volume, dst_path)
             if fi.data_dir:
                 src_data = self._file_path(src_volume, src_path)
@@ -559,6 +600,8 @@ class LocalStorage(StorageAPI):
                 meta = XLMeta()
             meta.add_version(fi)
             self._write_meta(dst_volume, dst_path, meta)
+        finally:
+            self._lock.release()
 
     # --- files ---
 

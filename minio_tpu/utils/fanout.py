@@ -174,10 +174,11 @@ def quorum_wait(cv, pending, count_ok, quorum, deadline_s, grace_s):
     elapses. count_ok runs under cv. Whatever is left in `pending`
     afterwards is the caller's to detach. Records one request span
     (kind "fanout"/"quorum-wait") so a PUT stalled on a straggling
-    disk attributes the stall to the fan-out, not the handler."""
+    disk attributes the stall to the fan-out, not the handler; on the
+    profiler's clock too, since the wait holds no span of its thread."""
     from ..observability import spans as _spans
 
-    with _spans.span("fanout", "quorum-wait"):
+    with _spans.span("fanout", "quorum-wait", mirror=True):
         _quorum_wait(cv, pending, count_ok, quorum, deadline_s, grace_s)
 
 

@@ -153,9 +153,12 @@ def test_exposition_has_span_kind_histograms():
         cv = _th.Condition()
         quorum_wait(cv, set(), lambda: 0, 0, 0.01, 0.0)
     text = reg.render_prometheus()
-    for kind in ("admission", "stage", "fanout", "request"):
+    for kind in ("admission", "stage", "request"):
         assert (f'mtpu_span_seconds_count{{kind="{kind}",'
                 f'op="put_object"}}') in text, kind
+    # a fan-out's series keeps its phase as well
+    assert ('mtpu_span_seconds_count{kind="fanout",label="quorum-wait",'
+            'op="put_object"}') in text
     assert reg.counter_value("trace_slow_captures_total") >= 1
 
 

@@ -46,11 +46,12 @@ DESCRIPTORS: list[tuple[str, str, str]] = [
     ("disk_offline_total", "counter", "Disk offline transitions"),
     ("disk_reconnect_total", "counter", "Disk reconnect events"),
     ("drive_lock_wait_seconds_total", "counter",
-     "Seconds waited for a drive's metadata lock when another thread "
-     "held it, by op (rename_data, write_metadata, update_metadata, "
-     "delete_version)"),
+     "Seconds waited for an object path's metadata lock on a drive when "
+     "another thread held it, by op (rename_data, write_metadata, "
+     "update_metadata, delete_version)"),
     ("drive_lock_waits_total", "counter",
-     "Takings of a drive's metadata lock that had to wait, by op"),
+     "Takings of an object path's metadata lock on a drive that had to "
+     "wait, by op"),
     # --- in-band disk health (circuit breaker / deadlines) ---
     ("disk_health_state", "gauge",
      "0 when healthy, 1 when latched faulty by the circuit breaker"),

@@ -18,6 +18,7 @@ are preserved behind the `fsync` flag.
 
 from __future__ import annotations
 
+import contextlib
 import io
 import os
 import shutil
@@ -46,9 +47,16 @@ SYSTEM_TMP = SYSTEM_META_BUCKET + "/tmp"
 SYSTEM_MULTIPART = SYSTEM_META_BUCKET + "/multipart"
 XL_META_FILE = "xl.meta"
 
-# The ops that take the drive's metadata lock (`LocalStorage._take_lock`).
+# The ops that take their object path's metadata lock
+# (`LocalStorage._path_lock`).
 _LOCKED_OPS = ("rename_data", "write_metadata", "update_metadata",
                "delete_version")
+
+# How many times an object directory's making and the move or write into
+# it are tried when a delete of another path's last object removes the
+# directory or a parent in between (reliableMkdirAll / reliableRename,
+# ref cmd/os-reliable.go).
+_DIR_TRIES = 8
 
 # Shard files at or below this size are inlined into xl.meta
 # (smallFileThreshold, ref cmd/xl-storage.go:66): a small PUT becomes
@@ -84,10 +92,12 @@ class LocalStorage(StorageAPI):
         self._endpoint = endpoint or self.root
         self._fsync = fsync
         self._disk_id = ""
-        # The drive's metadata lock, taken through `_take_lock`, which
-        # counts the waits for it into `metrics` (the registry the drive
-        # guard raises disk_ops_total with); their series stand at 0.
-        self._lock = threading.RLock()
+        # One metadata lock an object path, taken through `_path_lock`,
+        # which counts the waits for it into `metrics` (the registry the
+        # drive guard raises disk_ops_total with); their series stand
+        # at 0. An entry lives while some op holds or waits for it.
+        self._paths_mu = threading.Lock()
+        self._path_locks: dict[str, list] = {}  # dir -> [Lock, users]
         self._metrics = metrics
         if metrics is not None:
             for op in _LOCKED_OPS:
@@ -106,23 +116,72 @@ class LocalStorage(StorageAPI):
 
     # --- helpers ---
 
-    def _take_lock(self, op: str) -> None:
-        """Take the drive's metadata lock for `op`; the caller releases
-        it. Free, it is one non-blocking acquire and nothing recorded;
-        held by another thread, the blocking acquire is timed into
-        drive_lock_wait_seconds_total{op} and drive_lock_waits_total{op}:
-        counters and not spans, since a remote drive's ops run on the
-        storage plane's threads, where no trace is active."""
-        # lock-ok: the caller's try/finally releases what this takes
-        if self._lock.acquire(blocking=False):
-            return
-        t0 = time.monotonic_ns()
-        self._lock.acquire()  # lock-ok: as above
-        metrics = self._metrics
-        if metrics is not None:
-            metrics.inc("drive_lock_wait_seconds_total",
-                        (time.monotonic_ns() - t0) / 1e9, op=op)
-            metrics.inc("drive_lock_waits_total", op=op)
+    @contextlib.contextmanager
+    def _path_lock(self, op: str, volume: str, path: str):
+        """Hold the metadata lock of `volume`/`path` on this drive for
+        `op`: ops on one object path exclude each other, ops on other
+        paths do not wait. Free, it is one non-blocking acquire and
+        nothing recorded; held by another thread, the blocking acquire
+        is timed into drive_lock_wait_seconds_total{op} and
+        drive_lock_waits_total{op}: counters and not spans, since a
+        remote drive's ops run on the storage plane's threads, where no
+        trace is active."""
+        key = self._file_path(volume, path)
+        with self._paths_mu:
+            entry = self._path_locks.get(key)
+            if entry is None:
+                entry = self._path_locks[key] = [threading.Lock(), 0]
+            entry[1] += 1
+        lock = entry[0]
+        try:
+            # lock-ok: one object path's lock, released below: what it
+            # makes atomic is that path's data-dir move and xl.meta merge
+            if not lock.acquire(blocking=False):
+                t0 = time.monotonic_ns()
+                lock.acquire()  # lock-ok: as above
+                metrics = self._metrics
+                if metrics is not None:
+                    metrics.inc("drive_lock_wait_seconds_total",
+                                (time.monotonic_ns() - t0) / 1e9, op=op)
+                    metrics.inc("drive_lock_waits_total", op=op)
+            try:
+                yield
+            finally:
+                lock.release()
+        finally:
+            with self._paths_mu:
+                entry[1] -= 1
+                if not entry[1]:
+                    del self._path_locks[key]
+
+    @staticmethod
+    def _into_dir(dir_path: str, act) -> bool:
+        """Make the directory `dir_path` (one `mkdir`; its parents only
+        where they lack), then `act()` into it; True where `dir_path`
+        was not there, so that it holds no journal of this path (the
+        caller holds the path's lock). A delete of another path's last
+        object removes the directories it leaves empty under that
+        path's lock alone: where it removes `dir_path` or a parent
+        between the making and `act`, `act` raises FileNotFoundError
+        and both are tried again, up to `_DIR_TRIES` times
+        (reliableMkdirAll / reliableRename, ref cmd/os-reliable.go)."""
+        tries = 1
+        while True:
+            try:
+                try:
+                    os.mkdir(dir_path)
+                    made = True
+                except FileExistsError:
+                    made = False
+                except FileNotFoundError:
+                    os.makedirs(dir_path, exist_ok=True)
+                    made = True
+                act()
+                return made
+            except FileNotFoundError:
+                if tries == _DIR_TRIES:
+                    raise
+                tries += 1
 
     def _vol_path(self, volume: str) -> str:
         _check_path(volume)
@@ -445,25 +504,39 @@ class LocalStorage(StorageAPI):
         self._write_meta_blob(volume, path, meta.to_bytes())
 
     def _write_meta_blob(self, volume: str, path: str, blob: bytes):
+        """Write `blob` as the object's xl.meta through a tmp file and a
+        rename, in three syscalls where `open` would take five."""
         obj_dir = self._file_path(volume, path)
-        os.makedirs(obj_dir, exist_ok=True)
-        tmp = os.path.join(obj_dir, f".xl.meta.tmp.{os.getpid()}.{time.monotonic_ns()}")
-        with open(tmp, "wb") as f:
-            f.write(blob)
-            if self._fsync:
-                f.flush()
-                os.fsync(f.fileno())
-        os.replace(tmp, os.path.join(obj_dir, XL_META_FILE))
+
+        def write():
+            tmp = os.path.join(
+                obj_dir, f".xl.meta.tmp.{os.getpid()}.{time.monotonic_ns()}")
+            fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666)
+            try:
+                view = memoryview(blob)
+                while view:
+                    view = view[os.write(fd, view):]
+                if self._fsync:
+                    os.fsync(fd)
+            finally:
+                os.close(fd)
+            os.replace(tmp, os.path.join(obj_dir, XL_META_FILE))
+
+        self._into_dir(obj_dir, write)
         ioflow.account(self._endpoint, "wmeta", len(blob))
 
-    def _fresh_meta_blob(self, volume: str, path: str,
-                         fi: FileInfo) -> bytes | None:
+    def _fresh_meta_blob(self, volume: str, path: str, fi: FileInfo,
+                         new_dir: bool = False) -> bytes | None:
         """Pre-serialized journal from the PUT's shared fan-out pack
         (xlmeta.FanoutMetaPack), usable only when this disk holds NO
-        existing journal to merge with (xl.meta or legacy xl.json)."""
+        existing journal to merge with (xl.meta or legacy xl.json);
+        `new_dir` says the caller has just made the object's directory,
+        which then holds none."""
         pack = getattr(fi, "fanout_pack", None)
         if pack is None:
             return None
+        if new_dir:
+            return pack.bytes_for(fi)
         if not os.path.isdir(self._vol_path(volume)):
             return None  # slow path raises ErrVolumeNotFound as before
         obj_dir = self._file_path(volume, path)
@@ -477,8 +550,7 @@ class LocalStorage(StorageAPI):
 
     def write_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         self._require_online()
-        self._take_lock("write_metadata")
-        try:
+        with self._path_lock("write_metadata", volume, path):
             blob = self._fresh_meta_blob(volume, path, fi)
             if blob is not None:
                 self._write_meta_blob(volume, path, blob)
@@ -489,19 +561,14 @@ class LocalStorage(StorageAPI):
                 meta = XLMeta()
             meta.add_version(fi)
             self._write_meta(volume, path, meta)
-        finally:
-            self._lock.release()
 
     def update_metadata(self, volume: str, path: str, fi: FileInfo) -> None:
         self._require_online()
-        self._take_lock("update_metadata")
-        try:
+        with self._path_lock("update_metadata", volume, path):
             meta = self._read_meta(volume, path)
             meta.find_version(fi.version_id)  # must exist
             meta.add_version(fi)
             self._write_meta(volume, path, meta)
-        finally:
-            self._lock.release()
 
     def read_version(self, volume: str, path: str, version_id: str = "",
                      read_data: bool = False) -> FileInfo:
@@ -525,8 +592,7 @@ class LocalStorage(StorageAPI):
         """Remove one version; drop xl.meta + dirs when journal empties
         (ref cmd/xl-storage.go DeleteVersion)."""
         self._require_online()
-        self._take_lock("delete_version")
-        try:
+        with self._path_lock("delete_version", volume, path):
             meta = self._read_meta(volume, path)
             data_dir = meta.delete_version(fi)
             if data_dir:
@@ -545,8 +611,6 @@ class LocalStorage(StorageAPI):
                 obj_dir = self._file_path(volume, path)
                 shutil.rmtree(obj_dir, ignore_errors=True)
                 self._cleanup_empty_dirs(volume, path)
-        finally:
-            self._lock.release()
 
     def delete_versions(self, volume: str, versions: list[FileInfo]) -> list:
         errs = []
@@ -575,22 +639,34 @@ class LocalStorage(StorageAPI):
         """Atomic commit: move staged data dir into place and journal the
         version (ref cmd/xl-storage.go:1825 RenameData)."""
         self._require_online()
-        # lock-ok: per-disk metadata transaction lock — the
-        # rename+journal-merge must be atomic per disk (the reference
-        # holds xl-storage's lock across RenameData the same way)
-        self._take_lock("rename_data")
-        try:
-            dst_dir = self._file_path(dst_volume, dst_path)
+        # The object path's metadata lock: the rename and the merge into
+        # its xl.meta are atomic per object path on this drive; commits
+        # of other objects to the drive do not wait for it.
+        with self._path_lock("rename_data", dst_volume, dst_path):
+            made = False
             if fi.data_dir:
                 src_data = self._file_path(src_volume, src_path)
                 if not os.path.isdir(src_data):
                     raise ErrFileNotFound(f"{src_volume}/{src_path}")
-                os.makedirs(dst_dir, exist_ok=True)
-                dst_data = os.path.join(dst_dir, fi.data_dir)
-                if os.path.isdir(dst_data):
-                    shutil.rmtree(dst_data)
-                os.replace(src_data, dst_data)
-            blob = self._fresh_meta_blob(dst_volume, dst_path, fi)
+                dst_data = os.path.join(
+                    self._file_path(dst_volume, dst_path), fi.data_dir)
+
+                def move():
+                    try:
+                        os.replace(src_data, dst_data)
+                    except FileNotFoundError:
+                        raise  # the object's directory went: made again
+                    except OSError:
+                        # a data dir of this id is there (a heal's
+                        # re-commit): the staged one takes its place whole
+                        if not os.path.isdir(dst_data):
+                            raise
+                        shutil.rmtree(dst_data)
+                        os.replace(src_data, dst_data)
+
+                made = self._into_dir(
+                    self._file_path(dst_volume, dst_path), move)
+            blob = self._fresh_meta_blob(dst_volume, dst_path, fi, made)
             if blob is not None:
                 self._write_meta_blob(dst_volume, dst_path, blob)
                 return
@@ -600,8 +676,6 @@ class LocalStorage(StorageAPI):
                 meta = XLMeta()
             meta.add_version(fi)
             self._write_meta(dst_volume, dst_path, meta)
-        finally:
-            self._lock.release()
 
     # --- files ---
 

@@ -7,7 +7,8 @@ import os
 import pytest
 
 from minio_tpu.storage.fileinfo import ErasureInfo, FileInfo, new_uuid
-from minio_tpu.storage.local import SYSTEM_TMP, LocalStorage
+from minio_tpu.storage.local import SYSTEM_TMP, XL_META_FILE, LocalStorage
+from minio_tpu.storage.xlmeta import FanoutMetaPack
 from minio_tpu.utils.errors import (
     ErrFileNotFound,
     ErrFileVersionNotFound,
@@ -94,6 +95,89 @@ def test_version_journal_and_rename_data(disk):
     disk.delete_version("b", "obj1", fi)
     with pytest.raises(ErrFileNotFound):
         disk.read_version("b", "obj1")
+
+
+def _staged_version(disk, body: bytes, data_dir: str = ""):
+    """A version of 'b'/obj staged under tmp as putObject stages it, with
+    the fan-out's shared journal pack; -> (tmp path, FileInfo)."""
+    fi = FileInfo.new("b", "obj")
+    fi.version_id = new_uuid()
+    fi.size = len(body)
+    fi.data_dir = data_dir or new_uuid()
+    fi.erasure = ErasureInfo(data_blocks=2, parity_blocks=2,
+                             block_size=1 << 20, index=1,
+                             distribution=[1, 2, 3, 4])
+    fi.add_part(1, len(body), len(body))
+    fi.fanout_pack = FanoutMetaPack()
+    tmp = f"tmp/{new_uuid()}"
+    disk.create_file(SYSTEM_TMP.split("/")[0], f"{tmp}/part.1", len(body),
+                     io.BytesIO(body))
+    return tmp, fi
+
+
+def test_a_fresh_commit_writes_the_shared_pack_and_the_next_merges(disk):
+    disk.make_vol("b")
+    tmp, fi = _staged_version(disk, b"first")
+    disk.rename_data(".mtpu.sys", tmp, fi, "b", "obj")
+    obj_dir = os.path.join(disk.root, "b", "obj")
+    with open(os.path.join(obj_dir, XL_META_FILE), "rb") as f:
+        assert f.read() == fi.fanout_pack.bytes_for(fi)
+    tmp2, fi2 = _staged_version(disk, b"second")
+    fi2.mod_time_ns = fi.mod_time_ns + 10
+    disk.rename_data(".mtpu.sys", tmp2, fi2, "b", "obj")
+    assert {v.version_id for v in disk.list_versions("b", "obj").versions} \
+        == {fi.version_id, fi2.version_id}
+    assert disk.read_version("b", "obj").version_id == fi2.version_id
+    assert disk.read_file("b", f"obj/{fi2.data_dir}/part.1", 0, 6) \
+        == b"second"
+    # no tmp journal is left beside xl.meta
+    assert sorted(n for n in os.listdir(obj_dir) if n.startswith(".")) == []
+
+
+def test_a_commit_whose_staged_dir_is_gone_leaves_no_directory(disk):
+    disk.make_vol("b")
+    tmp, fi = _staged_version(disk, b"lost")
+    disk.delete(SYSTEM_TMP.split("/")[0], tmp, recursive=True)
+    with pytest.raises(ErrFileNotFound):
+        disk.rename_data(".mtpu.sys", tmp, fi, "b", "deep/prefix/obj")
+    assert os.listdir(os.path.join(disk.root, "b")) == []
+
+
+def test_a_re_commit_of_one_data_dir_replaces_it_whole(disk):
+    """A heal commits the data dir of the version the drive already
+    has, under the same id: the staged files take the old ones' place,
+    and the journal still holds one version."""
+    disk.make_vol("b")
+    tmp, fi = _staged_version(disk, b"stale")
+    disk.rename_data(".mtpu.sys", tmp, fi, "b", "obj")
+    tmp2, again = _staged_version(disk, b"fresh", data_dir=fi.data_dir)
+    again.version_id = fi.version_id
+    disk.rename_data(".mtpu.sys", tmp2, again, "b", "obj")
+    assert disk.read_file("b", f"obj/{fi.data_dir}/part.1", 0, 5) \
+        == b"fresh"
+    assert len(disk.list_versions("b", "obj").versions) == 1
+
+
+def test_journal_writes_with_fsync(tmp_path):
+    disk = LocalStorage(str(tmp_path / "d"), endpoint="d", fsync=True)
+    disk.make_vol("b")
+    tmp, fi = _staged_version(disk, b"synced")
+    disk.rename_data(".mtpu.sys", tmp, fi, "b", "obj")
+    fi2 = FileInfo.new("b", "obj")
+    fi2.version_id = new_uuid()
+    fi2.mod_time_ns = fi.mod_time_ns + 10
+    disk.write_metadata("b", "obj", fi2)
+    assert {v.version_id for v in disk.list_versions("b", "obj").versions} \
+        == {fi.version_id, fi2.version_id}
+
+
+def test_a_journal_write_to_a_missing_volume_makes_nothing(disk):
+    fi = FileInfo.new("nobucket", "obj")
+    fi.version_id = new_uuid()
+    fi.data = {1: b"tiny"}
+    with pytest.raises(ErrVolumeNotFound):
+        disk.write_metadata("nobucket", "obj", fi)
+    assert not os.path.exists(os.path.join(disk.root, "nobucket"))
 
 
 def test_inline_data_roundtrip(disk):
